@@ -30,7 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-import mxnet_tpu as mx  # noqa: F401 — backend init
+import mxnet_tpu as mx
 from mxnet_tpu import gluon
 from mxnet_tpu.observability.registry import registry
 from mxnet_tpu.serving import ModelServer, ServingError
@@ -44,14 +44,21 @@ def main():
     ap.add_argument("--max-batch", type=int, default=16)
     ap.add_argument("--deadline-ms", type=float, default=0.0,
                     help="per-request deadline (0 = none)")
+    ap.add_argument("--ctx", default="tpu(0)",
+                    help="context to place the model and data on — the "
+                         "default context is the host, so a run meant "
+                         "for the chip names it (cpu(0) for a host run)")
     args = ap.parse_args()
+    ctx = mx.Context.from_str(args.ctx)
+    print(f"running on {ctx}: {ctx.device.platform} "
+          f"{ctx.device.device_kind}")
 
     net = gluon.nn.HybridSequential()
     with net.name_scope():
         net.add(gluon.nn.Dense(128, activation="relu"),
                 gluon.nn.Dense(64, activation="relu"),
                 gluon.nn.Dense(10))
-    net.initialize()
+    net.initialize(ctx=ctx)   # the served graphs follow the parameters
     net.hybridize()
 
     rng = np.random.default_rng(0)

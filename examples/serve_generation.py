@@ -30,7 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-import mxnet_tpu as mx  # noqa: F401 — backend init
+import mxnet_tpu as mx
 from mxnet_tpu.gluon.model_zoo.transformer import causal_lm_small
 from mxnet_tpu.observability.registry import registry
 from mxnet_tpu.serving import GenerationServer, ServingError
@@ -46,12 +46,19 @@ def main():
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--prefill-mode", choices=("interleave", "step"),
                     default="interleave")
+    ap.add_argument("--ctx", default="tpu(0)",
+                    help="context to place the model and data on — the "
+                         "default context is the host, so a run meant "
+                         "for the chip names it (cpu(0) for a host run)")
     args = ap.parse_args()
+    ctx = mx.Context.from_str(args.ctx)
+    print(f"running on {ctx}: {ctx.device.platform} "
+          f"{ctx.device.device_kind}")
     os.environ["MXTPU_SERVING_PREFILL_MODE"] = args.prefill_mode
 
     np.random.seed(0)
     lm = causal_lm_small()
-    lm.initialize()
+    lm.initialize(ctx=ctx)   # the server's graphs and KV pool follow it
     lm.hybridize()
     ttft_ms, tokens, rejected = [], [0], [0]
     lock = threading.Lock()

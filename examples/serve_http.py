@@ -36,7 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
-import mxnet_tpu as mx  # noqa: F401 — backend init
+import mxnet_tpu as mx
 from mxnet_tpu import gluon
 from mxnet_tpu.gluon.model_zoo.transformer import causal_lm_small
 from mxnet_tpu.serving import (GenerationServer, HttpFrontend,
@@ -55,9 +55,9 @@ class Scale3(gluon.HybridBlock):
         return F.tanh(x * 3.0) - 0.25
 
 
-def _block(cls):
+def _block(cls, ctx):
     net = cls()
-    net.initialize()
+    net.initialize(ctx=ctx)
     net.hybridize()
     return net
 
@@ -69,16 +69,23 @@ def main():
                     help="predict requests per client")
     ap.add_argument("--generations", type=int, default=3)
     ap.add_argument("--max-new", type=int, default=8)
+    ap.add_argument("--ctx", default="tpu(0)",
+                    help="context to place the model and data on — the "
+                         "default context is the host, so a run meant "
+                         "for the chip names it (cpu(0) for a host run)")
     args = ap.parse_args()
+    ctx = mx.Context.from_str(args.ctx)
+    print(f"running on {ctx}: {ctx.device.platform} "
+          f"{ctx.device.device_kind}")
 
     np.random.seed(0)
     mx.random.seed(0)
     lm = causal_lm_small()
-    lm.initialize()
+    lm.initialize(ctx=ctx)
     lm.hybridize()
 
     registry = ModelRegistry()
-    predict_srv = ModelServer(_block(Scale2), max_batch=8,
+    predict_srv = ModelServer(_block(Scale2, ctx), max_batch=8,
                               batch_window_us=300.0)
     registry.load("scale", predict_srv, priority=1, slo_ms=50.0)
     gen_srv = GenerationServer(lm, slots=2, kv_block=16, kv_blocks=64,
@@ -164,7 +171,7 @@ def main():
     for t in threads:
         t.start()
     time.sleep(0.2)
-    staged = registry.swap("scale", _block(Scale3))
+    staged = registry.swap("scale", _block(Scale3, ctx))
     time.sleep(0.2)
     stop.set()
     for t in threads:

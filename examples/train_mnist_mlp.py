@@ -41,7 +41,14 @@ def main():
     ap.add_argument("--lr", type=float, default=2e-3)
     ap.add_argument("--synthetic", action="store_true")
     ap.add_argument("--hybridize", action="store_true")
+    ap.add_argument("--ctx", default="tpu(0)",
+                    help="context to place the model and data on — the "
+                         "default context is the host, so a run meant "
+                         "for the chip names it (cpu(0) for a host run)")
     args = ap.parse_args()
+    ctx = mx.Context.from_str(args.ctx)
+    print(f"running on {ctx}: {ctx.device.platform} "
+          f"{ctx.device.device_kind}")
 
     (Xtr, ytr), (Xte, yte) = load_data(args.synthetic)
     net = gluon.nn.HybridSequential()
@@ -49,7 +56,7 @@ def main():
         net.add(gluon.nn.Dense(128, activation="relu"),
                 gluon.nn.Dense(64, activation="relu"),
                 gluon.nn.Dense(10))
-    net.initialize(mx.init.Xavier())
+    net.initialize(mx.init.Xavier(), ctx=ctx)
     if args.hybridize:
         net.hybridize()
     trainer = gluon.Trainer(net.collect_params(), "adam",
@@ -60,8 +67,8 @@ def main():
     for epoch in range(args.epochs):
         metric.reset()
         for i in range(0, len(Xtr), args.batch_size):
-            x = mx.nd.array(Xtr[i:i + args.batch_size])
-            y = mx.nd.array(ytr[i:i + args.batch_size])
+            x = mx.nd.array(Xtr[i:i + args.batch_size], ctx=ctx)
+            y = mx.nd.array(ytr[i:i + args.batch_size], ctx=ctx)
             with autograd.record():
                 out = net(x)
                 L = loss_fn(out, y)
@@ -69,7 +76,7 @@ def main():
             trainer.step(x.shape[0])
             metric.update([y], [out])
         test_acc = float(np.mean(np.argmax(
-            net(mx.nd.array(Xte)).asnumpy(), 1) == yte))
+            net(mx.nd.array(Xte, ctx=ctx)).asnumpy(), 1) == yte))
         print(f"epoch {epoch}: train {metric.get()[1]:.4f} "
               f"test {test_acc:.4f}")
 

@@ -76,9 +76,9 @@ from . import test_utils
 from . import observability
 from . import serving
 from . import tuning
-# opt-in persistent compile cache: wiring the disk tier (segment hooks
-# + jax's own cache dir) costs nothing when the knob is unset
-if get_env("MXTPU_COMPILE_CACHE_DIR"):
+# opt-in persistent compile cache: wiring the disk tier (segment hooks)
+# costs nothing when the environment names no cache directory
+if tuning.compile_cache.cache_dir():
     tuning.compile_cache.active()
 # opt-in exporters: a Prometheus /metrics endpoint when
 # MXTPU_METRICS_PORT is set, a periodic JSONL snapshot writer when

@@ -141,6 +141,29 @@ class Block:
                    force_reinit=False) -> None:
         self.collect_params().initialize(init, ctx, verbose, force_reinit)
 
+    def param_context(self) -> Context:
+        """The context this block's parameters were initialized on (the
+        first one, for a multi-context block); the current context for a
+        block with no parameters."""
+        for p in self.collect_params().values():
+            if p._data is not None:
+                return next(iter(p._data))
+            if p._deferred_init is not None:
+                return p._deferred_init[1][0]
+        return current_context()
+
+    def example_inputs(self, inputs) -> Tuple[NDArray, ...]:
+        """An example batch as NDArrays.  Host values (numpy, lists) go
+        where the NDArrays among them are, else where the parameters are:
+        a serving thread's default context is the host, and a graph traced
+        there could not read parameters that live on the chip."""
+        ctx = next((a.context for a in inputs if isinstance(a, NDArray)),
+                   None)
+        if ctx is None:
+            ctx = self.param_context()
+        return tuple(a if isinstance(a, NDArray) else nd_mod.array(a, ctx=ctx)
+                     for a in inputs)
+
     def register_child(self, block: "Block", name: Optional[str] = None) -> None:
         self._children[name or str(len(self._children))] = block
 
@@ -486,7 +509,8 @@ class HybridBlock(Block):
                     vals = [p.data(ctx)._read() for p in params] + \
                            [a._read() for a in inputs]
                     lowered = jitted.lower(sample_key, *vals)
-                    infer_cell[0] = _cc.aot_compile(lowered, "graph")
+                    infer_cell[0] = _cc.aot_compile(
+                        lowered, "graph", ctx.device)
             except Exception:   # noqa: BLE001 — AOT/serialization drift
                 infer_cell[0] = None   # degrades to the plain jit path
         return jitted, jitted_vjp, params, (n_outs_cell, write_idx_cell,
@@ -527,8 +551,7 @@ class HybridBlock(Block):
         forward graph, so a warm process restart skips the XLA compile."""
         if entry != "forward":
             return self._cached_entry_graph(entry, inputs)
-        inputs = tuple(a if isinstance(a, NDArray) else nd_mod.array(a)
-                       for a in inputs)
+        inputs = self.example_inputs(inputs)
         ctx = inputs[0].context
         with _autograd.pause():
             # one eager pass settles every deferred shape (children
@@ -583,8 +606,7 @@ class HybridBlock(Block):
                 f"{type(self).__name__} has no {method_name}(); a "
                 f"generation-servable block implements hybrid_prefill "
                 f"and hybrid_decode (see serving.ModelServer docs)")
-        inputs = tuple(a if isinstance(a, NDArray) else nd_mod.array(a)
-                       for a in inputs)
+        inputs = self.example_inputs(inputs)
         ctx = inputs[0].context
         with _autograd.pause():
             sig = (entry,
@@ -658,7 +680,8 @@ class HybridBlock(Block):
                 vals = [p.data(ctx)._read() for p in params] + \
                        [a._read() for a in inputs]
                 lowered = jitted.lower(sample_key, *vals)
-                infer_cell[0] = _cc.aot_compile(lowered, "graph")
+                infer_cell[0] = _cc.aot_compile(
+                    lowered, "graph", ctx.device)
         except Exception:   # noqa: BLE001 — AOT/serialization drift
             infer_cell[0] = None   # degrades to the plain jit path
         return jitted, params, n_outs_cell, infer_cell

@@ -10,14 +10,19 @@ O(Lq·Lk) to O(Lq·D + Lk·D) — exactly the memory-bound regime SURVEY §6
 flags for long sequences (ring attention in parallel/ring.py handles the
 multi-chip axis; this kernel is the single-chip inner loop).
 
-Grid: (batch·heads, Lq/BLOCK_Q); the K/V sweep is a lax.fori_loop inside
-the kernel over VMEM-resident K/V (one head's K/V must fit VMEM — fine
-through Lk·D ≈ 512k fp32 elements; beyond that, shard Lk over the ring).
+Grid: (batch·heads, Lq/BLOCK_Q, Lk/BLOCK_K); the K/V sweep is the
+innermost, sequential grid axis, so one (BLOCK_K, D) tile of K and of V
+is in VMEM at a time and the running max/denominator/accumulator live in
+VMEM scratch between its steps.  The per-row valid length is
+scalar-prefetched into SMEM.
 
-Numerics: f32 accumulation regardless of input dtype; causal masking and
+Numerics: f32 accumulation regardless of input dtype, f32 operands
+multiplied at full precision, bf16 operands as they are (the
+probabilities are rounded to bf16 for the PV product); causal masking and
 right-padding masks derive from 2-D broadcasted_iota (TPU requires ≥2-D
 iota).  Interpret mode runs the same kernel on CPU (tests/conftest mesh);
-Mosaic compiles it on the chip (tests/test_kernels_tpu.py).
+Mosaic compiles it for the chip (tests/test_chip_compile.py compiles
+it for a described v5e; chip_smoke.py runs it).
 """
 from __future__ import annotations
 
@@ -41,76 +46,95 @@ def _build_call(bh: int, lq: int, lk: int, d: int, valid_lq: int,
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     nq = lq // BLOCK_Q
     nk = lk // BLOCK_K
     dtype = jnp.dtype(dtype_name)
+    precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
-    def kernel(q_ref, k_ref, v_ref, vl_ref, o_ref):
-        qi = pl.program_id(1)
-        q = q_ref[0].astype(jnp.float32) * scale          # (BQ, D)
+    def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _():
+            m_ref[...] = jnp.full((BLOCK_Q, 1), _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((BLOCK_Q, 1), jnp.float32)
+            acc_ref[...] = jnp.zeros((BLOCK_Q, d), jnp.float32)
+
         # per-sequence valid key length (padding mask support): the tile
         # padding bound `valid_lk` is static; vl tightens it per row
-        vl = jnp.minimum(vl_ref[0], jnp.float32(valid_lk))
+        vl = jnp.minimum(vl_ref[b], valid_lk)
+        # operands stay in the input dtype, the scale is applied to the
+        # f32 scores: bf16 products are exact in the MXU's f32
+        # accumulator, and f32 operands ask for full precision (the MXU's
+        # default would round them to bf16: 5e-3 off at seq 256)
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32) * scale    # (BQ, BK)
+        # mask K padding (and the causal upper triangle)
+        k_idx = ki * BLOCK_K + lax.broadcasted_iota(
+            jnp.int32, (BLOCK_Q, BLOCK_K), 1)
+        kmask = k_idx < vl
+        mask = kmask
+        if causal:
+            # bottom-right alignment (the flash/decode convention and
+            # this repo's reference): query i sits at absolute key
+            # position (valid_lk - valid_lq + i), so Lq=1 against a
+            # length-N cache attends ALL N keys
+            q_idx = qi * BLOCK_Q + lax.broadcasted_iota(
+                jnp.int32, (BLOCK_Q, BLOCK_K), 0)
+            mask = mask & (k_idx <= q_idx + (valid_lk - valid_lq))
+        s = jnp.where(mask, s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        # rows whose every key is masked (causal bound < 0): the
+        # reference softmaxes a uniform -NEG_INF row, i.e. uniform
+        # attention over the valid keys — exp(0)=1 here would
+        # instead spread over PADDED slots, so substitute the valid
+        # mask as the weights (masks are prefixes, so a row dead in
+        # this block is dead in every block)
+        dead = m_new <= (_NEG_INF * 0.5)
+        p = jnp.where(dead, kmask.astype(jnp.float32), p)
+        corr = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32)
 
-        def body(ki, carry):
-            m, l, acc = carry
-            k_blk = k_ref[0, pl.dslice(ki * BLOCK_K, BLOCK_K)].astype(
-                jnp.float32)                               # (BK, D)
-            v_blk = v_ref[0, pl.dslice(ki * BLOCK_K, BLOCK_K)].astype(
-                jnp.float32)
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (BQ, BK)
-            # mask K padding (and the causal upper triangle)
-            k_idx = ki * BLOCK_K + lax.broadcasted_iota(
-                jnp.int32, (BLOCK_Q, BLOCK_K), 1)
-            kmask = k_idx.astype(jnp.float32) < vl
-            mask = kmask
-            if causal:
-                # bottom-right alignment (the flash/decode convention and
-                # this repo's reference): query i sits at absolute key
-                # position (valid_lk - valid_lq + i), so Lq=1 against a
-                # length-N cache attends ALL N keys
-                q_idx = qi * BLOCK_Q + lax.broadcasted_iota(
-                    jnp.int32, (BLOCK_Q, BLOCK_K), 0)
-                mask = mask & (k_idx <= q_idx + (valid_lk - valid_lq))
-            s = jnp.where(mask, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            # rows whose every key is masked (causal bound < 0): the
-            # reference softmaxes a uniform -NEG_INF row, i.e. uniform
-            # attention over the valid keys — exp(0)=1 here would
-            # instead spread over PADDED slots, so substitute the valid
-            # mask as the weights (masks are prefixes, so a row dead in
-            # this block is dead in every block)
-            dead = m_new <= (_NEG_INF * 0.5)
-            p = jnp.where(dead[:, None], kmask.astype(jnp.float32), p)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1)
-            acc_new = acc * corr[:, None] + jax.lax.dot_general(
-                p, v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+        @pl.when(ki == nk - 1)
+        def _():
+            # rows with no valid keys (padded queries) divide by 1 instead
+            l = l_ref[...]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[...] / l).astype(dtype)
 
-        m0 = jnp.full((BLOCK_Q,), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((BLOCK_Q,), jnp.float32)
-        a0 = jnp.zeros((BLOCK_Q, d), jnp.float32)
-        m, l, acc = lax.fori_loop(0, nk, body, (m0, l0, a0))
-        # rows with no valid keys (padded queries) divide by 1 instead
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc / l[:, None]).astype(dtype)
-
-    q_spec = pl.BlockSpec((1, BLOCK_Q, d), lambda b, i: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, lk, d), lambda b, i: (b, 0, 0))
-    vl_spec = pl.BlockSpec((1,), lambda b, i: (b,))
+    # Mosaic takes neither a rank-1 block of one element nor rank-1 loop
+    # carries: the per-row length rides scalar memory, and the running
+    # max/denominator are (BLOCK_Q, 1) columns in VMEM scratch.  K/V are a
+    # grid axis (innermost, sequential), so one (BLOCK_K, D) tile of each
+    # is resident at a time whatever Lk is.
+    q_spec = pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j, vl: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j, vl: (b, j, 0))
     return pl.pallas_call(
         kernel,
-        grid=(bh, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, vl_spec],
-        out_specs=q_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nq, nk),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((BLOCK_Q, 1), jnp.float32),
+                            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),
+                            pltpu.VMEM((BLOCK_Q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )
 
 
@@ -228,7 +252,7 @@ def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
     call = _build_call(bh, qp.shape[1], kp.shape[1], qp.shape[2], lq, lk,
                        bool(causal), float(scale),
                        jnp.result_type(q).name, bool(interpret))
-    return call(qp, kp, vp, vl.astype(jnp.float32))[:, :lq, :d]
+    return call(vl.astype(jnp.int32), qp, kp, vp)[:, :lq, :d]
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,

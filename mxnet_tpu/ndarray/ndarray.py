@@ -611,51 +611,54 @@ def empty(shape, ctx=None, dtype=None) -> NDArray:
     return zeros(shape, ctx=ctx, dtype=dtype)
 
 
-def zeros(shape, ctx=None, dtype=None, **kwargs) -> NDArray:
+def _created(ctx: Context, make):
+    """Run a creation thunk on ``ctx``'s device and COMMIT the result
+    there.  An uncommitted array follows whatever it is next combined
+    with — a host value, the process default device — and its context
+    tag would then no longer say where it lives: parameters initialized
+    on ``tpu(0)`` or ``cpu(1)`` must stay there."""
     import jax
+    dev = ctx.device
+    with jax.default_device(dev):
+        return jax.device_put(make(), dev)
+
+
+def zeros(shape, ctx=None, dtype=None, **kwargs) -> NDArray:
     ctx = ctx if ctx is not None else current_context()
     shape = (shape,) if isinstance(shape, int) else tuple(shape)
-    with jax.default_device(ctx.device):
-        val = _jnp().zeros(shape, dtype=jax_compute_dtype(dtype))
-    return NDArray(val, ctx=ctx)
+    return NDArray(_created(ctx, lambda: _jnp().zeros(
+        shape, dtype=jax_compute_dtype(dtype))), ctx=ctx)
 
 
 def ones(shape, ctx=None, dtype=None, **kwargs) -> NDArray:
-    import jax
     ctx = ctx if ctx is not None else current_context()
     shape = (shape,) if isinstance(shape, int) else tuple(shape)
-    with jax.default_device(ctx.device):
-        val = _jnp().ones(shape, dtype=jax_compute_dtype(dtype))
-    return NDArray(val, ctx=ctx)
+    return NDArray(_created(ctx, lambda: _jnp().ones(
+        shape, dtype=jax_compute_dtype(dtype))), ctx=ctx)
 
 
 def full(shape, val, ctx=None, dtype=None) -> NDArray:
-    import jax
     ctx = ctx if ctx is not None else current_context()
     shape = (shape,) if isinstance(shape, int) else tuple(shape)
-    with jax.default_device(ctx.device):
-        out = _jnp().full(shape, val, dtype=jax_compute_dtype(dtype))
-    return NDArray(out, ctx=ctx)
+    return NDArray(_created(ctx, lambda: _jnp().full(
+        shape, val, dtype=jax_compute_dtype(dtype))), ctx=ctx)
 
 
 def arange(start, stop=None, step=1.0, repeat=1, ctx=None, dtype=None) -> NDArray:
-    import jax
     ctx = ctx if ctx is not None else current_context()
-    with jax.default_device(ctx.device):
+
+    def make():
         val = _jnp().arange(start, stop, step, dtype=jax_compute_dtype(dtype))
-        if repeat != 1:
-            val = _jnp().repeat(val, repeat)
-    return NDArray(val, ctx=ctx)
+        return val if repeat == 1 else _jnp().repeat(val, repeat)
+    return NDArray(_created(ctx, make), ctx=ctx)
 
 
 def eye(N, M=0, k=0, ctx=None, dtype=None) -> NDArray:
     """Identity-like matrix (reference mx.nd.eye: M=0 means square)."""
-    import jax
     ctx = ctx if ctx is not None else current_context()
-    with jax.default_device(ctx.device):
-        val = _jnp().eye(int(N), int(M) if M else int(N), k=int(k),
-                         dtype=jax_compute_dtype(dtype))
-    return NDArray(val, ctx=ctx)
+    return NDArray(_created(ctx, lambda: _jnp().eye(
+        int(N), int(M) if M else int(N), k=int(k),
+        dtype=jax_compute_dtype(dtype))), ctx=ctx)
 
 
 def moveaxis(data: "NDArray", source, destination) -> NDArray:
@@ -666,12 +669,10 @@ def moveaxis(data: "NDArray", source, destination) -> NDArray:
 
 def linspace(start, stop, num, endpoint=True, ctx=None,
              dtype=None) -> NDArray:
-    import jax
     ctx = ctx if ctx is not None else current_context()
-    with jax.default_device(ctx.device):
-        val = _jnp().linspace(start, stop, int(num), endpoint=endpoint,
-                              dtype=jax_compute_dtype(dtype))
-    return NDArray(val, ctx=ctx)
+    return NDArray(_created(ctx, lambda: _jnp().linspace(
+        start, stop, int(num), endpoint=endpoint,
+        dtype=jax_compute_dtype(dtype))), ctx=ctx)
 
 
 def zeros_like(other: NDArray) -> NDArray:

@@ -363,12 +363,12 @@ def _install_persist_hooks(lookup, store) -> None:
     _persist_hooks = (lookup, store)
 
 
-def _segment_persist_key(needed, nodes, ext_vals) -> str:
-    """Canonical string form of the segment signature for the disk tier
-    — the in-memory ``_segment_cache`` key minus the device id (the
-    cache's backend fingerprint covers platform/device kind, so an
-    executable can be replayed by any process on the same chip type)."""
-    return repr((needed, nodes,
+def _segment_persist_key(device_id, needed, nodes, ext_vals) -> str:
+    """Canonical string form of the segment signature for the disk tier:
+    the in-memory ``_segment_cache`` key (a serialized executable names
+    its device by id, so the id stays in; the cache's backend
+    fingerprint covers platform/device kind)."""
+    return repr((device_id, needed, nodes,
                  tuple((tuple(v.shape), str(_np.dtype(v.dtype)))
                        for v in ext_vals)))
 
@@ -505,13 +505,16 @@ def _compile_segment(nodes: Tuple, taped: bool,
     return jax.jit(fused)
 
 
-_exact_compile_broken = False
+#: XLA passes switched off for an exact-mode segment, so that every node
+#: keeps the kernels its per-op jit compiles
+_EXACT_COMPILER_OPTIONS = {
+    "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"}
 
 
 def _compile_segment_exact(nodes: Tuple, needed: Optional[Tuple],
                            ext_vals: Sequence, device,
                            persist_key: Optional[str] = None) -> Callable:
-    """'exact' codegen (the default): ONE PJRT executable per segment but
+    """'exact' codegen (the default): ONE executable per segment but
     with XLA's fusion passes disabled, so every node keeps the same
     kernels the unbulked per-op path compiles — results are BITWISE
     identical to unbulked (no cross-op FMA contraction, no refused
@@ -523,74 +526,33 @@ def _compile_segment_exact(nodes: Tuple, needed: Optional[Tuple],
     previously-compiled executable for the same signature+backend is
     deserialized from disk instead of compiled — the restart-without-
     recompile path; a real compile is serialized back for the next
-    process.
+    process.  A compile error is an error: there is no per-op fallback."""
+    import jax
+    hooks = _persist_hooks if persist_key is not None else None
+    exe = hooks[0](persist_key, device) if hooks is not None else None
+    if exe is None:
+        # keep_unused: liveness-DCE can leave some external inputs
+        # unused, and the executable is fed ALL of them.  default_device
+        # pins a segment with no committed input (creation ops only) to
+        # the context's device rather than the process default.
+        with jax.default_device(device):
+            exe = jax.jit(_build_fused(nodes, needed), keep_unused=True) \
+                .lower(*ext_vals) \
+                .compile(compiler_options=_EXACT_COMPILER_OPTIONS)
+        if hooks is not None:
+            hooks[1](persist_key, exe)
+    device_put = jax.device_put
 
-    Falls back to a node-by-node interpreter over the per-op jitted fns
-    (still bitwise, one jit dispatch per node) if the lower/compile
-    internals are unavailable."""
-    global _exact_compile_broken
-    fused = _build_fused(nodes, needed)
-    if not _exact_compile_broken:
+    def run(*vals):
         try:
-            import jax
-            from jax._src.lib import xla_client as xc
-            jax_array_cls = jax.Array
-            device_put = jax.device_put
-            opts = xc.CompileOptions()
-            opts.executable_build_options.debug_options \
-                .xla_disable_hlo_passes = "fusion,cpu-instruction-fusion"
-            opts.executable_build_options.device_assignment = \
-                xc.DeviceAssignment.create(
-                    # mxlint: disable=hot-path-purity — compile miss
-                    _np.asarray([[device.id]], dtype=_np.int32))
-            exe = None
-            hooks = _persist_hooks
-            if hooks is not None and persist_key is not None:
-                exe = hooks[0](persist_key, device, opts)
-            if exe is None:
-                # keep_unused: liveness-DCE can leave some external
-                # inputs unused; the raw executable is fed ALL of them,
-                # so jit must not prune its parameter list
-                # (kept_var_idx filtering is a jit-call-path service we
-                # bypass here)
-                lowered = jax.jit(fused,
-                                  keep_unused=True).lower(*ext_vals)
-                exe = device.client.compile(
-                    lowered.compiler_ir().operation.get_asm(), opts)
-                if hooks is not None and persist_key is not None:
-                    hooks[1](persist_key, device, exe)
+            return exe(*vals)
+        except ValueError:
+            # a buffer committed to another device (NDArray ctx tags can
+            # diverge from actual placement after cross-device
+            # _set_data): align and retry once; a real failure re-raises
+            return exe(*[device_put(v, device) for v in vals])
 
-            def run(*vals):
-                try:
-                    out = exe.execute_sharded(
-                        [v if isinstance(v, jax_array_cls)
-                         else device_put(v, device) for v in vals])
-                except Exception:  # noqa: BLE001 — a buffer on another
-                    # device (NDArray ctx tags can diverge from actual
-                    # placement after cross-device _set_data): align and
-                    # retry once; a real failure re-raises below
-                    out = exe.execute_sharded(
-                        [device_put(v, device) for v in vals])
-                return [a[0] for a in
-                        out.disassemble_into_single_device_arrays()]
-
-            return run
-        except Exception as e:  # noqa: BLE001 — jax-internal API drift:
-            # fall back, never break dispatch — but say so ONCE: the
-            # silent alternative is the headline single-dispatch win
-            # evaporating with healthy-looking stats
-            _exact_compile_broken = True
-            import warnings
-            # fires ONCE on jax API drift, then the
-            # _exact_compile_broken flag short-circuits
-            # mxlint: disable=hot-path-purity — warn-once cold path
-            warnings.warn(
-                "bulked dispatch: exact-mode segment compile unavailable "
-                f"({type(e).__name__}: {e}); falling back to per-op "
-                "dispatch at flush (correct but slower). "
-                "MXNET_ENGINE_BULK_FUSE=aggressive restores fused "
-                "execution.", RuntimeWarning, stacklevel=2)
-    return fused
+    return run
 
 
 class _BulkSegment:
@@ -678,9 +640,10 @@ class _BulkSegment:
                               (_perf_counter() - _t0) * 1e6)
             return
         # device id in the key: an exact-mode executable is PINNED to its
-        # device (DeviceAssignment); same-signature segments on another
+        # device; same-signature segments on another
         # device must compile their own
-        key = (self.fuse, taped, needed, self.ctx.device.id,
+        device = self.ctx.device
+        key = (self.fuse, taped, needed, device.id,
                tuple(self.nodes),
                tuple((tuple(v.shape), _np.dtype(v.dtype))
                      for v in self.ext_vals))
@@ -693,11 +656,12 @@ class _BulkSegment:
                     # in-memory miss — the steady-state flush never
                     # pays the repr
                     pkey = None if _persist_hooks is None else \
-                        _segment_persist_key(needed, tuple(self.nodes),
-                                             self.ext_vals)
+                        _segment_persist_key(
+                            device.id, needed, tuple(self.nodes),
+                            self.ext_vals)
                     fn = _compile_segment_exact(
                         tuple(self.nodes), needed, self.ext_vals,
-                        self.ctx.device, persist_key=pkey)
+                        device, persist_key=pkey)
                 else:
                     fn = _compile_segment(tuple(self.nodes), taped,
                                           needed)

@@ -39,6 +39,7 @@ import threading
 from typing import Optional, Tuple
 
 from ..base import MXNetError, get_env, hot_path
+from . import _coordination
 
 __all__ = ["init_process_group", "is_initialized", "rank", "num_workers",
            "phys_rank", "active_members", "fence_generation",
@@ -51,14 +52,8 @@ __all__ = ["init_process_group", "is_initialized", "rank", "num_workers",
 
 def is_initialized() -> bool:
     """True if this process has joined a multi-process JAX runtime."""
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client is not None
-    except Exception:
-        # no backend-initializing fallback here: this runs before
-        # jax.distributed.initialize, which must precede the first backend
-        # query — assume uninitialized
-        return False
+    import jax
+    return jax.distributed.is_initialized()   # touches no backend
 
 
 def init_process_group(coordinator: Optional[str] = None,
@@ -125,42 +120,22 @@ def init_process_group(coordinator: Optional[str] = None,
         timeout = float(get_env("MXTPU_DIST_TIMEOUT"))
     if elastic is None:
         elastic = bool(get_env("MXTPU_ELASTIC"))
-    join_kwargs = {}
-    if elastic:
-        # the service reaper would otherwise broadcast a FATAL error on
-        # the first silent task and jax's error-polling thread would
-        # terminate every survivor — the membership lease layer is the
-        # liveness authority in an elastic fleet
-        join_kwargs["service_heartbeat_interval_seconds"] = 10
-        join_kwargs["service_max_missing_heartbeats"] = 1_000_000
+    # the service would otherwise declare a silent task dead after its
+    # heartbeat timeout and jax's error-polling thread would terminate
+    # every survivor — the membership lease layer is the liveness
+    # authority in an elastic fleet, so its timeout is out of reach
+    heartbeat = 10_000_000 if elastic else 100   # 100 s: jax's default
     import jax
     from ..faults import retry_call
 
     def _join():
         try:
-            if not join_kwargs:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                    initialization_timeout=max(1, int(timeout)))
-            else:
-                # the public wrapper does not forward the heartbeat
-                # knobs — replicate its two lines (backend guard +
-                # global_state.initialize) with them added
-                from jax._src import distributed as _jdist
-                from jax._src import xla_bridge as _xb
-                if _xb.backends_are_initialized():
-                    raise MXNetError(
-                        "init_process_group(elastic=True) must run "
-                        "before any JAX computation initializes the "
-                        "backend")
-                _jdist.global_state.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                    initialization_timeout=max(1, int(timeout)),
-                    **join_kwargs)
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes,
+                process_id=process_id,
+                initialization_timeout=max(1, int(timeout)),
+                heartbeat_timeout_seconds=heartbeat)
         except Exception:
             # a failed connect leaves jax's global client/service assigned
             # (State.initialize sets them BEFORE connect()), and a retry
@@ -455,14 +430,13 @@ def _allgather_bytes_kv(data: bytes, timeout: float):
     Peers are the ACTIVE member set: after a re-form the gather spans
     the survivors only, indexed by logical rank."""
     import base64
-    from jax._src import distributed
 
     # the KV gather is a blocking fleet-wide wait: measured as a span so
     # it lands in the histogram AND — inside a traced region (a step or
     # re-form trace) — as a child span attributing collective time
     from ..observability.trace import span as _span
     global _agb_gen
-    client = distributed.global_state.client
+    client = _coordination.client()
     me = phys_rank()
     members = active_members()
     with _gen_lock:
@@ -598,11 +572,10 @@ def kv_publish(prefix: str, payload: bytes) -> None:
     incarnation's state immediately instead of serving the dead
     process's frozen payload until the new counter catches up."""
     import base64
-    from jax._src import distributed
     if not is_initialized():
         raise MXNetError("kv_publish requires an initialized process "
                          "group (init_process_group)")
-    client = distributed.global_state.client
+    client = _coordination.client()
     r = phys_rank()   # stable across re-forms: a host's namespace is its
     own = f"{prefix}/{r}"   # ORIGINAL id, so survivors' keys never move
     with _kv_pub_lock:
@@ -643,11 +616,10 @@ def kv_collect(prefix: str):
     blocks on a peer: a rank that has not published yet is simply
     absent from this collect and present in a later one."""
     import base64
-    from jax._src import distributed
     if not is_initialized():
         raise MXNetError("kv_collect requires an initialized process "
                          "group (init_process_group)")
-    client = distributed.global_state.client
+    client = _coordination.client()
     newest = {}            # rank -> (gen, value)
     for key, value in client.key_value_dir_get(prefix):
         parts = key.rsplit("/", 2)
@@ -673,10 +645,9 @@ def kv_purge_rank(prefix: str, dead_rank: int) -> int:
     a later collect — the restart-safety purge in :func:`kv_publish`
     only covers the SAME rank coming back, not a rank that never
     returns."""
-    from jax._src import distributed
     if not is_initialized():
         return 0
-    client = distributed.global_state.client
+    client = _coordination.client()
     tag = str(int(dead_rank))
     removed = 0
     try:
@@ -726,7 +697,6 @@ def _barrier_kv(name: str, timeout: Optional[float] = None) -> None:
     collective, and it is fence-scoped so a fenced-out incarnation's
     barriers can never alias the re-formed group's."""
     global _barrier_gen   # noqa: PLW0603 — lockstep generation counter
-    from jax._src import distributed
     with _gen_lock:
         gen = _barrier_gen
         _barrier_gen += 1
@@ -741,7 +711,7 @@ def _barrier_kv(name: str, timeout: Optional[float] = None) -> None:
     with _span("dist.barrier_kv_us", args={"name": name, "gen": gen}):
         _deadline_wait(
             f"barrier '{name}' gen {gen} over ranks {list(members)}",
-            timeout, distributed.global_state.client.wait_at_barrier,
+            timeout, _coordination.client().wait_at_barrier,
             f"mxtpu_barrier_{fence_generation()}_{name}_{gen}",
             timeout_ms, _barrier_ids(members))
 

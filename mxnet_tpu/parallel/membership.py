@@ -65,7 +65,7 @@ from ..faults import Deadline, DeadlineExceeded
 from ..observability import tracing as _tracing
 from ..observability.flight import recorder as _flight_recorder
 from ..observability.registry import registry as _metrics_registry
-from . import dist
+from . import _coordination, dist
 
 __all__ = ["MembershipManager", "LeaseTracker", "ReformResult",
            "FleetReformed", "HostFenced", "FleetLost",
@@ -535,7 +535,6 @@ class MembershipManager:
         ``MXTPU_DIST_TIMEOUT``), and an absent peer raises the typed
         ``DeadlineExceeded`` the resilience layer converts into a
         forced scan + re-form."""
-        from jax._src import distributed
         if timeout is None:
             timeout = max(2.0 * self.lease_ttl, 4 * self.heartbeat_interval)
         with self._lock:
@@ -546,7 +545,7 @@ class MembershipManager:
         dist._deadline_wait(
             f"membership step_barrier {n} (fence {fence}) over ranks "
             f"{list(members)}", timeout,
-            distributed.global_state.client.wait_at_barrier,
+            _coordination.client().wait_at_barrier,
             f"mxtpu_step_{fence}_{n}", timeout_ms, list(members))
 
     # -- the re-form protocol -----------------------------------------------
@@ -655,12 +654,11 @@ class MembershipManager:
             self._purge_dead(dead, fence)
         # rejoin barrier OVER THE NEW SET: every survivor has installed
         # before anyone's next collective
-        from jax._src import distributed
         timeout = max(1.0, deadline.remaining())
         try:
             dist._deadline_wait(
                 f"re-form rejoin barrier (fence {fence_next})", timeout,
-                distributed.global_state.client.wait_at_barrier,
+                _coordination.client().wait_at_barrier,
                 f"mxtpu_reform_{fence_next}",
                 max(1000, int(timeout * 1000)), list(members))
         except DeadlineExceeded as exc:
@@ -862,8 +860,7 @@ def _hard_exit(code: int) -> None:
     except Exception:   # noqa: BLE001 — exiting regardless
         pass
     try:
-        from jax._src import distributed as _jdist
-        if _jdist.global_state.service is not None:
+        if _coordination.hosts_service():
             # this process HOSTS the coordination service: its death
             # severs every peer's fabric mid-RPC, and jax's
             # error-polling thread SIGABRTs a peer whose poll hits the
@@ -918,9 +915,7 @@ def _install_dirty_exit() -> None:
 
 # -- module helpers ----------------------------------------------------------
 
-def _client():
-    from jax._src import distributed
-    return distributed.global_state.client
+_client = _coordination.client
 
 
 def _kv_set(key: str, value: str) -> None:

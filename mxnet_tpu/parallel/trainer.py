@@ -751,21 +751,33 @@ class ShardedTrainer:
             return
         self._build_jits()
 
+    def _bytes_per_device(self, what: str, arrays) -> dict:
+        """``arrays``: a callable giving the arrays, read once built."""
+        if not self._built:
+            raise MXNetError(f"run at least one step() before {what}()")
+        out: dict = {}
+        for leaf in arrays():
+            for sh in leaf.addressable_shards:
+                d = sh.device.id
+                out[d] = out.get(d, 0) + int(sh.data.nbytes)
+        return out
+
     def opt_state_bytes_per_device(self) -> dict:
         """Actually-resident optimizer-state bytes per device id — the
         ZeRO acceptance metric.  At stage 0 every chip carries the full
         state; at stage >= 1 each chip carries ~1/dp of every
         partitionable tensor."""
         import jax
-        if not self._built:
-            raise MXNetError("run at least one step() before "
-                             "opt_state_bytes_per_device()")
-        out: dict = {}
-        for leaf in jax.tree.leaves(self._state):
-            for sh in leaf.addressable_shards:
-                d = sh.device.id
-                out[d] = out.get(d, 0) + int(sh.data.nbytes)
-        return out
+        return self._bytes_per_device(
+            "opt_state_bytes_per_device",
+            lambda: jax.tree.leaves(self._state))
+
+    def param_bytes_per_device(self) -> dict:
+        """Actually-resident parameter (and aux) bytes per device id:
+        which devices hold the weights the trainer owns, and how much of
+        them each."""
+        return self._bytes_per_device(
+            "param_bytes_per_device", lambda: self._pvals + self._avals)
 
     def peak_opt_state_bytes(self) -> int:
         """max over devices of :meth:`opt_state_bytes_per_device`."""
@@ -777,17 +789,10 @@ class ShardedTrainer:
         the ROW-SHARDED tables (RowShardedEmbedding) — the dp-sharded
         table acceptance metric, sibling of
         :meth:`opt_state_bytes_per_device`."""
-        if not self._built:
-            raise MXNetError("run at least one step() before "
-                             "table_bytes_per_device()")
-        out: dict = {}
-        for p, v in zip(self._train_params, self._pvals):
-            if getattr(p, "_row_shard_axis", None) is None:
-                continue
-            for sh in v.addressable_shards:
-                d = sh.device.id
-                out[d] = out.get(d, 0) + int(sh.data.nbytes)
-        return out
+        return self._bytes_per_device(
+            "table_bytes_per_device",
+            lambda: [v for p, v in zip(self._train_params, self._pvals)
+                     if getattr(p, "_row_shard_axis", None) is not None])
 
     def peak_table_bytes(self) -> int:
         """max over devices of :meth:`table_bytes_per_device` — what one
@@ -1051,6 +1056,28 @@ class ShardedTrainer:
         if isinstance(out, tuple):
             return tuple(NDArray(o, ctx=self._ctx) for o in out)
         return NDArray(out, ctx=self._ctx)
+
+    def lower_step(self, x, y):
+        """The train step for this batch, lowered (``jax.stages.Lowered``)
+        against the live state without running or donating it.
+        ``.compile()`` gives the program as the backend builds it: its
+        ``as_text()`` is where a check reads which kernels
+        (``tpu_custom_call``) and collectives (``reduce-scatter``,
+        ``all-gather``) the step really carries, its
+        ``memory_analysis()`` what it needs on each device.  With a
+        persistent compilation cache on, that compile is a cache read."""
+        import jax
+        import jax.numpy as jnp
+        xv, yv = self.shard_batch(x, y)
+        if not isinstance(xv, tuple):
+            xv = (xv,)
+        scalars = (jax.random.PRNGKey(0), jnp.asarray(1, jnp.int32),
+                   jnp.asarray(0.0, jnp.float32),
+                   jnp.asarray(1.0, jnp.float32))
+        guard = (self._gstate,) if self._guard else ()
+        return self._jit_step.lower(
+            self._pvals, self._avals, self._state, *scalars, *guard,
+            xv, yv)
 
     def _checkpointer(self):
         # one long-lived async checkpointer: save() returns once the

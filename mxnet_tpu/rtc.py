@@ -34,7 +34,7 @@ class PallasKernel:
         self._kwargs = pallas_kwargs
         self._compiled = {}
 
-    def _build(self, shapes, dtypes, out_shape, grid):
+    def _build(self, shapes, dtypes, out_shape, grid, interpret):
         import jax
         from jax.experimental import pallas as pl
         out_shape = out_shape or self._out_shape or shapes[0]
@@ -43,9 +43,10 @@ class PallasKernel:
         g = grid if grid is not None else self._grid
         if g is not None:
             kwargs["grid"] = g
-        # Mosaic compiles for TPU; on the CPU test mesh fall back to the
-        # pallas interpreter so kernels stay testable everywhere
-        if jax.default_backend() == "cpu":
+        # Mosaic compiles for TPU; data that lives elsewhere (the CPU test
+        # mesh, a host context on a TPU machine) runs the pallas
+        # interpreter so kernels stay testable everywhere
+        if interpret:
             kwargs.setdefault("interpret", True)
         call = pl.pallas_call(
             self._fn,
@@ -58,15 +59,19 @@ class PallasKernel:
         """Run the kernel; returns a new NDArray (TPU buffers are
         immutable — unlike the reference's in-place CUDA launches, the
         output is the return value)."""
+        from .kernels.multi_sgd import _interpret
         vals = [a._read() for a in args]
+        interpret = _interpret(vals[0])
         key = (tuple(v.shape for v in vals),
                tuple(str(v.dtype) for v in vals),
                tuple(out_shape) if out_shape else None,
-               grid if not isinstance(grid, list) else tuple(grid))
+               grid if not isinstance(grid, list) else tuple(grid),
+               interpret)
         fn = self._compiled.get(key)
         if fn is None:
             fn = self._build([v.shape for v in vals],
-                             [v.dtype for v in vals], out_shape, grid)
+                             [v.dtype for v in vals], out_shape, grid,
+                             interpret)
             self._compiled[key] = fn
         out = fn(*vals)
         return NDArray(out, ctx=args[0].context)

@@ -37,7 +37,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as _np
 
 from ..base import MXNetError, get_env, hot_path, jax_compute_dtype
-from ..ndarray import NDArray, array as nd_array
+from ..ndarray import NDArray
 from ..observability import tracing as _tracing
 from ..observability.flight import recorder as _flight_recorder
 from ..observability.registry import registry
@@ -419,9 +419,9 @@ class ModelServer:
         bucket) signature — lock-free, so :meth:`swap_block` can stage a
         full replacement graph set while live traffic keeps hitting the
         current one."""
-        examples = [nd_array(_np.zeros((batch,) + tuple(shape),
-                                       dtype=dt))
-                    for shape, dt in key]
+        examples = block.example_inputs(
+            [_np.zeros((batch,) + tuple(shape), dtype=dt)
+             for shape, dt in key])
         from ..gluon.block import HybridBlock
         if isinstance(block, HybridBlock):
             g = block.cached_graph(*examples).raw

@@ -27,16 +27,11 @@ Design:
   proof holds because the wiring seams are installed hooks, not direct
   calls).
 
-Two payload formats, matching the two compile paths in the repo:
-
-- **pjrt** — exact-mode bulk segments compile through the raw PJRT
-  client (``device.client.compile``); ``client.serialize_executable``
-  round-trips those directly.
-- **jit** — cached-graph executables are ``jax.jit`` artifacts; the AOT
-  ``jax.experimental.serialize_executable`` pickle (payload + in/out
-  trees) round-trips a ``lowered.compile()`` result.  Entries are
-  trusted local state (same trust level as jax's own persistent cache,
-  which uses the same mechanism).
+One payload format: every wired site (exact-mode bulk segments, cached
+graphs) holds a ``jax.stages.Compiled``, and the AOT
+``jax.experimental.serialize_executable`` pickle (payload + in/out
+trees) round-trips it.  Entries are trusted local state (same trust
+level as jax's own persistent cache, which uses the same mechanism).
 
 Metrics (process-global registry): ``tuning.compile_cache_hits`` /
 ``_misses`` / ``_stores`` / ``_errors``, and ``tuning.compiles`` — the
@@ -44,10 +39,15 @@ count of actual backend compiles performed at cache-wired sites.  A
 warm-started process replaying only previously-seen signatures holds
 ``tuning.compiles`` at ~0; the subprocess test asserts exactly that.
 
-Enabled by ``MXTPU_COMPILE_CACHE_DIR``; with ``MXTPU_COMPILE_CACHE_JAX``
-(default on) the same directory also hosts jax's own persistent
-compilation cache (``<dir>/jax``), so plain ``jax.jit`` paths — per-op
-fns, training vjp graphs — reuse compiles across processes too.
+Where the cache lives is decided from outside, by :func:`cache_dir`:
+``JAX_COMPILATION_CACHE_DIR`` if it is set — jax reads that variable
+itself for its own persistent cache, this module's entries go in the
+``mxnet_tpu`` directory under it, and nothing here touches
+``jax_compilation_cache_dir`` — else ``MXTPU_COMPILE_CACHE_DIR``, which
+(with ``MXTPU_COMPILE_CACHE_JAX``, default on) also hosts jax's own
+cache in ``<dir>/jax``, so plain ``jax.jit`` paths — per-op fns,
+training vjp graphs — reuse compiles across processes too.  Neither
+set: no persistent cache.
 """
 from __future__ import annotations
 
@@ -55,17 +55,30 @@ import hashlib
 import os
 import pickle
 import threading
-import warnings
 from typing import Optional
 
 from ..base import get_env
 from ..observability.registry import registry as _metrics_registry
 
-__all__ = ["CompileCache", "active", "configure", "install",
-           "CACHE_DIR_ENV", "CACHE_JAX_ENV"]
+__all__ = ["CompileCache", "active", "cache_dir", "configure",
+           "CACHE_DIR_ENV", "CACHE_JAX_ENV", "JAX_CACHE_DIR_ENV"]
 
 CACHE_DIR_ENV = "MXTPU_COMPILE_CACHE_DIR"
 CACHE_JAX_ENV = "MXTPU_COMPILE_CACHE_JAX"
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def _jax_cache_dir() -> str:
+    return os.environ.get(JAX_CACHE_DIR_ENV, "").strip()
+
+
+def cache_dir() -> Optional[str]:
+    """The directory this module's entries live in, or None (module
+    docstring: the environment decides, jax's own variable first)."""
+    outer = _jax_cache_dir()
+    if outer:
+        return os.path.join(outer, "mxnet_tpu")
+    return (get_env(CACHE_DIR_ENV) or "").strip() or None
 
 
 def _fingerprint() -> str:
@@ -173,35 +186,12 @@ class CompileCache:
                 pass
             return False
 
-    # -- pjrt tier: exact-mode bulk segments ---------------------------------
-    def load_pjrt(self, key: str, client, options):
-        """Deserialize a raw PJRT executable, or None on miss.  The
-        caller supplies the same CompileOptions it would compile with —
-        PJRT needs them to rebuild the device assignment."""
-        data = self.load_bytes(key)
-        if data is None:
-            self._c_misses.inc()
-            return None
-        try:
-            exe = client.deserialize_executable(data, options)
-        except Exception:   # noqa: BLE001 — stale/foreign entry: a miss,
-            self._c_errors.inc()       # never a crash on the compile path
-            return None
-        self._c_hits.inc()
-        return exe
-
-    def store_pjrt(self, key: str, client, exe) -> None:
-        self._c_compiles.inc()         # a store follows a real compile
-        try:
-            data = client.serialize_executable(exe)
-        except Exception:   # noqa: BLE001 — backend without executable
-            self._c_errors.inc()       # serialization: run-only, no disk
-            return
-        self.store_bytes(key, bytes(data))
-
-    # -- jit tier: AOT-compiled jax.jit executables --------------------------
-    def load_jit(self, key: str):
-        """Deserialize an AOT ``Compiled`` callable, or None on miss."""
+    # -- AOT-compiled jax.jit executables ------------------------------------
+    def load_jit(self, key: str, device=None):
+        """Deserialize an AOT ``Compiled`` callable, or None on miss.
+        ``device``: the one device the executable was compiled for (it
+        names its devices by id); None loads over the default backend's
+        devices."""
         data = self.load_bytes(key)
         if data is None:
             self._c_misses.inc()
@@ -209,8 +199,13 @@ class CompileCache:
         try:
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = pickle.loads(data)
-            compiled = _se.deserialize_and_load(payload, in_tree,
-                                                out_tree)
+            if device is None:
+                compiled = _se.deserialize_and_load(payload, in_tree,
+                                                    out_tree)
+            else:
+                compiled = _se.deserialize_and_load(
+                    payload, in_tree, out_tree, backend=device.client,
+                    execution_devices=[device])
         except Exception:   # noqa: BLE001 — toolchain drift or torn
             self._c_errors.inc()       # entry reads as a plain miss
             return None
@@ -223,8 +218,8 @@ class CompileCache:
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = _se.serialize(compiled)
             data = pickle.dumps((payload, in_tree, out_tree))
-        except Exception:   # noqa: BLE001 — same degradation as pjrt
-            self._c_errors.inc()
+        except Exception:   # noqa: BLE001 — backend without executable
+            self._c_errors.inc()       # serialization: run-only, no disk
             return
         self.store_bytes(key, data)
 
@@ -233,40 +228,36 @@ class CompileCache:
 
 _active_lock = threading.Lock()
 _active: Optional[CompileCache] = None
-_configured_for: Optional[str] = None
-_jax_cache_warned = False
 
 
 def active() -> Optional[CompileCache]:
-    """THE process-global cache, or None when ``MXTPU_COMPILE_CACHE_DIR``
-    is unset.  Resolved live so a test (or a late-exported env) can
+    """THE process-global cache, or None when :func:`cache_dir` names no
+    directory.  Resolved live so a test (or a late-exported env) can
     enable it after import; the instance is rebuilt if the dir changes."""
-    global _active, _configured_for
-    path = (get_env(CACHE_DIR_ENV) or "").strip()
-    if not path:
+    global _active
+    path = cache_dir()
+    if path is None:
         return None
+    path = os.path.abspath(path)
     inst = _active
-    if inst is not None and _configured_for == path:
+    if inst is not None and inst.path == path:
         return inst
     with _active_lock:
-        if _active is None or _configured_for != path:
+        if _active is None or _active.path != path:
             _active = CompileCache(path)
-            _configured_for = path
             _wire(_active)
     return _active
 
 
-def configure(path: Optional[str] = None) -> Optional[CompileCache]:
-    """Explicit enable: point the cache at ``path`` (exported to the
-    env so child processes inherit it) and wire every seam.  With no
-    argument, just resolves from the env like :func:`active`."""
-    if path:
+def configure(path: str) -> CompileCache:
+    """Explicit enable for a program that wants a cache whatever the
+    environment says (``bench.py``, ``chip_smoke.py``): where
+    ``JAX_COMPILATION_CACHE_DIR`` is set that directory is used and
+    ``path`` is ignored; where it is not, ``path`` is exported as
+    ``MXTPU_COMPILE_CACHE_DIR`` (child processes inherit it)."""
+    if not _jax_cache_dir():
         os.environ[CACHE_DIR_ENV] = os.path.abspath(path)
     return active()
-
-
-# back-compat alias: install() == configure-from-env
-install = configure
 
 
 def _wire(cache: CompileCache) -> None:
@@ -275,65 +266,53 @@ def _wire(cache: CompileCache) -> None:
     the frontend layers free of a tuning import."""
     from ..ndarray import register as _register
     _register._install_persist_hooks(_segment_lookup, _segment_store)
-    _maybe_configure_jax_cache(cache)
+    _configure_jax_cache(cache)
 
 
-def _maybe_configure_jax_cache(cache: CompileCache) -> None:
-    """Point jax's own persistent compilation cache at ``<dir>/jax`` so
-    the plain ``jax.jit`` paths (per-op fns, training vjp graphs) also
-    survive restarts.  Best-effort: refused config updates (backend
-    already live on some versions) only cost the jit tier."""
-    global _jax_cache_warned
-    if not get_env(CACHE_JAX_ENV):
-        return
-    try:
-        import jax
+def _configure_jax_cache(cache: CompileCache) -> None:
+    """Let jax's own persistent compilation cache keep the plain
+    ``jax.jit`` paths (per-op fns, training vjp graphs) across restarts:
+    in the directory the environment gave it, else in ``<dir>/jax``."""
+    import jax
+    if not _jax_cache_dir():
+        if not get_env(CACHE_JAX_ENV):
+            return
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(cache.path, "jax"))
-        # default thresholds skip sub-second compiles and tiny
-        # executables — this repo's segment graphs are exactly those
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          0)
-    except Exception as e:   # noqa: BLE001 — version drift in config
-        if not _jax_cache_warned:  # names must not disable OUR tiers
-            _jax_cache_warned = True
-            warnings.warn(
-                f"persistent compile cache: could not configure jax's "
-                f"own compilation cache ({e}); segment/cached-graph "
-                f"tiers remain active", RuntimeWarning, stacklevel=2)
+    # default thresholds skip sub-second compiles and tiny executables —
+    # this repo's segment graphs are exactly those
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 # -- the segment seam (installed into ndarray.register) ---------------------
 
-def _segment_lookup(canonical: str, device, options):
+def _segment_lookup(canonical: str, device):
     """Hook: exact-mode segment cache miss → try the disk tier."""
     cache = active()
     if cache is None:
         return None
-    key = cache.entry_key("seg", canonical)
-    return cache.load_pjrt(key, device.client, options)
+    return cache.load_jit(cache.entry_key("seg", canonical), device)
 
 
-def _segment_store(canonical: str, device, exe) -> None:
+def _segment_store(canonical: str, exe) -> None:
     """Hook: a segment executable was compiled → persist it."""
     cache = active()
     if cache is None:
         return
-    key = cache.entry_key("seg", canonical)
-    cache.store_pjrt(key, device.client, exe)
+    cache.store_jit(cache.entry_key("seg", canonical), exe)
 
 
 # -- the cached-graph seam (called from gluon.block) ------------------------
 
-def aot_compile(lowered, kind: str = "graph"):
+def aot_compile(lowered, kind: str = "graph", device=None):
     """Compile a ``jax.jit(...).lower(...)`` artifact through the
     persistent cache: the lowered StableHLO text (plus the backend
     fingerprint) is the key, so identical traces in a fresh process
-    deserialize instead of compiling.  Returns the AOT ``Compiled``
-    callable, or None when the cache is disabled (callers then keep
-    their plain jit path)."""
+    deserialize instead of compiling.  ``device`` is the one device the
+    graph is lowered for (see :meth:`CompileCache.load_jit`).  Returns
+    the AOT ``Compiled`` callable, or None when the cache is disabled
+    (callers then keep their plain jit path)."""
     cache = active()
     if cache is None:
         return None
@@ -343,7 +322,7 @@ def aot_compile(lowered, kind: str = "graph"):
         cache._c_errors.inc()
         return None
     key = cache.entry_key(kind, canonical)
-    compiled = cache.load_jit(key)
+    compiled = cache.load_jit(key, device)
     if compiled is not None:
         return compiled
     compiled = lowered.compile()

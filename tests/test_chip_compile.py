@@ -1,0 +1,139 @@
+"""The kernels of the main paths, compiled for a TPU v5e that is
+described and not attached (on-chip-measurement guide §2, rehearsal 3).
+
+Interpret mode cannot see what Mosaic refuses — block shapes, loop
+carries, memory spaces — and the chip is not here.  The TPU compiler is:
+``jax.experimental.topologies`` describes a ``v5e:2x2`` host, and
+``jit(...).lower(shapes on its device).compile()`` raises what the chip's
+compiler would raise.  Nothing runs; a compile that passes is not a chip
+run (``chip_smoke.py`` is).
+
+All of these stay in THIS file: the worker that describes the topology
+loads libtpu and keeps it until it exits, so a second file on another
+xdist worker could not.  The topology is described inside a fixture, never
+at import.
+"""
+import os
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    had_log_dir = "TPU_LOG_DIR" in os.environ
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs in /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to jax's persistent cache
+    # but cannot be read back without the chip: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    cc.reset_cache()
+    if not had_log_dir:
+        os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args):
+    import jax
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# BERT-base at batch 8, seq 256: 8 x 12 heads of 64; and the causal case
+# tests/test_kernels_tpu.py runs on the chip
+@pytest.mark.parametrize("shape,dtype,valid_len,causal", [
+    ((96, 256, 64), "float32", False, False),
+    ((96, 256, 64), "float32", True, False),
+    ((96, 256, 64), "bfloat16", False, False),
+    ((96, 256, 64), "bfloat16", True, False),
+    ((2, 256, 128), "float32", False, True),
+])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype,
+                                          valid_len, causal):
+    import jax
+    from mxnet_tpu.kernels import flash_attention
+
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [qkv, qkv, qkv]
+    if valid_len:
+        args.append(jax.ShapeDtypeStruct((shape[0],), "float32",
+                                         sharding=one_chip))
+
+    def fwd(q, k, v, vl=None):
+        return flash_attention(q, k, v, causal=causal, valid_len=vl,
+                               interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(fwd, *args)
+
+
+def _resnet50_shapes():
+    """Every trainable tensor of ResNet-50 v1 (161 of them, 25.6M
+    elements): the tail of small tensors is what the multi-tensor apply
+    exists for."""
+    shapes = [(64, 3, 7, 7), (64,), (64,)]
+    c_in = 64
+    for blocks, mid in ((3, 64), (4, 128), (6, 256), (3, 512)):
+        out = 4 * mid
+        for b in range(blocks):
+            shapes += [(mid, c_in, 1, 1), (mid,), (mid,),
+                       (mid, mid, 3, 3), (mid,), (mid,),
+                       (out, mid, 1, 1), (out,), (out,)]
+            if b == 0:
+                shapes += [(out, c_in, 1, 1), (out,), (out,)]
+            c_in = out
+    return shapes + [(1000, 2048), (1000,)]
+
+
+@pytest.mark.parametrize("dtype,momentum", [
+    ("float32", None), ("float32", 0.9), ("bfloat16", 0.9)])
+def test_multi_sgd_compiles_for_v5e(one_chip, dtype, momentum):
+    import jax
+    from mxnet_tpu.kernels import fused_multi_sgd, fused_multi_sgd_mom
+
+    shapes = _resnet50_shapes()
+    assert len(shapes) == 161
+    assert sum(int(np.prod(s)) for s in shapes) == 25_557_032
+    ws = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip) for s in shapes]
+    per_tensor = jax.ShapeDtypeStruct((len(shapes),), "float32",
+                                      sharding=one_chip)
+    if momentum is None:
+        def update(w, g, lrs, wds):
+            return fused_multi_sgd(w, g, lrs, wds, rescale_grad=1 / 128,
+                                   interpret=False)
+        text = _compiled_text(update, ws, ws, per_tensor, per_tensor)
+    else:
+        def update(w, g, m, lrs, wds):
+            return fused_multi_sgd_mom(w, g, m, lrs, wds, momentum=momentum,
+                                       rescale_grad=1 / 128, interpret=False)
+        text = _compiled_text(update, ws, ws, ws, per_tensor, per_tensor)
+    assert "tpu_custom_call" in text
+
+
+def test_rtc_kernel_compiles_for_v5e(one_chip):
+    import jax
+    from mxnet_tpu import rtc
+
+    def axpy(x_ref, y_ref, o_ref):
+        o_ref[...] = 2.0 * x_ref[...] + y_ref[...]
+
+    kernel = rtc.PallasModule().add_kernel("axpy", axpy)
+    shape, dtype = (256, 512), np.dtype("float32")
+    call = kernel._build([shape, shape], [dtype, dtype], None, None,
+                         interpret=False)
+    arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    assert "tpu_custom_call" in call.lower(arg, arg).compile().as_text()

@@ -218,6 +218,45 @@ def test_context_placement():
     np.testing.assert_allclose(y.asnumpy(), x.asnumpy())
 
 
+def test_default_context_is_the_host():
+    """The reference's default: work reaches an accelerator only because
+    the caller names it."""
+    assert mx.current_context() == mx.cpu(0)
+    assert nd.ones((2,)).context == mx.cpu(0)
+    with mx.cpu(1):
+        assert mx.current_context() == mx.cpu(1)
+    assert mx.current_context() == mx.cpu(0)
+
+
+@pytest.mark.parametrize("make", [mx.tpu, mx.gpu])
+def test_accelerator_context_raises_without_the_device(make):
+    """No fall-through to a host core: on a CPU-only process mx.tpu(0) /
+    mx.gpu(0) fail where they are resolved, as the reference's gpu(0) does
+    on a host without one."""
+    assert mx.context.num_tpus() == 0 and mx.context.num_gpus() == 0
+    with pytest.raises(mx.MXNetError, match="0 such device"):
+        make(0).device
+    with pytest.raises(mx.MXNetError):
+        nd.ones((2,), ctx=make(0))
+
+
+@pytest.mark.parametrize("kind", ["tpu", "gpu"])
+def test_accelerator_context_index_is_exact(monkeypatch, kind):
+    """mx.tpu(n) is device n or an error, never n modulo the count.
+    Virtual CPU devices cannot stand in for TPUs, so the resolver runs
+    against a stubbed device list."""
+    from mxnet_tpu import context
+    chips = ["chip0", "chip1"]
+    monkeypatch.setattr(
+        context, "_platform_devices",
+        lambda kinds: chips if "tpu" in kinds else [])
+    assert context._resolve_device(kind, 0) == "chip0"
+    assert context._resolve_device(kind, 1) == "chip1"
+    for bad in (2, 3, -1):
+        with pytest.raises(mx.MXNetError, match="2 such device"):
+            context._resolve_device(kind, bad)
+
+
 def test_waitall_and_naive_engine():
     x = nd.ones((8, 8))
     y = nd.dot(x, x)

@@ -388,6 +388,39 @@ def test_zero_opt_state_bytes_sharded():
     assert len(set(per_dev.values())) == 1
 
 
+@pytest.mark.parametrize("guard", [False, True])
+def test_lower_step_reads_the_compiled_program(guard):
+    """lower_step() hands back the very step the trainer runs, lowered
+    against its live state: compiling it shows the collectives ZeRO put
+    there, and neither runs nor donates anything (the next step still
+    works, bitwise as if it had not been looked at)."""
+    tr, _, _ = _zero_run(1, 1, guard=guard, steps=1)
+    rng = np.random.RandomState(11)
+    x = rng.randn(16, 16).astype(np.float32)
+    y = rng.randint(0, 10, (16,))
+    text = tr.lower_step(x, y).compile().as_text()
+    assert "all-gather" in text             # updated params gathered back
+    assert not tr.donation_consumed
+    looked = float(tr.step(x, y).asnumpy())
+    tr_ref, _, _ = _zero_run(1, 1, guard=guard, steps=1)
+    assert looked == float(tr_ref.step(x, y).asnumpy())
+
+
+def test_param_bytes_per_device_counts_every_holder():
+    tr, _, _ = _zero_run(0, 1, steps=1)
+    per_dev = tr.param_bytes_per_device()
+    n_param_bytes = sum(int(np.prod(p.shape)) * 4 for p in
+                        tr._block.collect_params().values())
+    assert len(per_dev) == 8 and set(per_dev.values()) == {n_param_bytes}
+    net = _mlp("pbytes_")
+    net.initialize()
+    fresh = par.ShardedTrainer(net, gloss.SoftmaxCrossEntropyLoss(), "sgd")
+    with pytest.raises(mx.MXNetError, match="param_bytes_per_device"):
+        fresh.param_bytes_per_device()
+    with pytest.raises(mx.MXNetError, match="opt_state_bytes_per_device"):
+        fresh.opt_state_bytes_per_device()
+
+
 def test_accum_requires_divisible_batch():
     np.random.seed(0)
     net = _mlp("accval_")

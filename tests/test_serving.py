@@ -131,6 +131,31 @@ def test_served_bitwise_equals_direct_on_controlled_batch():
         np.testing.assert_array_equal(out, r)
 
 
+def test_served_model_runs_where_its_parameters_live():
+    """Requests arrive as host arrays on threads whose default context is
+    cpu(0).  The served graph must still be built and run on the context
+    the parameters were initialized on — on a TPU host that is the chip,
+    here a second CPU device stands in for it."""
+    ctx = mx.cpu(1)
+    net = gluon.nn.HybridSequential()
+    with net.name_scope():
+        net.add(gluon.nn.Dense(6, in_units=16))
+    net.initialize(ctx=ctx)
+    net.hybridize()
+    x = np.random.default_rng(3).standard_normal((16,)).astype(np.float32)
+    g = net.cached_graph(np.stack([x, x]))       # host example values
+    assert {d for o in g.raw(np.stack([x, x])) for d in o.devices()} \
+        == {ctx.device}
+    srv = ModelServer(net, max_batch=2, deadline_ms=0, workers=1)
+    try:
+        srv.start()
+        out = srv.infer(x, timeout=60)
+    finally:
+        srv.stop()
+    np.testing.assert_allclose(
+        out, net(mx.nd.array(x[None], ctx=ctx)).asnumpy()[0], rtol=1e-6)
+
+
 def test_concurrent_clients_bitwise_elementwise():
     """4 client threads x 8 requests against an elementwise model:
     whatever batches the continuous batcher forms, every served row is

@@ -2,8 +2,9 @@
 backend (reference: tests/python/gpu/test_operator_gpu.py imports the
 entire CPU unittest module and re-runs it on gpu(0) — SURVEY.md §4.3).
 
-The CPU files guard their own device assumptions, so a straight
-re-export under the TPU-live conftest re-executes every op on the chip.
+The CPU files name no context, so a straight re-export under this
+directory's conftest (default context tpu(0)) re-executes every op on the
+chip.
 """
 from tests.test_ndarray import *          # noqa: F401,F403
 from tests.test_autograd import *         # noqa: F401,F403
@@ -24,11 +25,3 @@ from tests.test_ops_tail import *         # noqa: F401,F403
 from tests.test_sldwin import *           # noqa: F401,F403
 from tests.test_dgl import *              # noqa: F401,F403
 from tests.test_numpy_frontend import *   # noqa: F401,F403
-
-# test_kernels_tpu's module-level skipif mark rode in with the star
-# import; the conftest's TPU gate already covers the no-chip case, and
-# keeping the mark here would needlessly re-evaluate the backend probe
-try:
-    del pytestmark                         # noqa: F821
-except NameError:
-    pass

@@ -3,10 +3,9 @@
 The full import-and-rerun trick (test_operator_tpu.py) covers op-level
 tests, but hybridize, Module.fit, and the sharded trainer had never
 re-run on the chip.  Re-importing test_gluon/test_module wholesale would
-be pathological over the remote compiler (hundreds of per-op dispatch
-compilations — the constraint documented in PERF.md's outage log), so
-this file is a CURATED set: every test is whole-graph jit with a handful
-of compilations total, exactly how TPU training is supposed to run.
+mean hundreds of per-op dispatch compilations, so this file is a CURATED
+set: every test is whole-graph jit with a handful of compilations total,
+exactly how TPU training is supposed to run.
 
 Compile budget (~5 XLA computations across the file):
   1. hybridized-MLP cached fwd+vjp graph (one per shape signature)
@@ -16,8 +15,7 @@ Compile budget (~5 XLA computations across the file):
   5. Module.score's eval executor
 
 Reference parity: tests/python/gpu/ train-path coverage
-(test_gluon_gpu.py / test_module_gpu.py — SURVEY.md §4.3) re-imagined
-under the remote-compiler constraint.
+(test_gluon_gpu.py / test_module_gpu.py — SURVEY.md §4.3), curated.
 """
 import numpy as np
 
@@ -58,7 +56,7 @@ def test_hybridized_mlp_converges_on_chip():
         losses.append(float(nd.mean(L).asnumpy()))
     assert losses[-1] < 0.35 * losses[0], (losses[0], losses[-1])
     # hybridize actually cached: exactly one graph signature
-    assert len(net._cached_graph) == 1
+    assert net._cached_graph.cache_info()["currsize"] == 1
 
 
 def test_sharded_trainer_step_on_chip():
