@@ -19,6 +19,7 @@ mirroring CachedOp::Backward.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import warnings
@@ -232,7 +233,16 @@ class Block:
 
     # -- call ----------------------------------------------------------------
     def __call__(self, *args):
-        out = self.forward(*args)
+        tc = _TraceCtx.active()
+        if tc is None:
+            out = self.forward(*args)
+        else:
+            # inside a compiled program every op carries the name of the
+            # block that made it (``.../encoder/layer3/attn/...`` in the
+            # HLO's op_name metadata); the eager path pays one
+            # thread-local read
+            with tc.block_scope(self._prefix):
+                out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -282,6 +292,22 @@ class _TraceCtx:
 
     def __init__(self, substitutes: Dict[int, NDArray]):
         self.substitutes = substitutes   # id(Parameter) -> wrapper NDArray
+        self._prefixes: List[str] = []   # of the blocks being traced
+
+    @contextlib.contextmanager
+    def block_scope(self, prefix: str):
+        """``jax.named_scope`` of the block's own name: its prefix with
+        the enclosing block's prefix cut (``bertmodel0_encoder_`` inside
+        ``bertmodel0_`` is ``encoder``)."""
+        import jax
+        outer = self._prefixes[-1] if self._prefixes else ""
+        own = prefix[len(outer):] if prefix.startswith(outer) else prefix
+        self._prefixes.append(prefix)
+        try:
+            with jax.named_scope(own.rstrip("_") or "block"):
+                yield
+        finally:
+            self._prefixes.pop()
 
     def __enter__(self):
         self._old = getattr(_TraceCtx._current, "value", None)
@@ -469,9 +495,9 @@ class HybridBlock(Block):
             wrappers = [NDArray(v, ctx=ctx) for v in pvals]
             win = [NDArray(v, ctx=ctx) for v in invals]
             subs = {id(p): w for p, w in zip(params, wrappers)}
-            with _TraceCtx(subs), \
+            with _TraceCtx(subs) as tc, \
                     _autograd._RecordingScope(False, training), \
-                    _KeyScope(key):
+                    _KeyScope(key), tc.block_scope(block._prefix):
                 out = block.hybrid_forward_entry(*win)
             outs = list(out) if isinstance(out, (list, tuple)) else [out]
             out_vals = [o._read() for o in outs]
@@ -656,9 +682,9 @@ class HybridBlock(Block):
             wrappers = [NDArray(v, ctx=ctx) for v in pvals]
             win = [NDArray(v, ctx=ctx) for v in invals]
             subs = {id(p): w for p, w in zip(params, wrappers)}
-            with _TraceCtx(subs), \
+            with _TraceCtx(subs) as tc, \
                     _autograd._RecordingScope(False, False), \
-                    _KeyScope(key):
+                    _KeyScope(key), tc.block_scope(block._prefix):
                 pkw = {k: _param_data_maybe_traced(p, ctx)
                        for k, p in block._reg_params.items()}
                 out = method(nd_mod, *win, **pkw)

@@ -133,11 +133,13 @@ class MultiHeadAttention(HybridBlock):
             else:
                 out = F.flash_attention(q, k, v, valid_len, scale=scale)
         else:
-            scores = F.batch_dot(q, k, transpose_b=True) * scale
-            att = _masked_softmax(F, scores, mask)
-            if self.drop is not None:
-                att = self.drop(att)
-            out = F.batch_dot(att, v)
+            import jax
+            with jax.named_scope("attention_xla"):
+                scores = F.batch_dot(q, k, transpose_b=True) * scale
+                att = _masked_softmax(F, scores, mask)
+                if self.drop is not None:
+                    att = self.drop(att)
+                out = F.batch_dot(att, v)
         return self.proj(self._merge_heads(F, out, b, sq))
 
     def _flash_eligible(self, F, mask, valid_len) -> bool:

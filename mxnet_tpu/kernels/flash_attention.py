@@ -217,12 +217,15 @@ def _flash_core_fn():
 
     def core_bwd(causal, scale, interpret, res, g):
         q, k, v, vl = res
-        _, vjp = jax.vjp(
-            lambda a, b, c: _chunked_reference(a, b, c, vl, causal, scale),
-            q, k, v)
-        dq, dk, dv = vjp(g)
         import jax.numpy as jnp
-        return dq, dk, dv, jnp.zeros_like(vl)   # vl is a mask, not a weight
+        with jax.named_scope("flash_attention_bwd"):
+            _, vjp = jax.vjp(
+                lambda a, b, c: _chunked_reference(a, b, c, vl, causal,
+                                                   scale),
+                q, k, v)
+            dq, dk, dv = vjp(g)
+            # vl is a mask, not a weight
+            return dq, dk, dv, jnp.zeros_like(vl)
     core.defvjp(core_fwd, core_bwd)
     return core
 
@@ -232,6 +235,7 @@ def _flash_core(q, k, v, vl, causal: bool, scale: float, interpret: bool):
 
 
 def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
+    import jax
     import jax.numpy as jnp
 
     bh, lq, d = q.shape
@@ -246,13 +250,18 @@ def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
         widths[axis] = (0, pad)
         return jnp.pad(x, widths)
 
-    qp = pad_to(pad_to(q, 1, BLOCK_Q), 2, 128)
-    kp = pad_to(pad_to(k, 1, BLOCK_K), 2, 128)
-    vp = pad_to(pad_to(v, 1, BLOCK_K), 2, 128)
+    # the kernel's own pad and unpad (a head of 64 goes to 128 lanes) carry
+    # a name of their own: their device time is the kernel's to answer for
+    with jax.named_scope("flash_attention_pad"):
+        qp = pad_to(pad_to(q, 1, BLOCK_Q), 2, 128)
+        kp = pad_to(pad_to(k, 1, BLOCK_K), 2, 128)
+        vp = pad_to(pad_to(v, 1, BLOCK_K), 2, 128)
     call = _build_call(bh, qp.shape[1], kp.shape[1], qp.shape[2], lq, lk,
                        bool(causal), float(scale),
                        jnp.result_type(q).name, bool(interpret))
-    return call(vl.astype(jnp.int32), qp, kp, vp)[:, :lq, :d]
+    out = call(vl.astype(jnp.int32), qp, kp, vp)
+    with jax.named_scope("flash_attention_pad"):
+        return out[:, :lq, :d]
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,

@@ -29,6 +29,7 @@ import numpy as _np
 from ..base import MXNetError, hot_path
 from ..context import current_context
 from ..engine import PendingValue, engine, _install_flush_hook
+from ..observability.trace import span as _span
 from .. import autograd as _autograd
 
 __all__ = ["Operator", "register_op", "get_op", "list_ops", "invoke",
@@ -616,14 +617,19 @@ class _BulkSegment:
             _tls.seg = None
         if not self.nodes:
             return                    # nothing was deferred
-        eng = engine()
         # always timed: the per-flush latency histogram (engine.flush_us)
-        # is the auto-tune signal for MXNET_ENGINE_BULK_SIZE — two
-        # perf_counter() calls per SEGMENT (not per op) is noise next to
-        # the dispatch they bracket
-        _t0 = _perf_counter()   # mxlint: disable=timing-pair — feeds
-        # engine.flush_us on the per-segment hot path (a span would add
-        # a registry lookup per flush)
+        # is the auto-tune signal for MXNET_ENGINE_BULK_SIZE; as a span
+        # (the engine observes its duration itself, so no registry lookup
+        # here) the flush also lies in a profiler's trace as
+        # ``mx.engine.flush_us``
+        with _span("engine.flush_us", histogram=False) as sp:
+            hit = self._run_locked()
+        engine().on_bulk_flush(len(self.nodes), hit, sp.duration_us)
+
+    @hot_path("dispatch")
+    def _run_locked(self):
+        """Execute the segment; returns whether the fused-executable
+        cache hit (None where it was never consulted)."""
         taped = self.tapenode is not None
         # liveness: outputs whose NDArray died (or was overwritten by an
         # in-place write) before the flush need no buffer at all
@@ -635,10 +641,8 @@ class _BulkSegment:
         needed = None if taped else tuple(m.index for _, m in live)
         if not taped and not live:
             # nothing observable: the whole segment is dead code — the
-            # executable cache was never consulted (cache_hit=None)
-            eng.on_bulk_flush(len(self.nodes), None,
-                              (_perf_counter() - _t0) * 1e6)
-            return
+            # executable cache was never consulted
+            return None
         # device id in the key: an exact-mode executable is PINNED to its
         # device; same-signature segments on another
         # device must compile their own
@@ -684,8 +688,7 @@ class _BulkSegment:
             # via NDArray._read's pending barrier
             self.error = e
             raise
-        eng.on_bulk_flush(len(self.nodes), hit,
-                          (_perf_counter() - _t0) * 1e6)
+        return hit
 
 
 @hot_path("dispatch")

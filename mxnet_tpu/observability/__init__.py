@@ -10,8 +10,8 @@ registry (ROADMAP follow-up for both PRs):
   ``resilience.steps_skipped``, ``loader.batches``) with one
   ``registry().snapshot()`` returning every metric in one dict.
 - :mod:`.trace` — lightweight ``span(name)`` context managers recording
-  wall-time into histograms (and echoing to engine profiler listeners
-  when installed).
+  wall-time into histograms, each also a ``jax.profiler.TraceAnnotation``
+  (``mx.<name>``) on the profiler's clock.
 - :mod:`.export` — a Prometheus-text-format HTTP endpoint (opt-in via
   ``MXTPU_METRICS_PORT``; ``MXTPU_METRICS_AGGREGATE`` serves the
   host-labeled fleet view) and a JSONL periodic writer for headless

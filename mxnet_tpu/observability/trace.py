@@ -8,46 +8,43 @@ integration points:
 - **Registry**: every exit observes the duration into
   ``registry().histogram(name)``, so percentiles surface through
   ``snapshot()`` / the Prometheus endpoint with zero extra plumbing.
-- **Profiler**: when engine dispatch listeners are installed (i.e. the
-  profiler is running), the span additionally emits a ``span:<name>``
-  event through the same listener hook op dispatches use, so spans
-  appear in the chrome trace next to the ops they contain.
+- **The profiler's clock**: every span is also a
+  ``jax.profiler.TraceAnnotation("mx.<name>")``, so while ``jax.profiler``
+  (or ``mx.profiler`` with ``profile_all``) is tracing, the span lies in
+  the host plane of the ``.xplane.pb`` on the same clock as the device's
+  operations, and an idle gap on the device can be given to the span
+  that covers it.  With no profiler attached a ``TraceMe`` is one atomic
+  flag read.
 
-Spans nest: a thread-local stack tracks the active chain (``current()``
-returns the innermost name, ``stack()`` the whole chain outermost-first).
-The stack is maintained exception-safely — a span body that raises still
-pops and still records its duration.
+Spans nest as ``with`` blocks do, and the trace shows them nested; a span
+body that raises still records its duration.
 
-Cost discipline: entering a span is a perf_counter() call and a list
-append; exiting is a perf_counter(), a list pop, and one histogram
-observe (bisect + int adds under a lock).  No allocation beyond the span
-object, no formatting.  Spans guard paths that run per step / per flush
-/ per batch — not per op; the op hot path keeps its existing
-listener-gated timing.
+Cost discipline: entering a span is a perf_counter() call and the
+annotation's flag read; exiting is a perf_counter() and one histogram
+observe (bisect + int adds under a lock).  No formatting (the ``mx.``
+name is built once, at construction).  Spans guard paths that run per
+step / per flush / per batch — not per op; the op hot path keeps its
+existing listener-gated timing.
 """
 from __future__ import annotations
 
-import threading
 from time import perf_counter
 from typing import List, Optional
 
-from ..engine import engine
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import tracing as _tracing
 from .registry import registry
 
-__all__ = ["span", "current", "stack", "add_span_listener",
-           "remove_span_listener"]
-
-_tls = threading.local()
+__all__ = ["span", "add_span_listener", "remove_span_listener"]
 
 # span sinks: fn(name, t_end_seconds, duration_us, args) called on
 # every span exit (``args`` is the span's metadata dict or None).  The
 # profiler installs one so spans land on its chrome-trace timeline as
 # PROPER duration events (pid=host, tid=thread, chrome-trace ``args``
-# carrying step/batch ids) next to op events — unlike the
-# engine-listener echo below, installing a span listener does NOT
-# suspend bulked dispatch (spans wrap steps/flushes, not ops, so they
-# need no per-op outputs).
+# carrying step/batch ids) next to op events.  Installing a span
+# listener does NOT suspend bulked dispatch (spans wrap steps/flushes,
+# not ops, so they need no per-op outputs).
 _span_listeners: List = []
 
 
@@ -64,41 +61,24 @@ def remove_span_listener(fn) -> None:
         _span_listeners.remove(fn)
 
 
-def _stack() -> List[str]:
-    s = getattr(_tls, "stack", None)
-    if s is None:
-        s = _tls.stack = []
-    return s
-
-
-def current() -> Optional[str]:
-    """Innermost active span name on this thread, or None."""
-    s = getattr(_tls, "stack", None)
-    return s[-1] if s else None
-
-
-def stack() -> List[str]:
-    """The active span chain on this thread, outermost first (a copy)."""
-    return list(getattr(_tls, "stack", ()))
-
-
 class span:
     """``with span("resilience.step_us"): ...`` — record the body's
     wall-time into the histogram of that name.
 
-    ``histogram=False`` keeps the nesting/bookkeeping (and the profiler
-    event) without creating a registry metric — for ad-hoc scoping.
+    ``histogram=False`` keeps the nesting/bookkeeping (and the profiler's
+    annotation) without creating a registry metric — for a site whose
+    owner observes the duration itself, and for ad-hoc scoping.
     The measured duration is available afterwards as ``.duration_us``.
 
     ``args`` is an optional metadata dict (step number, batch id, ...):
     it never touches the histogram (labels would explode cardinality)
-    but rides to span listeners, so the profiler surfaces it as the
-    chrome-trace event's ``args`` — hover a step span in the timeline
-    and see WHICH step it was.  Cost: one attribute store when unused.
+    but rides to span listeners and onto the annotation, so both the
+    chrome trace and the ``.xplane.pb`` say WHICH step it was.  Cost:
+    one attribute store when unused.
     """
 
     __slots__ = ("name", "duration_us", "args", "t_end", "_t0",
-                 "_record")
+                 "_record", "_ann")
 
     def __init__(self, name: str, histogram: bool = True,
                  args: Optional[dict] = None):
@@ -112,18 +92,17 @@ class span:
         # stays allocation-free
         if histogram:
             registry().histogram(name)
+        self._ann = _TraceAnnotation("mx." + name, **(args or {}))
 
     def __enter__(self) -> "span":
-        _stack().append(self.name)
+        self._ann.__enter__()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t_end = self.t_end = perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         self.duration_us = (t_end - self._t0) * 1e6
-        s = getattr(_tls, "stack", None)
-        if s:
-            s.pop()
         if self._record:
             registry().get(self.name).observe(self.duration_us)
         # causal tracing: inside a traced region (an active tracing
@@ -138,11 +117,4 @@ class span:
             # real start/end timestamps on the host/thread lanes (and
             # the span's args as chrome-trace event args)
             fn(self.name, t_end, self.duration_us, self.args)
-        eng = engine()
-        if eng._listeners:
-            # monitors tapping raw engine dispatches still see the span
-            # in the same event stream (the profiler ignores this echo —
-            # it gets the real event through the span listener above)
-            for fn in eng._listeners:
-                fn(f"span:{self.name}", (), self.duration_us)
         return None

@@ -559,10 +559,6 @@ class Tracer:
         return chrome_events_from_spans(self.spans(), base_pc=base_pc,
                                         tid_offset=tid_offset)
 
-    def dump_chrome_trace(self, path: str) -> str:
-        """Write the ring as a standalone chrome-trace JSON file."""
-        return chrome_trace_from_spans(self.spans(), path)
-
 
 def chrome_events_from_spans(spans: List[dict],
                              base_pc: Optional[float] = None,

@@ -50,6 +50,7 @@ from ..ndarray import NDArray
 from ..gluon.block import _TraceCtx, _KeyScope
 from ..gluon.parameter import Parameter
 from ..observability.registry import registry as _metrics_registry
+from ..observability.trace import span as _span
 from ..sparse_grad import SparseGradTrace as _SparseGradTrace
 from .mesh import (ShardingRules, axis_size, comm_buckets, default_mesh,
                    replicated, shard, zero_sharding)
@@ -314,6 +315,7 @@ class ShardedTrainer:
         import jax
         import jax.numpy as jnp
 
+        self._dispatch_metrics = _dispatch_metrics()
         block, loss_blk = self._block, self._loss
         tparams, aparams = self._train_params, self._aux_params
         fopt, ctx = self._fopt, self._ctx
@@ -331,11 +333,13 @@ class ShardedTrainer:
                 out = block(*[NDArray(v, ctx=ctx) for v in xv])
                 if yv is None:
                     l_nd = None
-                elif isinstance(yv, tuple):
-                    l_nd = loss_blk(out, tuple(NDArray(v, ctx=ctx)
-                                               for v in yv))
                 else:
-                    l_nd = loss_blk(out, NDArray(yv, ctx=ctx))
+                    with jax.named_scope("loss"):
+                        if isinstance(yv, tuple):
+                            l_nd = loss_blk(out, tuple(NDArray(v, ctx=ctx)
+                                                       for v in yv))
+                        else:
+                            l_nd = loss_blk(out, NDArray(yv, ctx=ctx))
             for w in tw:
                 if w._version > 0:
                     raise MXNetError(
@@ -519,6 +523,7 @@ class ShardedTrainer:
                 else:
                     yms = mb(yv, mb_y_sh)
 
+                @jax.named_scope("microbatch")
                 def body(carry, mb):
                     g_acc, av, lsum = carry
                     k_m, xm, ym = mb
@@ -569,14 +574,16 @@ class ShardedTrainer:
                 # the param's own (replicated) sharding — the barrier
                 # chain still pins WHERE each bucket's psum lands in
                 # the schedule
-                grads = constrain_grads(grads)
+                with jax.named_scope("grad_reduce"):
+                    grads = constrain_grads(grads)
             sp = frozenset(i for i, g in enumerate(grads)
                            if isinstance(g, tuple))
-            new_pvals, new_state = fopt.update(pvals, grads, state, t,
-                                               lr, rescale, sparse=sp)
-            if zero >= 1:
-                new_pvals = [wsc(wsc(w, zs), ps) for w, zs, ps in
-                             zip(new_pvals, z_sh, p_sh)]
+            with jax.named_scope("optimizer"):
+                new_pvals, new_state = fopt.update(
+                    pvals, grads, state, t, lr, rescale, sparse=sp)
+                if zero >= 1:
+                    new_pvals = [wsc(wsc(w, zs), ps) for w, zs, ps in
+                                 zip(new_pvals, z_sh, p_sh)]
             return new_pvals, new_state
 
         if not self._guard:
@@ -907,55 +914,97 @@ class ShardedTrainer:
     @hot_path("step")
     def step(self, x, y, batch_size: Optional[int] = None):
         """Run one sharded train step; returns the (device) mean loss.
-        `x` may be a single array or a tuple of inputs."""
+        `x` may be a single array or a tuple of inputs.
+
+        In a profiler's trace the call is the step ``mx.train`` with its
+        number, and its host work lies in three spans (histograms of the
+        same names, in microseconds): ``trainer.to_vals_us`` (inputs to
+        values, the checks, the step's RNG key), ``trainer.h2d_us`` (the
+        ``device_put``s and the three scalars) and ``trainer.jit_call_us``
+        (the jitted call until it returns, not waited for)."""
         import jax
         import jax.numpy as jnp
-        xv = _to_vals(x)
-        yv = _to_val(y)
-        self._ensure_built(xv, yv)
-        if len(xv) != len(self._x_sh):
-            raise MXNetError(
-                f"step() got {len(xv)} inputs but the trainer was built "
-                f"with {len(self._x_sh)} — optional inputs must be passed "
-                f"consistently from the first call")
-        if isinstance(yv, tuple) != self._y_multi or \
-                (self._y_multi and len(yv) != len(self._y_sh)):
-            want = (f"a tuple of {len(self._y_sh)} label streams"
-                    if self._y_multi else "a single label array")
-            raise MXNetError(
-                f"step() label structure changed: the trainer was built "
-                f"with {want} — labels must keep the first call's shape")
-        if self._accum > 1 and int(xv[0].shape[0]) % self._accum:
-            raise MXNetError(
-                f"step() batch of {int(xv[0].shape[0])} does not divide "
-                f"into accum_steps={self._accum} microbatches — pad the "
-                f"batch or change accum_steps")
-        if batch_size is None:
-            batch_size = int(xv[0].shape[0])
-        self._t += 1
-        self._optimizer.num_update = self._t
-        key = _grandom.next_key()
-        xv = tuple(jax.device_put(v, s) for v, s in zip(xv, self._x_sh))
-        if self._y_multi:
-            yv = tuple(jax.device_put(v, s)
-                       for v, s in zip(yv, self._y_sh))
-        else:
-            yv = jax.device_put(yv, self._y_sh)
-        t = jnp.asarray(self._t, dtype=jnp.int32)
-        lr = jnp.asarray(self._optimizer.learning_rate, dtype=jnp.float32)
-        rescale = jnp.asarray(self._scale / batch_size, dtype=jnp.float32)
-        if self._guard:
-            (self._pvals, self._avals, self._state, lval, self._gstate,
-             self._last_finite) = self._jit_step(
-                self._pvals, self._avals, self._state, key, t, lr,
-                rescale, self._gstate, xv, yv)
-        else:
-            self._pvals, self._avals, self._state, lval = self._jit_step(
-                self._pvals, self._avals, self._state, key, t, lr, rescale,
-                xv, yv)
+        if not self._built:
+            self._ensure_built(_to_vals(x), _to_val(y))
+        with jax.profiler.StepTraceAnnotation("mx.train",
+                                              step_num=self._t + 1):
+            with _span("trainer.to_vals_us"):
+                xv = _to_vals(x)
+                yv = _to_val(y)
+                if len(xv) != len(self._x_sh):
+                    raise MXNetError(
+                        f"step() got {len(xv)} inputs but the trainer was "
+                        f"built with {len(self._x_sh)} — optional inputs "
+                        f"must be passed consistently from the first call")
+                if isinstance(yv, tuple) != self._y_multi or \
+                        (self._y_multi and len(yv) != len(self._y_sh)):
+                    want = (f"a tuple of {len(self._y_sh)} label streams"
+                            if self._y_multi else "a single label array")
+                    raise MXNetError(
+                        f"step() label structure changed: the trainer was "
+                        f"built with {want} — labels must keep the first "
+                        f"call's shape")
+                if self._accum > 1 and int(xv[0].shape[0]) % self._accum:
+                    raise MXNetError(
+                        f"step() batch of {int(xv[0].shape[0])} does not "
+                        f"divide into accum_steps={self._accum} "
+                        f"microbatches — pad the batch or change "
+                        f"accum_steps")
+                if batch_size is None:
+                    batch_size = int(xv[0].shape[0])
+                self._t += 1
+                self._optimizer.num_update = self._t
+                key = _grandom.next_key()
+            with _span("trainer.h2d_us"):
+                xv = tuple(jax.device_put(v, s)
+                           for v, s in zip(xv, self._x_sh))
+                if self._y_multi:
+                    yv = tuple(jax.device_put(v, s)
+                               for v, s in zip(yv, self._y_sh))
+                else:
+                    yv = jax.device_put(yv, self._y_sh)
+                t = jnp.asarray(self._t, dtype=jnp.int32)
+                lr = jnp.asarray(self._optimizer.learning_rate,
+                                 dtype=jnp.float32)
+                rescale = jnp.asarray(self._scale / batch_size,
+                                      dtype=jnp.float32)
+            if self._guard:
+                (self._pvals, self._avals, self._state, lval, self._gstate,
+                 self._last_finite) = self._jit_call(
+                    self._jit_step, self._pvals, self._avals, self._state,
+                    key, t, lr, rescale, self._gstate, xv, yv)
+            else:
+                self._pvals, self._avals, self._state, lval = \
+                    self._jit_call(
+                        self._jit_step, self._pvals, self._avals,
+                        self._state, key, t, lr, rescale, xv, yv)
         if self._sparse_trace_info:
             self._record_sparse_metrics()
         return NDArray(lval, ctx=self._ctx)
+
+    def _jit_call(self, fn, *args):
+        """``fn(*args)`` under the span ``trainer.jit_call_us``, the call
+        un-waited.  A call during which jax traced, lowered or compiled
+        anything is kept out of that histogram: its seconds go to the
+        counters ``trainer.compile_call_s`` / ``trainer.compile_calls``,
+        the part that was tracing and lowering (which no cache saves) to
+        ``trainer.trace_lower_s``, and the gauge ``trainer.compile_step``
+        keeps the number of the last step that did it."""
+        m = self._dispatch_metrics
+        n0 = sum(c.n for c in m.jax_phases_n)
+        tl0 = sum(c.n for c in m.jax_trace_lower_s)
+        with _span("trainer.jit_call_us", histogram=False,
+                   args={"step_num": self._t}) as sp:
+            out = fn(*args)
+        if sum(c.n for c in m.jax_phases_n) == n0:
+            m.jit_call_us.observe(sp.duration_us)
+        else:
+            m.compile_calls.inc()
+            m.compile_call_s.inc(sp.duration_us / 1e6)
+            m.trace_lower_s.inc(
+                sum(c.n for c in m.jax_trace_lower_s) - tl0)
+            m.compile_step.set(self._t)
+        return out
 
     def _record_sparse_metrics(self) -> None:
         """Host-side sparse.* metrics from the last trace's probe record
@@ -1039,20 +1088,24 @@ class ShardedTrainer:
         _grandom.set_state(key)
 
     def forward(self, x):
-        """Sharded inference forward with the trainer-owned weights."""
+        """Sharded inference forward with the trainer-owned weights (the
+        same three spans as :meth:`step`)."""
         import jax
-        xv = _to_vals(x)
         if not self._built:
             raise MXNetError("run at least one step() before forward(), or "
                              "use the block directly")
-        if len(xv) != len(self._x_sh):
-            raise MXNetError(
-                f"forward() got {len(xv)} inputs but the trainer was built "
-                f"with {len(self._x_sh)}")
-        key = _grandom.next_key()
-        out = self._jit_fwd(self._pvals, self._avals, key,
-                            tuple(jax.device_put(v, s)
-                                  for v, s in zip(xv, self._x_sh)))
+        with _span("trainer.to_vals_us"):
+            xv = _to_vals(x)
+            if len(xv) != len(self._x_sh):
+                raise MXNetError(
+                    f"forward() got {len(xv)} inputs but the trainer was "
+                    f"built with {len(self._x_sh)}")
+            key = _grandom.next_key()
+        with _span("trainer.h2d_us"):
+            xv = tuple(jax.device_put(v, s)
+                       for v, s in zip(xv, self._x_sh))
+        out = self._jit_call(self._jit_fwd, self._pvals, self._avals, key,
+                             xv)
         if isinstance(out, tuple):
             return tuple(NDArray(o, ctx=self._ctx) for o in out)
         return NDArray(out, ctx=self._ctx)
@@ -1439,6 +1492,34 @@ class ShardedTrainer:
             for p, v in zip(self._aux_params, self._avals):
                 p.data(self._ctx)._set_data(
                     _np_to_dev(jax.device_get(v), self._ctx))
+
+
+def _dispatch_metrics():
+    """What :meth:`ShardedTrainer._jit_call` reads and writes: jax's
+    compile counters (``tuning.compile_cache.watch_compiles``) and the
+    trainer's own."""
+    import types
+    from ..tuning.compile_cache import watch_compiles
+    watched = watch_compiles()
+    reg = _metrics_registry()
+    return types.SimpleNamespace(
+        jax_phases_n=[watched[p][1] for p in ("trace", "lower", "backend")],
+        jax_trace_lower_s=[watched[p][0] for p in ("trace", "lower")],
+        jit_call_us=reg.histogram(
+            "trainer.jit_call_us",
+            help="the jitted call until it returns, un-waited; calls "
+                 "that traced or compiled are not in it"),
+        compile_calls=reg.counter(
+            "trainer.compile_calls",
+            "trainer calls during which jax traced, lowered or compiled"),
+        compile_call_s=reg.counter(
+            "trainer.compile_call_s", "seconds those calls took"),
+        trace_lower_s=reg.counter(
+            "trainer.trace_lower_s",
+            "seconds of them spent tracing and lowering"),
+        compile_step=reg.gauge(
+            "trainer.compile_step",
+            "number of the last step whose call compiled"))
 
 
 def _np_to_dev(val, ctx):

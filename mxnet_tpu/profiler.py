@@ -3,19 +3,27 @@ SURVEY.md §5.1).
 
 Two levels, mirroring the reference:
 - **Op events** from the engine's dispatch listener → chrome://tracing JSON
-  (``dump()``) and an aggregate table (``dumps()``), the analog of the
-  reference's OprBlock begin/end events.  Dispatch wall-time is recorded;
-  because XLA dispatch is async, per-op *device* time lives in the XLA
-  trace below (the reference had the same split: engine events vs CUDA
-  kernels).
-- **Device/XLA traces** via ``jax.profiler`` (XPlane/perfetto) when
-  ``profile_all=True``: written to ``trace_dir`` if configured, else to
-  ``<filename>_xla/`` next to the chrome trace — the analog of nvprof/NVTX.
+  (``dump()``), the analog of the reference's OprBlock begin/end events.
+  Dispatch wall-time is recorded; because XLA dispatch is async, per-op
+  *device* time lives in the device trace below (the reference had the
+  same split: engine events vs CUDA kernels).
+- **The device trace** via ``jax.profiler`` when ``profile_all=True``:
+  written to ``trace_dir`` if configured, else to ``<filename>_xla/`` next
+  to the chrome trace — the analog of nvprof/NVTX.  After ``stop``,
+  ``dumps()`` reduces its ``.xplane.pb`` to the three tables an operator
+  wants here: device self time by the program's own scopes (the Gluon
+  blocks, ``loss``, ``optimizer``, the kernels: ``jax.named_scope`` names
+  that every compiled program carries), the program's host spans
+  (``trace.span`` = ``mx.<name>`` annotations) by name, and the device's
+  idle gaps by the span that covers them.  Without a device trace
+  ``dumps()`` is the table of engine-op dispatch times it always was.
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
+import re
 import threading
 import time
 from typing import Dict, List, Optional
@@ -41,6 +49,8 @@ class Profiler:
         self._agg: Dict[str, List[float]] = {}
         self._lock = threading.Lock()
         self._listener_installed = False
+        self._tracing_device = False
+        self._xplane: Optional[str] = None   # the last device trace written
         self._t0 = time.perf_counter()
         # ONE timeline for the whole fleet: pid = this process's host
         # index (resolved lazily — profiling may start before the
@@ -89,11 +99,6 @@ class Profiler:
     def _on_op(self, op_name: str, outputs, dispatch_us: float = 0.0) -> None:
         if not self._running or self._paused:
             return
-        if op_name.startswith("span:"):
-            # the engine-listener echo of a trace span — the real event
-            # (correct start timestamp, host pid, thread lane) arrives
-            # through _on_span; counting this too would double it
-            return
         now = (time.perf_counter() - self._t0) * 1e6   # µs
         dur = max(dispatch_us, 0.1)                    # measured, not gap
         pid = self._host_pid()
@@ -126,7 +131,7 @@ class Profiler:
         with self._lock:
             ev["tid"] = self._lane_locked()
             self._events.append(ev)
-            self._agg.setdefault(f"span:{name}", []).append(dur)
+            self._agg.setdefault("mx." + name, []).append(dur)
 
     def start(self) -> None:
         self._pid = None               # re-resolve host index per session
@@ -143,15 +148,20 @@ class Profiler:
             self.trace_dir = self.filename + "_xla"
         if self.profile_all and self.trace_dir:
             import jax
+            self._xplane = None
             jax.profiler.start_trace(self.trace_dir)
+            self._tracing_device = True
 
     def stop(self) -> None:
-        if self.profile_all and self.trace_dir:
+        if self._tracing_device:
             import jax
+            self._tracing_device = False
             try:
                 jax.profiler.stop_trace()
             except RuntimeError:
                 pass
+            else:
+                self._xplane = find_xplane(self.trace_dir)
         self._running = False
         # drop the engine tap: an installed listener makes every invoke
         # pay dispatch timing AND suspends bulked dispatch — a stopped
@@ -203,7 +213,19 @@ class Profiler:
         with open(self.filename, "w") as f:
             json.dump(payload, f)
 
-    def dumps(self, reset: bool = False) -> str:
+    def dumps(self, reset: bool = False, depth: int = 4) -> str:
+        """The aggregate table.  With a device trace from the last
+        ``run``..``stop`` (``profile_all=True``): device self time by
+        scope down to ``depth`` names, forward and backward apart; the
+        ``mx.*`` host spans; idle gaps by owner (:func:`reduce_trace`).
+        Without one: the engine ops' host dispatch times, which say
+        nothing about the device and are not shown beside a trace."""
+        if self._xplane is not None:
+            text = format_tables(reduce_trace(load_xplane(self._xplane),
+                                              depth))
+            if reset:
+                self._xplane = None
+            return text
         with self._lock:
             rows = []
             for name, durs in sorted(self._agg.items()):
@@ -214,7 +236,8 @@ class Profiler:
                 self._agg.clear()
         head = (f"{'Name':<32}{'Calls':>8}{'Total(us)':>14}"
                 f"{'Avg(us)':>12}{'Min(us)':>12}{'Max(us)':>12}\n")
-        lines = [head, "-" * len(head) + "\n"]
+        lines = ["host dispatch time (no device trace was taken)\n", head,
+                 "-" * len(head) + "\n"]
         for name, calls, total, avg, mn, mx in rows:
             lines.append(f"{name:<32}{calls:>8}{total:>14.1f}"
                          f"{avg:>12.1f}{mn:>12.1f}{mx:>12.1f}\n")
@@ -235,6 +258,7 @@ class Profiler:
         with self._lock:
             self._events.clear()
             self._agg.clear()
+        self._xplane = None
 
 
 def set_config(**kwargs) -> None:
@@ -285,5 +309,241 @@ def dump(finished: bool = True) -> None:
     Profiler.get().dump(finished)
 
 
-def dumps(reset: bool = False) -> str:
-    return Profiler.get().dumps(reset)
+def dumps(reset: bool = False, depth: int = 4) -> str:
+    return Profiler.get().dumps(reset, depth)
+
+
+# -- a device trace (.xplane.pb), reduced ------------------------------------
+#
+# The device plane ``/device:TPU:<n>`` holds, in its line ``XLA Ops``, one
+# event per operation the chip ran.  ``jax.profiler.ProfileData`` gives each
+# event's HLO text, start and duration; the scope (the HLO ``op_name``) is a
+# stat of the event's *metadata* (``tf_op``), which ProfileData does not
+# hand out, so a few lines of protobuf wire format read that one table.
+# The CPU backend's events carry no scope at all (``hlo_module`` and
+# ``hlo_op`` only): the by-scope table is then empty and says so.
+
+DEVICE_PREFIX = "/device:TPU:"
+OPS_LINE = "XLA Ops"
+SPAN_PREFIX = "mx."
+SHORT_GAP_NS = 20e3   # shorter gaps lie between two operations of one program
+#: names jax puts into an op_name that are not scopes of the program
+_NOT_A_SCOPE = re.compile(
+    r"^((p?jit|vmap|shard_map)\(.*|while|cond|body|branch_\d+_fun|"
+    r"closed_call|checkpoint|remat\d*|rematted_computation|"
+    r"custom_(jvp|vjp)_call(_jaxpr)?)$")
+
+
+def find_xplane(trace_dir: str) -> Optional[str]:
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    return files[-1] if files else None
+
+
+def _wire_fields(buf):
+    """(field number, value) of one protobuf message: varints as ints,
+    length-delimited fields as memoryviews."""
+    i, n = 0, len(buf)
+
+    def varint():
+        nonlocal i
+        val = shift = 0
+        while True:
+            c = buf[i]
+            i += 1
+            val |= (c & 0x7F) << shift
+            shift += 7
+            if c < 0x80:
+                return val
+
+    while i < n:
+        key = varint()
+        if key & 7 == 0:
+            val = varint()
+        else:
+            width = varint() if key & 7 == 2 else {1: 8, 5: 4}[key & 7]
+            val = buf[i:i + width]
+            i += width
+        yield key >> 3, val
+
+
+def _op_names(raw) -> Dict[str, Dict[str, str]]:
+    """{plane name: {event name (the HLO text): op_name}} from the bytes of
+    an XSpace: XSpace.planes=1; XPlane.name=2, .event_metadata=4 and
+    .stat_metadata=5 (maps: key=1, value=2); XEventMetadata.name=2,
+    .stats=5; XStat.metadata_id=1, .str_value=5, .ref_value=7;
+    XStatMetadata.id=1, .name=2."""
+    out = {}
+    for no, plane in _wire_fields(memoryview(raw)):
+        if no != 1:
+            continue
+        name, stat_names, metas = "", {}, []
+        for f, v in _wire_fields(plane):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f == 5:
+                md = dict(_wire_fields(dict(_wire_fields(v))[2]))
+                stat_names[md.get(1, 0)] = bytes(md.get(2, b"")).decode()
+            elif f == 4:
+                metas.append(dict(_wire_fields(v))[2])
+        if not name.startswith(DEVICE_PREFIX):
+            continue
+        tf_op = {i for i, n in stat_names.items() if n == "tf_op"}
+        scopes = out[name] = {}
+        for meta in metas:
+            ev_name, scope = "", ""
+            for f, v in _wire_fields(meta):
+                if f == 2:
+                    ev_name = bytes(v).decode()
+                elif f == 5:
+                    st = dict(_wire_fields(v))
+                    if st.get(1) in tf_op:
+                        scope = bytes(st[5]).decode() if 5 in st \
+                            else stat_names.get(st.get(7), "")
+            if scope:
+                scopes[ev_name] = scope
+    return out
+
+
+def load_xplane(path: str) -> dict:
+    """``{"device": {plane: [[HLO text, op_name, start ns, duration ns]]},
+    "host": [[span name, thread, start ns, duration ns]]}``: every device
+    plane's operations with their scope, and the program's own host
+    spans."""
+    from jax.profiler import ProfileData
+    with open(path, "rb") as f:
+        raw = f.read()
+    scopes = _op_names(raw)
+    device, host = {}, []
+    for plane in ProfileData.from_serialized_xspace(raw).planes:
+        if plane.name.startswith(DEVICE_PREFIX):
+            names = scopes.get(plane.name, {})
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    device[plane.name] = [
+                        [ev.name, names.get(ev.name, ""),
+                         float(ev.start_ns), float(ev.duration_ns)]
+                        for ev in line.events]
+        else:
+            for line in plane.lines:
+                host += [[ev.name, line.name, float(ev.start_ns),
+                          float(ev.duration_ns)] for ev in line.events
+                         if ev.name.startswith(SPAN_PREFIX)]
+    return {"device": device, "host": host}
+
+
+def scope_of(op_name: str, depth: int):
+    """(scope, "fwd" | "bwd") of one device operation.  The op_name is
+    ``jit(step_fn)/transpose(jvp(bertmodel0))/enc/cell3/attn/jit(fn)/mul``:
+    the jit's name, then the program's scopes (the first inside jax's
+    ``jvp``/``transpose``, which mark the backward), then jax's own names
+    down to the primitive.  The scope is the program's part, cut to
+    ``depth`` names, with a block's number starred so that twelve layers
+    make one row; ``""`` where the program named nothing."""
+    parts = op_name.split("/")
+    way = "bwd" if any(p.startswith("transpose(") for p in parts) else "fwd"
+    if parts and parts[0].startswith(("jit(", "pjit(")):
+        parts = parts[1:]
+    own = []
+    for p in parts[:-1]:            # the last name is the primitive's
+        while p.startswith(("transpose(", "jvp(")) and p.endswith(")"):
+            p = p[p.index("(") + 1:-1]
+        if not p or _NOT_A_SCOPE.match(p):
+            break
+        own.append(re.sub(r"\d+$", "*", p))
+    return "/".join(own[:depth]), way
+
+
+def _self_times(events):
+    """[[event, self ns]]: each event's duration less that of the events
+    nested in it (a ``while`` holds the operations of its body; a span
+    the spans inside it).  ``events`` end in (start, duration)."""
+    out, stack = [], []         # stack of [end, index into out]
+    for ev in sorted(events, key=lambda e: (e[-2], -e[-1])):
+        start, dur = ev[-2], ev[-1]
+        while stack and start >= stack[-1][0]:
+            stack.pop()
+        if stack:
+            out[stack[-1][1]][1] -= dur
+        out.append([ev, dur])
+        stack.append([start + dur, len(out) - 1])
+    return out
+
+
+def reduce_trace(trace: dict, depth: int = 4) -> dict:
+    """The three tables, as numbers (seconds, a device plane's average):
+
+    ``busy_s``; ``scopes``: {scope: {"fwd": s, "bwd": s}} of device self
+    time, ``""`` for what no scope of the program's covers, and
+    ``unscoped``: that rest by operation; ``spans``: {mx.<name>: [count,
+    total s, self s]}; ``gaps``: {owner: s} of the idle gaps of 20 us and
+    more, each given to the innermost (shortest) ``mx.*`` span that covers
+    most of it."""
+    n = max(len(trace["device"]), 1)
+    spans_in = trace["host"]
+    busy = 0.0
+    scopes, unscoped, gaps, spans = {}, {}, {}, {}
+    for events in trace["device"].values():
+        merged = []
+        for _, _, s, d in sorted(events, key=lambda e: e[2]):
+            if merged and s <= merged[-1][1]:
+                merged[-1][1] = max(merged[-1][1], s + d)
+            else:
+                merged.append([s, s + d])
+        busy += sum(e - s for s, e in merged)
+        for (hlo, op_name, _, _), self_ns in _self_times(events):
+            scope, way = scope_of(op_name, depth)
+            row = scopes.setdefault(scope, {"fwd": 0.0, "bwd": 0.0})
+            row[way] += self_ns / n / 1e9
+            if not scope:
+                op = re.sub(r"\.\d+$", "", hlo.split(" = ", 1)[0].lstrip("%"))
+                unscoped[op] = unscoped.get(op, 0.0) + self_ns / n / 1e9
+        for (_, e0), (s1, _) in zip(merged, merged[1:]):
+            if s1 - e0 < SHORT_GAP_NS:
+                continue
+            owner, best = "unowned", (0.0, 0.0)
+            for name, _, s, d in spans_in:
+                cover = min(s + d, s1) - max(s, e0)
+                if cover > 0 and (cover, -d) > best:
+                    owner, best = name, (cover, -d)
+            gaps[owner] = gaps.get(owner, 0.0) + (s1 - e0) / n / 1e9
+    for thread in {ev[1] for ev in spans_in}:
+        for (name, _, _, dur), self_ns in _self_times(
+                [ev for ev in spans_in if ev[1] == thread]):
+            row = spans.setdefault(name, [0, 0.0, 0.0])
+            row[0] += 1
+            row[1] += dur / 1e9
+            row[2] += self_ns / 1e9
+    return {"busy_s": busy / n / 1e9, "scopes": scopes,
+            "unscoped": unscoped, "spans": spans, "gaps": gaps}
+
+
+def format_tables(red: dict, top: int = 12) -> str:
+    busy = red["busy_s"] or float("nan")
+    out = [f"device self time by scope (busy {red['busy_s']:.6f} s a "
+           f"device)\n",
+           f"{'Scope':<56}{'fwd(s)':>11}{'bwd(s)':>11}{'% busy':>8}\n"]
+    rows = sorted(red["scopes"].items(),
+                  key=lambda kv: -(kv[1]["fwd"] + kv[1]["bwd"]))
+    for scope, t in rows:
+        out.append(f"{scope or '(no scope of the program)':<56}"
+                   f"{t['fwd']:>11.6f}{t['bwd']:>11.6f}"
+                   f"{100 * (t['fwd'] + t['bwd']) / busy:>8.2f}\n")
+    if not any(scope for scope, _ in rows):
+        out.append("  (this trace's device events carry no op_name)\n")
+    elif red["unscoped"]:
+        out.append("  without a scope, by operation: " + ", ".join(
+            f"{k} {v:.6f}" for k, v in sorted(
+                red["unscoped"].items(), key=lambda kv: -kv[1])[:top])
+            + "\n")
+    out.append(f"\nhost spans\n{'Span':<40}{'Calls':>8}{'Total(s)':>12}"
+               f"{'Self(s)':>12}\n")
+    for name, (calls, total, self_s) in sorted(
+            red["spans"].items(), key=lambda kv: -kv[1][1]):
+        out.append(f"{name:<40}{calls:>8}{total:>12.6f}{self_s:>12.6f}\n")
+    out.append(f"\ndevice idle gaps of 20 us and more, by the span "
+               f"covering them\n{'Owner':<40}{'Idle(s)':>12}\n")
+    for name, idle in sorted(red["gaps"].items(), key=lambda kv: -kv[1]):
+        out.append(f"{name:<40}{idle:>12.6f}\n")
+    return "".join(out)

@@ -41,6 +41,7 @@ from ..ndarray import NDArray
 from ..observability import tracing as _tracing
 from ..observability.flight import recorder as _flight_recorder
 from ..observability.registry import registry
+from ..observability.trace import span as _span
 from ..observability.sampler import maybe_start_from_env as \
     _maybe_start_sampler
 from ..observability.watchdog import touchpoint as _touchpoint
@@ -1106,12 +1107,20 @@ class GenerationServer:
             # graph/bucket resolution OUTSIDE the hot per-step root:
             # first use compiles under the lock; after warmup these are
             # dict hits
+            # each iteration's work lies in a profiler's trace under the
+            # scheduler thread's own spans (``mx.serving.*``), whether or
+            # not request tracing (MXTPU_TRACE) is on: a device's idle gap
+            # then has an owner
             for req in admit:
                 bucket = self._bucket_for(len(req.prompt))
-                self._prefill(self._prefill_graph(bucket), req, bucket)
+                graph = self._prefill_graph(bucket)
+                with _span("serving.prefill", histogram=False):
+                    self._prefill(graph, req, bucket)
             occupied = any(r is not None for r in self._running)
             if occupied:
-                self._decode_step(self._decode_graph(self._slots))
+                graph = self._decode_graph(self._slots)
+                with _span("serving.decode_step", histogram=False):
+                    self._decode_step(graph)
             elif not admit and not expired:
                 if self._closed:
                     with self._lock:
@@ -1249,22 +1258,25 @@ class GenerationServer:
             # Assembly is inside the try: a failed ensure() must fail
             # the batch AND close the step span like a compiled-call
             # failure would
-            tokens, positions, tables = self._step_bufs[self._slots]
-            tokens.fill(0)
-            positions.fill(0)
-            tables.fill(0)
-            for i, r in occupied:
-                # lazy block growth: back the write position; infallible
-                # under the admission-time reservation
-                table = self._kv.ensure(r.rid, r.pos + 1)
-                tokens[i] = r.tokens[-1]
-                positions[i] = r.pos
-                tables[i, :] = table.padded(self._max_blocks)
+            with _span("serving.decode_assemble", histogram=False):
+                tokens, positions, tables = self._step_bufs[self._slots]
+                tokens.fill(0)
+                positions.fill(0)
+                tables.fill(0)
+                for i, r in occupied:
+                    # lazy block growth: back the write position;
+                    # infallible under the admission-time reservation
+                    table = self._kv.ensure(r.rid, r.pos + 1)
+                    tokens[i] = r.tokens[-1]
+                    positions[i] = r.pos
+                    tables[i, :] = table.padded(self._max_blocks)
             t0 = time.monotonic()
-            logits, pool = graph.raw(tokens, positions, tables,
-                                     self._pool)
+            with _span("serving.decode_dispatch", histogram=False):
+                logits, pool = graph.raw(tokens, positions, tables,
+                                         self._pool)
             self._pool = pool  # mxlint: disable=lock-discipline — scheduler-thread-owned; the lock-held writes happen in pre-start warmup
-            lg = _np.asarray(logits)  # mxlint: disable=hidden-host-sync,hot-path-purity — ONE batched logits readback per decode step (results are host tokens by contract)
+            with _span("serving.decode_readback", histogram=False):
+                lg = _np.asarray(logits)  # mxlint: disable=hidden-host-sync,hot-path-purity — ONE batched logits readback per decode step (results are host tokens by contract)
         except BaseException as exc:
             if sp is not None:
                 sp.annotate(error=type(exc).__name__)

@@ -328,3 +328,71 @@ def aot_compile(lowered, kind: str = "graph", device=None):
     compiled = lowered.compile()
     cache.store_jit(key, compiled)
     return compiled
+
+
+# -- what compiling costs, from jax's own events ----------------------------
+
+#: jax's duration event -> the registry counters ``compile.<phase>_s``
+#: (seconds) and ``compile.<phase>_n`` (times).  ``backend`` is jax's
+#: ``compile_or_get_cached``: the backend's compile, or the read of the
+#: persistent cache that stood in for it; ``cache_read`` is that read
+#: alone where it was a hit, so it lies inside ``backend``.
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_watch_lock = threading.Lock()
+_watch_counters: Optional[dict] = None
+_watch_tls = threading.local()
+
+
+def watch_compiles() -> dict:
+    """Install, once a process, the two ``jax.monitoring`` listeners that
+    fold jax's compile events into the registry, and return the counters:
+    ``{phase: (seconds counter, times counter)}``.  They fire only where
+    something is traced, lowered or compiled: nothing on a hot path.
+
+    jax times every ``jit`` it traces, also those traced inside another's
+    trace or while another is lowered, and the outer duration holds the
+    inner ones.  Each timed region announces its start through
+    ``record_scalar`` under the same event name, so a per-thread depth
+    keeps the outermost region alone: the three phases' seconds add up
+    to wall time."""
+    global _watch_counters
+    with _watch_lock:
+        if _watch_counters is not None:
+            return _watch_counters
+        import jax
+        from ..observability.registry import registry
+        reg = registry()
+        counters = {
+            phase: (reg.counter(f"compile.{phase}_s",
+                                f"seconds jax spent in {phase}"),
+                    reg.counter(f"compile.{phase}_n",
+                                f"times jax ran {phase}"))
+            for phase in (*COMPILE_PHASES.values(), "cache_read")}
+
+        def count(phase, seconds):
+            secs, times = counters[phase]
+            secs.inc(seconds)
+            times.inc()
+
+        def on_start(event, _value, **_):
+            if event in COMPILE_PHASES:
+                _watch_tls.depth = getattr(_watch_tls, "depth", 0) + 1
+
+        def on_duration(event, seconds, **_):
+            if event == CACHE_READ_EVENT:
+                count("cache_read", seconds)
+            elif event in COMPILE_PHASES:
+                depth = _watch_tls.depth = \
+                    max(getattr(_watch_tls, "depth", 1) - 1, 0)
+                if depth == 0:
+                    count(COMPILE_PHASES[event], seconds)
+
+        jax.monitoring.register_scalar_listener(on_start)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _watch_counters = counters
+    return counters

@@ -236,13 +236,15 @@ def test_baselined_file_is_not_a_new_finding(capsys):
     assert new == [] and len(old) >= 1
 
 
-def test_register_py_pragma_is_exercised():
-    """The deliberate hot-path clock pair in ndarray/register.py is
-    pragma-suppressed (justified inline), NOT baselined."""
+def test_register_py_flush_needs_no_timing_pragma():
+    """The segment flush in ndarray/register.py is timed by a
+    ``trace.span`` (so it lies in a profiler's trace), not by a bare
+    clock pair behind a pragma: nothing to find, nothing suppressed,
+    nothing baselined."""
     findings, sup = mxlint.lint_paths(
         [os.path.join(REPO, "mxnet_tpu", "ndarray", "register.py")])
     assert not any(f.rule == "timing-pair" for f in findings)
-    assert any(f.rule == "timing-pair" for f in sup)
+    assert not any(f.rule == "timing-pair" for f in sup)
 
 
 # -- framework guarantees ---------------------------------------------------

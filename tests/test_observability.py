@@ -147,13 +147,19 @@ def test_registry_reset_prefix():
 # -- spans ------------------------------------------------------------------
 
 def test_span_records_and_nests():
-    with trace.span("t.outer_us"):
-        assert trace.current() == "t.outer_us"
-        with trace.span("t.inner_us"):
-            assert trace.current() == "t.inner_us"
-            assert trace.stack() == ["t.outer_us", "t.inner_us"]
-        assert trace.current() == "t.outer_us"
-    assert trace.current() is None
+    seen = []
+    fn = lambda name, t_end, us, args: seen.append((name, t_end, us))  # noqa: E731
+    trace.add_span_listener(fn)
+    try:
+        with trace.span("t.outer_us"):
+            with trace.span("t.inner_us"):
+                pass
+    finally:
+        trace.remove_span_listener(fn)
+    # the inner span ends first and lies inside the outer one
+    (n_in, end_in, us_in), (n_out, end_out, us_out) = seen
+    assert (n_in, n_out) == ("t.inner_us", "t.outer_us")
+    assert end_out - us_out / 1e6 <= end_in - us_in / 1e6 <= end_in <= end_out
     outer = registry().get("t.outer_us").read()
     inner = registry().get("t.inner_us").read()
     assert outer["count"] >= 1 and inner["count"] >= 1
@@ -165,7 +171,6 @@ def test_span_pops_on_exception():
     with pytest.raises(ValueError):
         with trace.span("t.raises_us"):
             raise ValueError("boom")
-    assert trace.current() is None
     assert registry().get("t.raises_us").read()["count"] >= 1
 
 
@@ -178,15 +183,14 @@ def test_span_duration_and_no_histogram_mode():
 
 def test_span_emits_to_profiler_listener():
     events = []
-    eng = engine()
-    fn = lambda name, outs, us: events.append((name, us))  # noqa: E731
-    eng.add_listener(fn)
+    fn = lambda name, t_end, us, args: events.append((name, us))  # noqa: E731
+    trace.add_span_listener(fn)
     try:
         with trace.span("t.listened_us"):
             pass
     finally:
-        eng.remove_listener(fn)
-    assert any(n == "span:t.listened_us" for n, _ in events)
+        trace.remove_span_listener(fn)
+    assert any(n == "t.listened_us" for n, _ in events)
 
 
 # -- back-compat views ------------------------------------------------------
