@@ -11,7 +11,8 @@ each at the full width of a model the repo supports (random weights from
   ``parallel.ShardedTrainer`` (sgd+momentum), batch 128 at 224x224;
 - train / BERT-base — MLM+NSP loss with per-row valid lengths, batch 8,
   seq 256, attention on its default selector (the Pallas flash kernel), and
-  the kernel against the full-softmax XLA result at those shapes;
+  the kernel against the full-softmax XLA result at those shapes and at
+  the benchmark cell's (batch 16, seq 512, its valid lengths);
 - imperative / Gluon MLP — un-hybridized 784-128-64-10 inside
   ``with mx.tpu(0):``, ``gluon.Trainer`` sgd+momentum (the ``multi_sgd``
   Mosaic kernel), bulked segments;
@@ -69,7 +70,9 @@ GREEDY_TIE_ATOL = 5e-2      # logits of std ~1; see serve_causal_lm
 CTX = mx.tpu(0)             # where the imperative and serve paths place work
 RESNET_BATCH, RESNET_SIZE = 128, 224
 BERT_BATCH, BERT_SEQ, BERT_VOCAB = 8, 256, 30522
-FLASH_SHAPE = (96, 256, 64)                 # BERT-base: 8 x 12 heads
+# BERT-base's heads at this script's batch 8 x seq 256, and at the
+# benchmark cell's batch 16 x seq 512 (bert_base.pretrain_s512)
+FLASH_SHAPES = ((96, 256, 64), (192, 512, 64))
 LM = dict(vocab_size=30522, num_layers=12, units=768, hidden_size=3072,
           num_heads=12, max_length=1024)    # BERT-base widths
 
@@ -216,18 +219,33 @@ def full_softmax_attention(q, k, v, valid_len=None):
         return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
 
 
-def flash_kernel_check(seed):
-    """The kernel on the chip against the reference, at BERT-base's
-    (batch 8 x 12 heads, seq 256, head 64)."""
+def flash_lengths(rng, bh, seq):
+    """One valid length per sequence of 12 heads: at seq 512 the benchmark
+    cell's own set (``bert_base.pretrain_s512``, read from its file and
+    dealt by the seed), else uniform over the upper half."""
+    n = bh // 12
+    if seq == 512:
+        from benchmarks.harness import loader
+        from benchmarks.models import bert
+        _, cell, _ = loader.cell_and_config(loader.benchmark(),
+                                            "bert_base.pretrain_s512")
+        lens = rng.permutation(bert.lengths(cell["traffic_params"]))[:n]
+    else:
+        lens = rng.integers(seq // 2, seq + 1, (n,))
+    return np.repeat(lens, 12).astype(np.float32)
+
+
+def flash_kernel_check(shape, seed):
+    """The kernel on the chip against the reference, at BERT-base's heads
+    (12 of 64) for one of FLASH_SHAPES."""
     from mxnet_tpu.kernels import flash_attention
-    bh, seq, _ = FLASH_SHAPE
+    from mxnet_tpu.observability.registry import registry
+    bh, seq, _ = shape
     rng = np.random.default_rng(seed)
     q, k, v = (jax.device_put(
-        rng.standard_normal(FLASH_SHAPE, dtype=np.float32), CTX.device)
+        rng.standard_normal(shape, dtype=np.float32), CTX.device)
         for _ in range(3))
-    vl = jax.device_put(
-        np.repeat(rng.integers(seq // 2, seq + 1, (bh // 12,)), 12)
-        .astype(np.float32), CTX.device)
+    vl = jax.device_put(flash_lengths(rng, bh, seq), CTX.device)
     mosaic("flash attention", q,
            jax.jit(flash_attention).lower(q, k, v).compile().as_text())
     errs = {}
@@ -240,18 +258,19 @@ def flash_kernel_check(seed):
             err = np.abs(np.asarray(out, np.float32) - np.asarray(ref))
             if dtype == jnp.float32:
                 check(err.max() <= 3e-5,
-                      f"flash fp32 {name}: max |err| {err.max()} > 3e-5")
+                      f"flash fp32 {name} {shape}: max |err| {err.max()} "
+                      "> 3e-5")
             else:
                 bound = FLASH_BF16_ATOL + FLASH_BF16_RTOL * np.abs(
                     np.asarray(ref))
                 check((err <= bound).all(),
-                      f"flash bf16 {name}: max |err| {err.max()} beyond "
-                      f"{FLASH_BF16_ATOL}+{FLASH_BF16_RTOL}*|ref|")
+                      f"flash bf16 {name} {shape}: max |err| {err.max()} "
+                      f"beyond {FLASH_BF16_ATOL}+{FLASH_BF16_RTOL}*|ref|")
             errs[f"{jnp.dtype(dtype).name}_{name}"] = float(f"{err.max():.2e}")
     # the backward (a scanned jnp formulation behind the kernel's custom
     # VJP, at the chip's default matmul precision) against the reference's
     # gradients, relative to the largest of them
-    cot = jax.device_put(rng.standard_normal(FLASH_SHAPE, dtype=np.float32),
+    cot = jax.device_put(rng.standard_normal(shape, dtype=np.float32),
                          CTX.device)
     grads = [jax.jit(jax.grad(
         lambda a, b, c, f=f: jnp.sum(f(a, b, c, valid_len=vl) * cot),
@@ -260,11 +279,14 @@ def flash_kernel_check(seed):
     gerr = max(float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
                for g, r in zip(*grads))
     check(gerr <= FLASH_GRAD_RTOL,
-          f"flash gradients off by {gerr} of the largest reference gradient")
+          f"flash gradients {shape} off by {gerr} of the largest reference "
+          "gradient")
     say("kernel/flash_attention", lowering="tpu_custom_call",
-        shape=FLASH_SHAPE, max_abs_err=errs,
+        shape=shape, max_abs_err=errs,
         tol=f"fp32 3e-5; bf16 {FLASH_BF16_ATOL}+{FLASH_BF16_RTOL}*|ref|",
-        grad_max_rel_err=float(f"{gerr:.2e}"), grad_tol=FLASH_GRAD_RTOL)
+        grad_max_rel_err=float(f"{gerr:.2e}"), grad_tol=FLASH_GRAD_RTOL,
+        tiling={n: int(registry().get(f"kernels.flash_attention.{n}").read())
+                for n in ("block_q", "block_k", "kv_resident", "grid_steps")})
 
 
 def make_bert():
@@ -594,7 +616,8 @@ def main():
         sharded_resnet50(args.seed)
     else:
         train_resnet50(args.seed)
-        flash_kernel_check(args.seed)
+        for shape in FLASH_SHAPES:
+            flash_kernel_check(shape, args.seed)
         train_bert_base(args.seed)
         imperative_mlp(args.seed)
         multi_sgd_kernel_check(args.seed)

@@ -4,17 +4,27 @@ Reference context: the reference's attention rides cuDNN/hand-CUDA
 softmax(QKᵀ)V with the full (Lq, Lk) score matrix in HBM; the TPU-native
 answer is the tiled online-softmax formulation (Flash Attention), which
 never materializes the score matrix: each grid step owns one
-(BLOCK_Q, D) query tile in VMEM and streams K/V tiles through the MXU,
+(block_q, D) query tile in VMEM and sweeps tiles of K/V through the MXU,
 carrying the running max/denominator.  HBM traffic drops from
 O(Lq·Lk) to O(Lq·D + Lk·D) — exactly the memory-bound regime SURVEY §6
 flags for long sequences (ring attention in parallel/ring.py handles the
 multi-chip axis; this kernel is the single-chip inner loop).
 
-Grid: (batch·heads, Lq/BLOCK_Q, Lk/BLOCK_K); the K/V sweep is the
-innermost, sequential grid axis, so one (BLOCK_K, D) tile of K and of V
-is in VMEM at a time and the running max/denominator/accumulator live in
-VMEM scratch between its steps.  The per-row valid length is
-scalar-prefetched into SMEM.
+Tiling (``_tiling``, chosen from the shape and the dtype under one VMEM
+budget, never from a constant a user sets): the grid is
+(batch·heads, Lq/block_q, Lk/kv_block).  A head's K and V stay resident
+in VMEM as one block whenever they fit the budget (kv_block = Lk, the
+last grid axis has one step); longer keys ride a K-major, sequential grid
+axis of ``_KV_MAJOR``-key blocks with the running max/denominator/
+accumulator in VMEM scratch between its steps.  Inside a block the sweep
+over (block_k, D) key tiles is a ``lax.fori_loop`` whose carries are the
+(block_q, 1) max and denominator and the (block_q, D) accumulator.  The
+per-row valid length is scalar-prefetched into SMEM and bounds the
+sweep: the loop's trip count is the number of key tiles that hold a valid
+key, and a K-major block wholly beyond the length is neither computed
+nor fetched (its index map is clamped to the last block that holds one).
+The head keeps its own width (a head of 64 is a block 64 lanes wide);
+only ragged Lq/Lk are padded, to the tile.
 
 Numerics: f32 accumulation regardless of input dtype, f32 operands
 multiplied at full precision, bf16 operands as they are (the
@@ -28,9 +38,21 @@ from __future__ import annotations
 
 import functools
 
-BLOCK_Q = 128
-BLOCK_K = 128
 _NEG_INF = -1e30
+# the chunk of the scanned backward: a constant of its own, so the
+# forward's tiles never change the backward's HLO
+_BWD_CHUNK = 128
+# the forward's tiles (read on a v5e at (192, 512, 64) float32 with the
+# benchmark's lengths, PERF.md section 6, PR 29): a query tile of 512 rows
+# and a key tile of 256 were the fastest pair; keys that fit one lane
+# group keep a tile of 128
+_MAX_BLOCK_Q = 512
+_MAX_BLOCK_K = 256
+# what K and V may hold in VMEM as whole-head blocks, double-buffered by
+# the pipeline: a quarter of the 16 MB scoped limit of a v5e core, the
+# rest is Q, the output and the sweep's own temporaries
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+_KV_MAJOR = 2048
 
 
 def _interpret(example=None) -> bool:
@@ -38,99 +60,179 @@ def _interpret(example=None) -> bool:
     return _i(example)
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
+def _tiling(lq: int, lk: int, d: int, itemsize: int):
+    """(block_q, padded Lq, block_k, kv_block, padded Lk, padded D) for a
+    call's shape.  One query tile when Lq fits ``_MAX_BLOCK_Q`` rows
+    (Lq = 1 against a cache is one tile of a sublane group), else tiles of
+    ``_MAX_BLOCK_Q``; K and V resident whole (kv_block = padded Lk) when
+    they fit ``_KV_VMEM_BUDGET``, else K-major blocks of ``_KV_MAJOR``
+    keys."""
+    sublanes = 32 // itemsize           # rows of one (sublanes, 128) tile
+    # a width the MXU contracts over as it is, or whole lanes
+    dp = d if d % 64 == 0 else _round_up(d, 128)
+    block_q = min(_round_up(lq, sublanes), _MAX_BLOCK_Q)
+    lqp = _round_up(lq, block_q)
+    block_k = min(_round_up(lk, 128), _MAX_BLOCK_K)
+    lkp = _round_up(lk, block_k)
+    # K and V, two buffers each; VMEM rows are whole lanes, so a 64-wide
+    # block takes the room of 128
+    if 2 * 2 * lkp * _round_up(dp, 128) * itemsize <= _KV_VMEM_BUDGET:
+        kv_block = lkp
+    else:
+        kv_block = _KV_MAJOR
+        lkp = _round_up(lk, kv_block)
+    return block_q, lqp, block_k, kv_block, lkp, dp
+
+
 @functools.lru_cache(maxsize=None)
-def _build_call(bh: int, lq: int, lk: int, d: int, valid_lq: int,
-                valid_lk: int, causal: bool, scale: float,
-                dtype_name: str, interpret: bool):
+def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
+                scale: float, dtype_name: str, interpret: bool):
+    """The kernel for one call's (unpadded) shape; it takes the operands
+    padded as ``_tiling`` says.  Each build says which tiling engaged in
+    the ``kernels.flash_attention.*`` gauges."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from ..observability.registry import registry
 
-    nq = lq // BLOCK_Q
-    nk = lk // BLOCK_K
     dtype = jnp.dtype(dtype_name)
+    block_q, lqp, block_k, kv_block, lkp, dp = _tiling(
+        lq, lk, d, dtype.itemsize)
+    nq, nkv = lqp // block_q, lkp // kv_block
+    tiles = kv_block // block_k
     precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
-    def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
-        b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    reg = registry()
+    reg.counter("kernels.flash_attention.builds",
+                "flash forward kernels built (one per shape)").inc()
+    for name, value in (("block_q", block_q), ("block_k", block_k),
+                        ("kv_resident", int(nkv == 1)),
+                        ("grid_steps", bh * nq * nkv)):
+        reg.gauge(f"kernels.flash_attention.{name}",
+                  "tiling of the last flash forward kernel built").set(value)
 
-        @pl.when(ki == 0)
-        def _():
-            m_ref[...] = jnp.full((BLOCK_Q, 1), _NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((BLOCK_Q, 1), jnp.float32)
-            acc_ref[...] = jnp.zeros((BLOCK_Q, d), jnp.float32)
+    def sweep(vl, q, k_ref, v_ref, qi, kj, carry):
+        """Online softmax of one query tile over the key tiles of K-major
+        block ``kj`` that hold a key below ``vl``: a key at or beyond the
+        row's length has weight 0 whether the row is live or dead, so the
+        tiles beyond it are never visited."""
+        k0 = kj * kv_block
 
+        def tile(t, carry):
+            m, l, acc = carry
+            start = pl.multiple_of(t * block_k, block_k)
+            k = k_ref[0, pl.ds(start, block_k), :]
+            v = v_ref[0, pl.ds(start, block_k), :]
+            # operands stay in the input dtype, the scale is applied to
+            # the f32 scores: bf16 products are exact in the MXU's f32
+            # accumulator, and f32 operands ask for full precision (the
+            # MXU's default would round them to bf16: 5e-3 off at seq 256)
+            s = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * scale   # (BQ, BK)
+            # mask K padding (and the causal upper triangle)
+            k_idx = k0 + start + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            kmask = k_idx < vl
+            mask = kmask
+            if causal:
+                # bottom-right alignment (the flash/decode convention and
+                # this repo's reference): query i sits at absolute key
+                # position (lk - lq + i), so Lq=1 against a length-N
+                # cache attends ALL N keys
+                q_idx = qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                mask = mask & (k_idx <= q_idx + (lk - lq))
+            s = jnp.where(mask, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if causal:
+                # rows whose every key so far is masked (causal bound
+                # < 0): the reference softmaxes a uniform -NEG_INF row,
+                # i.e. uniform attention over the valid keys — exp(0)=1
+                # here would instead spread over PADDED slots, so
+                # substitute the valid mask as the weights (masks are
+                # prefixes, so a row dead in this tile is dead in every
+                # tile).  Without ``causal`` every visited tile holds a
+                # valid key and no row is dead.
+                dead = m_new <= (_NEG_INF * 0.5)
+                p = jnp.where(dead, kmask.astype(jnp.float32), p)
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_new = acc * corr + lax.dot_general(
+                p.astype(dtype), v, (((1,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
+        n = jnp.clip(pl.cdiv(vl - k0, block_k), 0, tiles)
+        return lax.fori_loop(0, n, tile, carry)
+
+    def start():
+        return (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((block_q, 1), jnp.float32),
+                jnp.zeros((block_q, dp), jnp.float32))
+
+    def finish(o_ref, l, acc):
+        # rows with no valid keys (padded queries) divide by 1 instead
+        o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(dtype)
+
+    def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, *carries):
+        b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         # per-sequence valid key length (padding mask support): the tile
-        # padding bound `valid_lk` is static; vl tightens it per row
-        vl = jnp.minimum(vl_ref[b], valid_lk)
-        # operands stay in the input dtype, the scale is applied to the
-        # f32 scores: bf16 products are exact in the MXU's f32
-        # accumulator, and f32 operands ask for full precision (the MXU's
-        # default would round them to bf16: 5e-3 off at seq 256)
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            precision=precision,
-            preferred_element_type=jnp.float32) * scale    # (BQ, BK)
-        # mask K padding (and the causal upper triangle)
-        k_idx = ki * BLOCK_K + lax.broadcasted_iota(
-            jnp.int32, (BLOCK_Q, BLOCK_K), 1)
-        kmask = k_idx < vl
-        mask = kmask
-        if causal:
-            # bottom-right alignment (the flash/decode convention and
-            # this repo's reference): query i sits at absolute key
-            # position (valid_lk - valid_lq + i), so Lq=1 against a
-            # length-N cache attends ALL N keys
-            q_idx = qi * BLOCK_Q + lax.broadcasted_iota(
-                jnp.int32, (BLOCK_Q, BLOCK_K), 0)
-            mask = mask & (k_idx <= q_idx + (valid_lk - valid_lq))
-        s = jnp.where(mask, s, _NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # rows whose every key is masked (causal bound < 0): the
-        # reference softmaxes a uniform -NEG_INF row, i.e. uniform
-        # attention over the valid keys — exp(0)=1 here would
-        # instead spread over PADDED slots, so substitute the valid
-        # mask as the weights (masks are prefixes, so a row dead in
-        # this block is dead in every block)
-        dead = m_new <= (_NEG_INF * 0.5)
-        p = jnp.where(dead, kmask.astype(jnp.float32), p)
-        corr = jnp.exp(m - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            precision=precision,
-            preferred_element_type=jnp.float32)
+        # padding bound ``lk`` is static; vl tightens it per row
+        vl = jnp.minimum(vl_ref[b], lk)
+        if nkv == 1:
+            _, l, acc = sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start())
+            finish(o_ref, l, acc)
+            return
+        m_ref, l_ref, acc_ref = carries
 
-        @pl.when(ki == nk - 1)
+        @pl.when(kj == 0)
         def _():
-            # rows with no valid keys (padded queries) divide by 1 instead
-            l = l_ref[...]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (acc_ref[...] / l).astype(dtype)
+            m_ref[...], l_ref[...], acc_ref[...] = start()
+
+        @pl.when(kj * kv_block < vl)
+        def _():
+            m_ref[...], l_ref[...], acc_ref[...] = sweep(
+                vl, q_ref[0], k_ref, v_ref, qi, kj,
+                (m_ref[...], l_ref[...], acc_ref[...]))
+
+        @pl.when(kj == nkv - 1)
+        def _():
+            finish(o_ref, l_ref[...], acc_ref[...])
+
+    def kv_index(b, i, j, vl_ref):
+        # a K-major block wholly beyond the row's length maps to the last
+        # block that holds a valid key: the pipeline sees the same block
+        # index again and issues no DMA for it
+        last = jnp.maximum(
+            pl.cdiv(jnp.minimum(vl_ref[b], lk), kv_block) - 1, 0)
+        return (b, jnp.minimum(j, last), 0)
 
     # Mosaic takes neither a rank-1 block of one element nor rank-1 loop
     # carries: the per-row length rides scalar memory, and the running
-    # max/denominator are (BLOCK_Q, 1) columns in VMEM scratch.  K/V are a
-    # grid axis (innermost, sequential), so one (BLOCK_K, D) tile of each
-    # is resident at a time whatever Lk is.
-    q_spec = pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j, vl: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j, vl: (b, j, 0))
+    # max/denominator are (block_q, 1) columns.
+    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j, vl: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, kv_block, dp), kv_index)
+    scratch = [] if nkv == 1 else [
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, dp), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, nq, nk),
+            grid=(bh, nq, nkv),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((BLOCK_Q, 1), jnp.float32),
-                            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),
-                            pltpu.VMEM((BLOCK_Q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), dtype),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((bh, lqp, dp), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -144,34 +246,34 @@ def _chunked_reference(q, k, v, vl, causal: bool, scale: float):
     dead-row semantics) and DIFFERENTIABLE.  The custom VJP below runs
     the Pallas kernel forward and differentiates THIS formulation
     backward, so training never materializes the (Lq, Lk) score matrix
-    either (per-step residuals are O(Lq·D·Lk/BLOCK_K))."""
+    either (per-step residuals are O(Lq·D·Lk/_BWD_CHUNK))."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     bh, lq, d = q.shape
     lk = k.shape[1]
-    pad = (-lk) % BLOCK_K
+    pad = (-lk) % _BWD_CHUNK
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-    nk = k.shape[1] // BLOCK_K
+    nk = k.shape[1] // _BWD_CHUNK
     qf = q.astype(jnp.float32) * scale
-    kb = k.astype(jnp.float32).reshape(bh, nk, BLOCK_K, d)
-    vb = v.astype(jnp.float32).reshape(bh, nk, BLOCK_K, d)
+    kb = k.astype(jnp.float32).reshape(bh, nk, _BWD_CHUNK, d)
+    vb = v.astype(jnp.float32).reshape(bh, nk, _BWD_CHUNK, d)
     q_idx = jnp.arange(lq)
     vl_eff = jnp.minimum(vl.astype(jnp.float32), jnp.float32(lk))  # (bh,)
 
     # remat: without checkpointing, vjp-of-scan stacks each step's p
-    # (bh, Lq, BLOCK_K) — a full probability matrix across steps; with it,
-    # backward recomputes per-block and stores only the carries
+    # (bh, Lq, _BWD_CHUNK) — a full probability matrix across steps; with
+    # it, backward recomputes per-block and stores only the carries
     # (O(Lq·(D+2)·nk))
     @jax.checkpoint
     def step(carry, blk):
         m, l, acc = carry
         k_blk, v_blk, ki = blk
         s = jnp.einsum("bqd,bkd->bqk", qf, k_blk)
-        k_ids = ki * BLOCK_K + jnp.arange(BLOCK_K)
+        k_ids = ki * _BWD_CHUNK + jnp.arange(_BWD_CHUNK)
         kmask = (k_ids[None, :].astype(jnp.float32)
                  < vl_eff[:, None])[:, None, :]        # (bh, 1, BK)
         mask = kmask
@@ -240,26 +342,24 @@ def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
 
     bh, lq, d = q.shape
     lk = k.shape[1]
+    _, lqp, _, _, lkp, dp = _tiling(lq, lk, d, jnp.result_type(q).itemsize)
 
-    def pad_to(x, axis, mult):
-        n = x.shape[axis]
-        pad = (-n) % mult
-        if pad == 0:
+    def pad_to(x, rows):
+        # only what the chosen tiles still need: ragged Lq/Lk, and a head
+        # whose width the kernel does not take as it is
+        if x.shape[1:] == (rows, dp):
             return x
-        widths = [(0, 0)] * x.ndim
-        widths[axis] = (0, pad)
-        return jnp.pad(x, widths)
+        return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, dp - d)))
 
-    # the kernel's own pad and unpad (a head of 64 goes to 128 lanes) carry
-    # a name of their own: their device time is the kernel's to answer for
+    # the kernel's own pad and unpad carry a name of their own: their
+    # device time is the kernel's to answer for
     with jax.named_scope("flash_attention_pad"):
-        qp = pad_to(pad_to(q, 1, BLOCK_Q), 2, 128)
-        kp = pad_to(pad_to(k, 1, BLOCK_K), 2, 128)
-        vp = pad_to(pad_to(v, 1, BLOCK_K), 2, 128)
-    call = _build_call(bh, qp.shape[1], kp.shape[1], qp.shape[2], lq, lk,
-                       bool(causal), float(scale),
+        qp, kp, vp = pad_to(q, lqp), pad_to(k, lkp), pad_to(v, lkp)
+    call = _build_call(bh, lq, lk, d, bool(causal), float(scale),
                        jnp.result_type(q).name, bool(interpret))
     out = call(vl.astype(jnp.int32), qp, kp, vp)
+    if out.shape == q.shape:
+        return out
     with jax.named_scope("flash_attention_pad"):
         return out[:, :lq, :d]
 

@@ -54,22 +54,31 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# BERT-base at batch 8, seq 256: 8 x 12 heads of 64; and the causal case
-# tests/test_kernels_tpu.py runs on the chip
-@pytest.mark.parametrize("shape,dtype,valid_len,causal", [
-    ((96, 256, 64), "float32", False, False),
-    ((96, 256, 64), "float32", True, False),
-    ((96, 256, 64), "bfloat16", False, False),
-    ((96, 256, 64), "bfloat16", True, False),
-    ((2, 256, 128), "float32", False, True),
+# BERT-base at batch 8, seq 256: 8 x 12 heads of 64; the causal case
+# tests/test_kernels_tpu.py runs on the chip; the benchmark's cell (batch
+# 16, seq 512: K and V resident, the head at its own 64 lanes); keys too
+# long to stay resident (the K-major grid axis); one query against a cache.
+# ``pads``: only a ragged Lq or Lk is padded, never a head of 64.
+@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal,pads", [
+    ((96, 256, 64), None, "float32", False, False, False),
+    ((96, 256, 64), None, "float32", True, False, False),
+    ((96, 256, 64), None, "bfloat16", False, False, False),
+    ((96, 256, 64), None, "bfloat16", True, False, False),
+    ((2, 256, 128), None, "float32", False, True, False),
+    ((192, 512, 64), None, "float32", True, False, False),
+    ((192, 512, 64), None, "bfloat16", True, False, False),
+    ((8, 4096, 128), None, "float32", True, False, False),
+    ((8, 1, 128), 300, "float32", True, True, True),
 ])
-def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype,
-                                          valid_len, causal):
+def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
+                                          valid_len, causal, pads):
     import jax
     from mxnet_tpu.kernels import flash_attention
 
-    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    args = [qkv, qkv, qkv]
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((shape[0], lk or shape[1], shape[2]), dtype,
+                              sharding=one_chip)
+    args = [q, kv, kv]
     if valid_len:
         args.append(jax.ShapeDtypeStruct((shape[0],), "float32",
                                          sharding=one_chip))
@@ -78,7 +87,9 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype,
         return flash_attention(q, k, v, causal=causal, valid_len=vl,
                                interpret=False)
 
-    assert "tpu_custom_call" in _compiled_text(fwd, *args)
+    text = _compiled_text(fwd, *args)
+    assert "tpu_custom_call" in text
+    assert (" pad(" in text) == pads
 
 
 def _resnet50_shapes():

@@ -443,6 +443,114 @@ def test_flash_attention_valid_len_matches_masked_softmax():
                                    atol=5e-4, rtol=1e-3)
 
 
+def _flash_module():
+    # the package binds the function under the module's name
+    import importlib
+    return importlib.import_module("mxnet_tpu.kernels.flash_attention")
+
+
+def _flash_gauges():
+    from mxnet_tpu.observability.registry import registry
+    return {n: registry().get(f"kernels.flash_attention.{n}").read()
+            for n in ("block_q", "block_k", "kv_resident", "grid_steps",
+                      "builds")}
+
+
+# the tile choice at the shapes the program meets: the benchmark's cell,
+# bf16, ragged cross-attention, one query against a cache, and keys too
+# long to stay resident (the K-major grid axis)
+@pytest.mark.parametrize("lq,lk,d,dtype,causal,resident,block_q", [
+    (512, 512, 64, "float32", False, 1, None),
+    (256, 256, 64, "bfloat16", False, 1, 256),
+    (100, 77, 64, "float32", False, 1, 104),
+    (1, 300, 128, "float32", True, 1, 8),
+    (256, 4096, 128, "float32", False, 0, 256),
+    (300, 200, 64, "float32", True, 1, 304),     # dead rows: Lq > Lk
+])
+def test_flash_attention_tile_choice_and_skipped_tiles(
+        lq, lk, d, dtype, causal, resident, block_q):
+    """Every tiling against the full softmax at highest precision, with
+    rows whose valid length sits on each side of a key tile's edge (and
+    of a K-major block's): tiles beyond the length are never visited, so
+    a wrong trip count shows as a wrong row."""
+    import jax
+    import jax.numpy as jnp
+    fa = _flash_module()
+
+    _, lqp, bk, kvb, lkp, _ = fa._tiling(lq, lk, d, jnp.dtype(dtype).itemsize)
+    lens = {0, 1, bk - 1, bk, bk + 1, lk}
+    if kvb < lkp:
+        lens |= {kvb - 1, kvb, kvb + 1}
+    lens = np.array(sorted(min(n, lk) for n in lens), np.float32)
+    bh = len(lens)
+    rs = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rs.randn(bh, n, d).astype(np.float32)).astype(dtype)
+               for n in (lq, lk, lk))
+    from mxnet_tpu.observability.registry import registry
+    builds = registry().counter("kernels.flash_attention.builds").read()
+    fa._build_call.cache_clear()
+    out = np.asarray(fa.flash_attention(q, k, v, causal=causal,
+                                        valid_len=jnp.asarray(lens)),
+                     np.float32)
+
+    g = _flash_gauges()
+    assert g["builds"] == builds + 1
+    assert g["kv_resident"] == resident and g["block_k"] == bk
+    assert g["block_q"] == (block_q or fa._MAX_BLOCK_Q)
+    assert g["grid_steps"] == bh * (lqp // g["block_q"]) * (lkp // kvb)
+    if resident:
+        assert kvb == lkp
+    else:
+        assert kvb == fa._KV_MAJOR and lkp // kvb > 1
+
+    qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bqd,bkd->bqk", qf, kf) / np.sqrt(d)
+        keep = jnp.arange(lk)[None, None, :] < lens[:, None, None]
+        live = keep
+        if causal:
+            live = keep & (jnp.arange(lk)[None, None, :] <=
+                           jnp.arange(lq)[None, :, None] + (lk - lq))
+        # a row with no live key weights its valid keys evenly (none: 0)
+        dead = ~live.any(-1, keepdims=True)
+        p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+        p = jnp.where(dead, keep / jnp.maximum(keep.sum(-1, keepdims=True),
+                                               1), p)
+        ref = np.asarray(jnp.einsum("bqk,bkd->bqd", p, vf))
+    if dtype == "float32":
+        np.testing.assert_allclose(out, ref, atol=3e-5, rtol=0)
+    else:
+        assert (np.abs(out - ref) <= 1e-2 + 1e-2 * np.abs(ref)).all()
+    assert not out[0].any()          # no valid key: the row divides by 1
+
+
+def test_flash_backward_keeps_its_chunk(monkeypatch):
+    """The scanned backward has a chunk of its own (128): its HLO does not
+    move with the forward's tiles, and it still sweeps ceil(Lk / 128)
+    chunks."""
+    import jax
+    import jax.numpy as jnp
+    fa = _flash_module()
+
+    assert fa._BWD_CHUNK == 128
+    arg = jax.ShapeDtypeStruct((4, 512, 64), jnp.float32)
+    vl = jax.ShapeDtypeStruct((4,), jnp.float32)
+
+    def bwd(q, k, v, vl, g):
+        _, vjp = jax.vjp(lambda a, b, c: fa._chunked_reference(
+            a, b, c, vl, False, 0.125), q, k, v)
+        return vjp(g)
+
+    def text():
+        return jax.jit(bwd).lower(arg, arg, arg, vl, arg).as_text()
+
+    was = text()
+    assert "4x4x128x64" in was       # K in four chunks of 128 keys
+    monkeypatch.setattr(fa, "_MAX_BLOCK_K", 128)
+    monkeypatch.setattr(fa, "_MAX_BLOCK_Q", 128)
+    assert text() == was
+
+
 def test_flash_attention_padding_mask_transformer_path(monkeypatch):
     """Encoder self-attention with (B,) valid LENGTHS (the GluonNLP
     valid_length idiom): the flash path must match the XLA mask path."""

@@ -130,3 +130,27 @@ def test_flash_attention_mosaic_compiles_and_matches(tpu):
         ref = jax.nn.softmax(s, axis=-1) @ v
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5)
+
+
+@pytest.mark.parametrize("shape", [(96, 256, 64), (192, 512, 64)])
+def test_flash_attention_valid_len_matches_on_chip(tpu, shape):
+    """BERT-base's heads at chip_smoke's shape and at the benchmark cell's
+    (batch 16 x seq 512, its lengths): float32 to 3e-5 of the full softmax
+    at highest precision."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    from chip_smoke import flash_lengths    # the cell's own lengths at 512
+
+    bh, seq, d = shape
+    rs = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rs.standard_normal(shape, np.float32))
+               for _ in range(3))
+    vl = jnp.asarray(flash_lengths(rs, bh, seq))
+    out = flash_attention(q, k, v, valid_len=vl)
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(d)
+        keep = jnp.arange(seq)[None, None, :] < vl[:, None, None]
+        ref = jnp.einsum("bqk,bkd->bqd",
+                         jax.nn.softmax(jnp.where(keep, s, -1e30), -1), v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
