@@ -242,7 +242,10 @@ class Block:
             # HLO's op_name metadata); the eager path pays one
             # thread-local read
             with tc.block_scope(self._prefix):
-                out = self.forward(*args)
+                if id(self) in tc.remat:
+                    out = _remat_forward(tc, self, args)
+                else:
+                    out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -290,9 +293,11 @@ def _camel_to_snake(name: str) -> str:
 class _TraceCtx:
     _current = threading.local()
 
-    def __init__(self, substitutes: Dict[int, NDArray]):
+    def __init__(self, substitutes: Dict[int, NDArray], remat=()):
         self.substitutes = substitutes   # id(Parameter) -> wrapper NDArray
         self._prefixes: List[str] = []   # of the blocks being traced
+        # ids of the blocks whose forward is rematerialised in the backward
+        self.remat = frozenset(id(b) for b in remat)
 
     @contextlib.contextmanager
     def block_scope(self, prefix: str):
@@ -320,6 +325,51 @@ class _TraceCtx:
     @staticmethod
     def active() -> Optional["_TraceCtx"]:
         return getattr(_TraceCtx._current, "value", None)
+
+
+def _remat_forward(tc: _TraceCtx, block: "Block", args):
+    """``block.forward(*args)`` under ``jax.checkpoint``: the backward keeps
+    the block's inputs and computes everything inside it again.  The
+    block's parameters ride in as closed-over values.  What the block
+    writes in place (a BatchNorm statistic, an expert layer's load) and the
+    RNG key it draws from cross the boundary as explicit values, so that
+    nothing made inside leaks out of the checkpointed trace."""
+    import jax
+    written = [p for p in block.collect_params().values()
+               if p.grad_req == "null" and id(p) in tc.substitutes]
+    outer = [tc.substitutes[id(p)] for p in written]
+    arrays = [a for a in args if isinstance(a, NDArray)]
+    keyed = bool(getattr(_grandom._tls, "stack", None))
+    key = _grandom.next_key() if keyed else None
+    treedef = []
+
+    def pure(vals, aux, key):
+        it = iter(vals)
+        inner_args = [NDArray(next(it), ctx=a.context)
+                      if isinstance(a, NDArray) else a for a in args]
+        inner = [NDArray(v, ctx=o.context) for v, o in zip(aux, outer)]
+        saved = {id(p): tc.substitutes[id(p)] for p in written}
+        tc.substitutes.update({id(p): w for p, w in zip(written, inner)})
+        try:
+            with _KeyScope(key) if keyed else contextlib.nullcontext():
+                out = block.forward(*inner_args)
+        finally:
+            tc.substitutes.update(saved)
+        flat, tree = jax.tree.flatten(
+            out, is_leaf=lambda o: isinstance(o, NDArray))
+        treedef[:] = [tree, [o.context for o in flat]]
+        return [o._read() for o in flat], \
+            [w._read() if w._version > 0 else None for w in inner]
+
+    with jax.named_scope("remat"):
+        flat, new_aux = jax.checkpoint(pure)(
+            [a._read() for a in arrays], [o._read() for o in outer], key)
+    for o, v in zip(outer, new_aux):
+        if v is not None:
+            o._set_data(v)
+    tree, ctxs = treedef
+    return jax.tree.unflatten(tree, [NDArray(v, ctx=c)
+                                     for v, c in zip(flat, ctxs)])
 
 
 def _param_data_maybe_traced(param: Parameter, ctx) -> NDArray:

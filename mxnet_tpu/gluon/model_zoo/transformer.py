@@ -22,7 +22,8 @@ from typing import Optional
 
 from ...base import MXNetError
 from ..block import HybridBlock
-from ..nn import Dense, Dropout, Embedding, HybridSequential, LayerNorm
+from ..nn import Dense, Dropout, Embedding, HybridSequential, LayerNorm, \
+    RMSNorm, SwiGLU
 
 __all__ = ["SlidingWindowSelfAttention", "LongformerEncoderCell",
            "LongformerEncoder",
@@ -31,6 +32,7 @@ __all__ = ["SlidingWindowSelfAttention", "LongformerEncoderCell",
            "TransformerDecoder", "TransformerNMT", "BERTEncoder",
            "BERTModel", "bert_base", "bert_small", "transformer_nmt_base",
            "CausalLMCell", "CausalLM", "causal_lm_small",
+           "MLAttention", "MLADecoderCell", "MTPModule", "MLAMoELM",
            "TP_RULES"]
 
 #: megatron-style tensor-parallel PartitionSpecs for this family — pass to
@@ -56,6 +58,50 @@ def _masked_softmax(F, scores, mask):
         # additive -1e9 mask (pad-and-mask — the XLA-friendly form)
         scores = scores + (F.cast(mask, dtype="float32") - 1.0) * 1e9
     return F.softmax(scores, axis=-1)
+
+
+def _flash_eligible(F, mask, valid_len, drop) -> bool:
+    # Kernel selection policy (auto by default on TPU):
+    #   MXNET_ATTENTION_KERNEL=flash  force the Pallas kernel
+    #   MXNET_ATTENTION_KERNEL=xla    force the full-softmax XLA path
+    #   unset/auto                    flash on the TPU backend when the
+    #                                 mask is expressible, XLA otherwise
+    # (MXNET_USE_FLASH_ATTENTION=1 is honored as a legacy force-on.)
+    # Eligibility regardless of policy: none-mask always works;
+    # explicit ``valid_len`` lengths ride the kernel's per-row
+    # masking.  An arbitrary (B*H,Sq,Sk) mask WITHOUT lengths falls
+    # back to the XLA path — a 2-D mask cannot be proven to be a
+    # prefix mask under trace, and collapsing a non-prefix mask to a
+    # length silently corrupts attention (caught in round-4 review).
+    # The kernel is differentiable (custom VJP over the chunked
+    # formulation), so training may ride it too — EXCEPT when this
+    # block has attention dropout and dropout is live (train_mode/
+    # record), since the flash path has no probs tensor to drop.
+    from ...base import get_env
+    mode = get_env("MXNET_ATTENTION_KERNEL").lower()
+    legacy = get_env("MXNET_USE_FLASH_ATTENTION")
+    if legacy == "1":
+        mode = "flash"              # legacy force-on
+    elif legacy == "0":
+        mode = "xla"                # legacy explicit force-off
+    if mode in ("xla", "off", "0"):
+        return False
+    if mask is not None and valid_len is None:
+        return False
+    if not hasattr(F, "flash_attention"):
+        return False
+    if drop is not None:
+        from ... import autograd
+        if autograd.is_recording() or autograd.is_training():
+            return False
+    if mode == "flash":
+        return True
+    # auto: default to flash only where Mosaic actually compiles — on
+    # the TPU backend (eager or under whole-graph jit).  Off-TPU the
+    # kernel would run in interpret mode, orders of magnitude slower
+    # than XLA's fused softmax.
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 class MultiHeadAttention(HybridBlock):
@@ -143,47 +189,7 @@ class MultiHeadAttention(HybridBlock):
         return self.proj(self._merge_heads(F, out, b, sq))
 
     def _flash_eligible(self, F, mask, valid_len) -> bool:
-        # Kernel selection policy (auto by default on TPU):
-        #   MXNET_ATTENTION_KERNEL=flash  force the Pallas kernel
-        #   MXNET_ATTENTION_KERNEL=xla    force the full-softmax XLA path
-        #   unset/auto                    flash on the TPU backend when the
-        #                                 mask is expressible, XLA otherwise
-        # (MXNET_USE_FLASH_ATTENTION=1 is honored as a legacy force-on.)
-        # Eligibility regardless of policy: none-mask always works;
-        # explicit ``valid_len`` lengths ride the kernel's per-row
-        # masking.  An arbitrary (B*H,Sq,Sk) mask WITHOUT lengths falls
-        # back to the XLA path — a 2-D mask cannot be proven to be a
-        # prefix mask under trace, and collapsing a non-prefix mask to a
-        # length silently corrupts attention (caught in round-4 review).
-        # The kernel is differentiable (custom VJP over the chunked
-        # formulation), so training may ride it too — EXCEPT when this
-        # block has attention dropout and dropout is live (train_mode/
-        # record), since the flash path has no probs tensor to drop.
-        from ...base import get_env
-        mode = get_env("MXNET_ATTENTION_KERNEL").lower()
-        legacy = get_env("MXNET_USE_FLASH_ATTENTION")
-        if legacy == "1":
-            mode = "flash"              # legacy force-on
-        elif legacy == "0":
-            mode = "xla"                # legacy explicit force-off
-        if mode in ("xla", "off", "0"):
-            return False
-        if mask is not None and valid_len is None:
-            return False
-        if not hasattr(F, "flash_attention"):
-            return False
-        if self.drop is not None:
-            from ... import autograd
-            if autograd.is_recording() or autograd.is_training():
-                return False
-        if mode == "flash":
-            return True
-        # auto: default to flash only where Mosaic actually compiles — on
-        # the TPU backend (eager or under whole-graph jit).  Off-TPU the
-        # kernel would run in interpret mode, orders of magnitude slower
-        # than XLA's fused softmax.
-        import jax
-        return jax.default_backend() == "tpu"
+        return _flash_eligible(F, mask, valid_len, self.drop)
 
 
 class PositionwiseFFN(HybridBlock):
@@ -906,3 +912,199 @@ def causal_lm_small(vocab_size=257, **kwargs):
     kwargs.setdefault("max_length", 256)
     return CausalLM(vocab_size=vocab_size, num_layers=2, units=64,
                     hidden_size=128, num_heads=4, **kwargs)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA), sparse experts and multi-token prediction: the
+# pre-norm causal decoder of the DeepSeek-V3 / GLM-4.x line
+# ---------------------------------------------------------------------------
+
+class MLAttention(HybridBlock):
+    """Multi-head latent attention, causal, for training (no cache): the
+    queries go through a ``q_lora_rank`` bottleneck, keys and values are
+    expanded from one ``kv_lora_rank`` latent per token, and one rotary key
+    of ``qk_rope_head_dim`` lanes is shared by every head.  A head's query
+    and key are ``[nope | rope]``, its value ``v_head_dim`` wide; where the
+    two widths agree the flash kernel serves as it is."""
+
+    def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, epsilon=1e-5, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads, self._nope, self._rope, self._vd = \
+            num_heads, qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+        self._kvr, self._theta = kv_lora_rank, rope_theta
+        qk = qk_nope_head_dim + qk_rope_head_dim
+
+        def lin(out, inp, name):
+            return Dense(out, use_bias=False, flatten=False, in_units=inp,
+                         prefix=name)
+        with self.name_scope():
+            self.q_down = lin(q_lora_rank, units, "q_down_")
+            self.q_norm = RMSNorm(epsilon=epsilon, in_channels=q_lora_rank,
+                                  prefix="q_norm_")
+            self.q_up = lin(num_heads * qk, q_lora_rank, "q_up_")
+            self.kv_down = lin(kv_lora_rank + qk_rope_head_dim, units,
+                               "kv_down_")
+            self.kv_norm = RMSNorm(epsilon=epsilon, in_channels=kv_lora_rank,
+                                   prefix="kv_norm_")
+            self.kv_up = lin(num_heads * (qk_nope_head_dim + v_head_dim),
+                             kv_lora_rank, "kv_up_")
+            self.proj = lin(units, num_heads * v_head_dim, "proj_")
+
+    def hybrid_forward(self, F, x):
+        import jax
+        b, s = x.shape[0], x.shape[1]
+        h, nope, rd, vd = self._heads, self._nope, self._rope, self._vd
+        q = F.reshape(self.q_up(self.q_norm(self.q_down(x))),
+                      shape=(b, s, h, nope + rd))
+        ckv = self.kv_down(x)
+        kv = F.reshape(
+            self.kv_up(self.kv_norm(F.slice_axis(ckv, axis=-1, begin=0,
+                                                 end=self._kvr))),
+            shape=(b, s, h, nope + vd))
+        with jax.named_scope("rope"):
+            q = F.rope(q, base=self._theta, rotary_dim=rd, seq_axis=1)
+            k_rope = F.rope(F.slice_axis(ckv, axis=-1, begin=self._kvr,
+                                         end=None),
+                            base=self._theta, seq_axis=1)
+            k = F.concat(
+                F.slice_axis(kv, axis=-1, begin=0, end=nope),
+                F.broadcast_axis(F.expand_dims(k_rope, axis=2), axis=2,
+                                 size=h), dim=-1)
+        v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+
+        def heads_first(t, width):
+            return F.reshape(F.transpose(t, axes=(0, 2, 1, 3)),
+                             shape=(b * h, s, width))
+        q, k, v = heads_first(q, nope + rd), heads_first(k, nope + rd), \
+            heads_first(v, vd)
+        scale = 1.0 / math.sqrt(nope + rd)
+        if vd == nope + rd and _flash_eligible(F, None, None, None):
+            out = F.flash_attention(q, k, v, causal=True, scale=scale)
+        else:
+            with jax.named_scope("attention_xla"):
+                keep = F.reshape(
+                    F.arange(s).reshape((1, s)) <= F.arange(s).reshape((s, 1)),
+                    shape=(1, s, s))
+                scores = F.batch_dot(q, k, transpose_b=True) * scale
+                out = F.batch_dot(_masked_softmax(
+                    F, scores, F.broadcast_to(keep, shape=scores.shape)), v)
+        out = F.transpose(F.reshape(out, shape=(b, h, s, vd)),
+                          axes=(0, 2, 1, 3))
+        return self.proj(F.reshape(out, shape=(b, s, h * vd)))
+
+
+class MLADecoderCell(HybridBlock):
+    """Pre-norm block: ``x + MLA(RMSNorm(x))``, then ``x + FFN(RMSNorm(x))``
+    with ``ffn`` a dense SwiGLU or a sparse-expert layer."""
+
+    def __init__(self, units, attention, ffn, epsilon=1e-5, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.attn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                     prefix="attn_norm_")
+            self.mla = attention(prefix="mla_")
+            self.ffn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                    prefix="ffn_norm_")
+            self.ffn = ffn()
+
+    def hybrid_forward(self, F, x):
+        x = x + self.mla(self.attn_norm(x))
+        return x + self.ffn(self.ffn_norm(x))
+
+
+class MTPModule(HybridBlock):
+    """One multi-token-prediction depth: the main stack's last hidden state
+    (before its final norm) and the embedding of the NEXT token, each
+    normed, concatenated ``[embedding ; hidden]`` and projected back to the
+    width; one decoder cell; a final norm of its own.  The embedding and
+    the head are the model's."""
+
+    def __init__(self, units, cell, epsilon=1e-5, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.enorm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="enorm_")
+            self.hnorm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="hnorm_")
+            self.eh_proj = Dense(units, use_bias=False, flatten=False,
+                                 in_units=2 * units, prefix="eh_proj_")
+            self.cell = cell(prefix="cell_")
+            self.final_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                      prefix="final_norm_")
+
+    def hybrid_forward(self, F, hidden, next_embed):
+        h = self.eh_proj(F.concat(self.enorm(next_embed), self.hnorm(hidden),
+                                  dim=-1))
+        return self.final_norm(self.cell(h))
+
+
+class MLAMoELM(HybridBlock):
+    """Causal language model of MLA decoder cells: ``first_dense`` leading
+    cells with a dense SwiGLU of ``hidden_size``, the rest with a
+    ``parallel.moe.SparseMoE`` that holds ``experts_held`` of the
+    ``num_experts`` routed experts (one chip's share) beside a shared
+    expert; an untied head; ``num_mtp`` (0 or 1) MTP module.
+
+    ``net(tokens)`` returns the logits (B, S, vocab), and with an MTP
+    module ``(logits, mtp_logits)``: ``mtp_logits[:, i]`` scores token
+    ``i + 2`` (the module's input at position i is the embedding of token
+    ``i + 1``; the last position's input wraps round and has no target).
+    ``remat_blocks`` lists the blocks a trainer rematerialises.
+    """
+
+    def __init__(self, vocab_size, units, num_layers, num_heads, q_lora_rank,
+                 kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 hidden_size, moe_hidden_size, num_experts, top_k,
+                 experts_held=None, num_shared_experts=1, routed_scale=1.0,
+                 norm_topk=True, first_dense=1, num_mtp=1, rope_theta=10000.0,
+                 epsilon=1e-5, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        import functools
+        from ...parallel.moe import SparseMoE
+        if num_mtp not in (0, 1):
+            raise MXNetError(f"num_mtp must be 0 or 1, got {num_mtp!r}")
+        attention = functools.partial(
+            MLAttention, units, num_heads, q_lora_rank, kv_lora_rank,
+            qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+            rope_theta=rope_theta, epsilon=epsilon)
+        dense = functools.partial(SwiGLU, units, hidden_size, prefix="ffn_")
+        sparse = functools.partial(
+            SparseMoE, units, moe_hidden_size, num_experts, top_k,
+            experts_held=experts_held,
+            shared_hidden=moe_hidden_size * num_shared_experts,
+            routed_scale=routed_scale, norm_topk=norm_topk, prefix="moe_")
+
+        def cell(i, prefix):
+            return MLADecoderCell(units, attention,
+                                  dense if i < first_dense else sparse,
+                                  epsilon=epsilon, prefix=prefix)
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, units, prefix="embed_")
+            self.cells = HybridSequential(prefix="")
+            for i in range(num_layers):
+                self.cells.add(cell(i, f"layer{i}_"))
+            self.final_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                      prefix="final_norm_")
+            self.head = Dense(vocab_size, use_bias=False, flatten=False,
+                              in_units=units, prefix="head_")
+            self.mtp = MTPModule(
+                units, functools.partial(cell, first_dense),
+                epsilon=epsilon, prefix="mtp_") if num_mtp else None
+
+    @property
+    def remat_blocks(self):
+        return list(self.cells) + ([self.mtp] if self.mtp is not None else [])
+
+    def hybrid_forward(self, F, tokens):
+        x = self.embed(tokens)
+        for cell in self.cells:
+            x = cell(x)
+        logits = self.head(self.final_norm(x))
+        if self.mtp is None:
+            return logits
+        nxt = F.concat(F.slice_axis(tokens, axis=1, begin=1, end=None),
+                       F.slice_axis(tokens, axis=1, begin=0, end=1), dim=1)
+        return logits, self.head(self.mtp(x, self.embed(nxt)))

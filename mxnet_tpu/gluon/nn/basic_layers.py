@@ -15,7 +15,8 @@ from ..block import Block, HybridBlock
 from ..parameter import Parameter
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "InstanceNorm", "LayerNorm", "GroupNorm", "Embedding",
+           "InstanceNorm", "LayerNorm", "RMSNorm", "SwiGLU", "GroupNorm",
+           "Embedding",
            "RowShardedEmbedding", "Flatten",
            "Lambda", "HybridLambda", "Activation", "LeakyReLU", "PReLU",
            "ELU", "SELU", "GELU", "Swish", "HybridConcurrent", "Identity",
@@ -271,6 +272,44 @@ class LayerNorm(HybridBlock):
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis,
                            eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """``x * rsqrt(mean(x^2) + epsilon) * gamma`` over ``axis``: LayerNorm
+    without the mean and without a shift."""
+
+    def __init__(self, axis=-1, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer,
+                                         allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape = (x.shape[self._axis],)
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+
+
+class SwiGLU(HybridBlock):
+    """Gated feed-forward ``down(silu(gate(x)) * up(x))``, no biases."""
+
+    def __init__(self, units, hidden_size, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.gate = Dense(hidden_size, use_bias=False, flatten=False,
+                              in_units=units, prefix="gate_")
+            self.up = Dense(hidden_size, use_bias=False, flatten=False,
+                            in_units=units, prefix="up_")
+            self.down = Dense(units, use_bias=False, flatten=False,
+                              in_units=hidden_size, prefix="down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.silu(self.gate(x)) * self.up(x))
 
 
 class Embedding(HybridBlock):

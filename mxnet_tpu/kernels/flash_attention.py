@@ -15,7 +15,8 @@ budget, never from a constant a user sets): the grid is
 (batch·heads, Lq/block_q, Lk/kv_block).  A head's K and V stay resident
 in VMEM as one block whenever they fit the budget (kv_block = Lk, the
 last grid axis has one step); longer keys ride a K-major, sequential grid
-axis of ``_KV_MAJOR``-key blocks with the running max/denominator/
+axis of blocks of at most ``_KV_MAJOR`` keys, as many as the budget holds
+(1024 of a 256-lane float32 head), with the running max/denominator/
 accumulator in VMEM scratch between its steps.  Inside a block the sweep
 over (block_k, D) key tiles is a ``lax.fori_loop`` whose carries are the
 (block_q, 1) max and denominator and the (block_q, D) accumulator.  The
@@ -23,6 +24,11 @@ per-row valid length is scalar-prefetched into SMEM and bounds the
 sweep: the loop's trip count is the number of key tiles that hold a valid
 key, and a K-major block wholly beyond the length is neither computed
 nor fetched (its index map is clamped to the last block that holds one).
+Under a causal mask with Lk >= Lq the keys beyond a query tile's last row
+bound the sweep in the same way: tiles and blocks wholly above the diagonal
+are neither visited nor fetched.  The backward is jnp: the vjp of a
+chunked scan where its stacked carries fit ``_BWD_CARRY_BUDGET``, else
+``_blocked_backward``, which stacks nothing.
 The head keeps its own width (a head of 64 is a block 64 lanes wide);
 only ragged Lq/Lk are padded, to the tile.
 
@@ -42,6 +48,13 @@ _NEG_INF = -1e30
 # the chunk of the scanned backward: a constant of its own, so the
 # forward's tiles never change the backward's HLO
 _BWD_CHUNK = 128
+# what the scanned backward may stack as carries (one running max,
+# denominator and accumulator per chunk: Lk / 128 x BH x Lq x (D + 2)
+# float32).  A shape over it takes the blocked backward below, which
+# stacks nothing: 64 chunks of (20, 8192, 256) would be 10.8 GB.
+_BWD_CARRY_BUDGET = 1 << 30
+# the blocked backward's query and key block
+_BWD_BLOCK = 512
 # the forward's tiles (read on a v5e at (192, 512, 64) float32 with the
 # benchmark's lengths, PERF.md section 6, PR 29): a query tile of 512 rows
 # and a key tile of 256 were the fastest pair; keys that fit one lane
@@ -80,10 +93,14 @@ def _tiling(lq: int, lk: int, d: int, itemsize: int):
     lkp = _round_up(lk, block_k)
     # K and V, two buffers each; VMEM rows are whole lanes, so a 64-wide
     # block takes the room of 128
-    if 2 * 2 * lkp * _round_up(dp, 128) * itemsize <= _KV_VMEM_BUDGET:
+    per_key = 2 * 2 * _round_up(dp, 128) * itemsize
+    if lkp * per_key <= _KV_VMEM_BUDGET:
         kv_block = lkp
     else:
-        kv_block = _KV_MAJOR
+        # as many keys as the budget holds, in whole key tiles (a head of
+        # 256 float32 lanes holds 1024 keys where one of 128 holds 2048)
+        kv_block = min(_KV_MAJOR, max(
+            block_k, _KV_VMEM_BUDGET // per_key // block_k * block_k))
         lkp = _round_up(lk, kv_block)
     return block_q, lqp, block_k, kv_block, lkp, dp
 
@@ -117,11 +134,23 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         reg.gauge(f"kernels.flash_attention.{name}",
                   "tiling of the last flash forward kernel built").set(value)
 
+    # under a causal mask whose every row sees a key (Lk >= Lq) the keys
+    # beyond a query tile's last row weigh nothing for the whole tile, so
+    # they bound the sweep as the valid length does; with Lk < Lq some rows
+    # see no key and take the dead-row rule below, which reads every tile
+    diagonal = causal and lk >= lq
+
+    def key_limit(vl, qi):
+        """Keys that query tile ``qi`` of a row of length ``vl`` can weigh."""
+        if not diagonal:
+            return vl
+        return jnp.minimum(vl, (qi + 1) * block_q + (lk - lq))
+
     def sweep(vl, q, k_ref, v_ref, qi, kj, carry):
         """Online softmax of one query tile over the key tiles of K-major
-        block ``kj`` that hold a key below ``vl``: a key at or beyond the
-        row's length has weight 0 whether the row is live or dead, so the
-        tiles beyond it are never visited."""
+        block ``kj`` that hold a key below ``vl`` (the tile's key limit): a
+        key at or beyond it has weight 0 whether the row is live or dead,
+        so the tiles beyond it are never visited."""
         k0 = kj * kv_block
 
         def tile(t, carry):
@@ -186,7 +215,7 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         # per-sequence valid key length (padding mask support): the tile
         # padding bound ``lk`` is static; vl tightens it per row
-        vl = jnp.minimum(vl_ref[b], lk)
+        vl = key_limit(jnp.minimum(vl_ref[b], lk), qi)
         if nkv == 1:
             _, l, acc = sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start())
             finish(o_ref, l, acc)
@@ -212,7 +241,8 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         # block that holds a valid key: the pipeline sees the same block
         # index again and issues no DMA for it
         last = jnp.maximum(
-            pl.cdiv(jnp.minimum(vl_ref[b], lk), kv_block) - 1, 0)
+            pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
+            0)
         return (b, jnp.minimum(j, last), 0)
 
     # Mosaic takes neither a rank-1 block of one element nor rank-1 loop
@@ -303,6 +333,101 @@ def _chunked_reference(q, k, v, vl, causal: bool, scale: float):
     return (acc / l[..., None]).astype(q.dtype)
 
 
+def _blocked_backward(q, k, v, vl, causal: bool, scale: float, out, g):
+    """(dq, dk, dv) of the attention whose output is ``out``, for the
+    cotangent ``g``, with nothing stacked: one scan over the (query block,
+    key block) pairs that hold a weight finds each row's max and
+    denominator, a second forms ``p = exp(s - m) / l`` again pair by pair
+    and adds ``dv += p^T g``, ``ds = p (g v^T - rowsum(g out))``,
+    ``dq += ds k``, ``dk += ds^T q`` into the gradients it carries.  Every
+    array is kept block-major, (blocks, BH, block, D), so that a pair
+    reads and writes whole leading slabs.  Under a causal mask with
+    Lk >= Lq the pairs above the diagonal are not in the list.  Same masks
+    and dead-row rule as the kernel."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bh, lq, d = q.shape
+    lk = k.shape[1]
+    bq = min(_BWD_BLOCK, _round_up(lq, 8))
+    bk = min(_BWD_BLOCK, _round_up(lk, 128))
+    nq, nk = -(-lq // bq), -(-lk // bk)
+    off = lk - lq
+
+    def blocks(x, n, b):
+        x = x.astype(jnp.float32)
+        x = jnp.pad(x, ((0, 0), (0, n * b - x.shape[1]), (0, 0)))
+        return jnp.moveaxis(x.reshape(bh, n, b, d), 1, 0)
+
+    def rows(x, n):
+        return jnp.moveaxis(x, 0, 1).reshape(bh, -1, d)[:, :n]
+    qf, gf, of = blocks(q, nq, bq) * scale, blocks(g, nq, bq), \
+        blocks(out, nq, bq)
+    kf, vf = blocks(k, nk, bk), blocks(v, nk, bk)
+    pairs = [(i, j) for i in range(nq) for j in range(nk)
+             if not (causal and off >= 0) or j * bk <= (i + 1) * bq - 1 + off]
+    pairs = (jnp.asarray([p[0] for p in pairs], jnp.int32),
+             jnp.asarray([p[1] for p in pairs], jnp.int32))
+    vl_eff = jnp.minimum(vl.astype(jnp.float32), jnp.float32(lk))
+
+    def at(x, i):
+        return lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
+
+    def put(x, i, blk):
+        return lax.dynamic_update_index_in_dim(x, blk, i, 0)
+
+    def scores(i, j):
+        s = jnp.einsum("bqd,bkd->bqk", at(qf, i), at(kf, j))
+        k_ids = j * bk + jnp.arange(bk)
+        kmask = (k_ids[None, :].astype(jnp.float32)
+                 < vl_eff[:, None])[:, None, :]
+        mask = kmask
+        if causal:
+            q_ids = i * bq + jnp.arange(bq)
+            mask = mask & (k_ids[None, None, :] <=
+                           q_ids[None, :, None] + off)
+        return jnp.where(mask, s, _NEG_INF), kmask.astype(jnp.float32)
+
+    def stats(carry, ij):
+        m, l = carry
+        i, j = ij
+        s, kmask = scores(i, j)
+        mb = at(m, i)
+        m_new = jnp.maximum(mb, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        p = jnp.where((m_new <= _NEG_INF * 0.5)[..., None], kmask, p)
+        l_new = at(l, i) * jnp.exp(mb - m_new) + jnp.sum(p, axis=-1)
+        return (put(m, i, m_new), put(l, i, l_new)), None
+
+    (m, l), _ = lax.scan(
+        stats, (jnp.full((nq, bh, bq), _NEG_INF, jnp.float32),
+                jnp.zeros((nq, bh, bq), jnp.float32)), pairs)
+    l = jnp.where(l == 0.0, 1.0, l)
+    delta = jnp.sum(gf * of, axis=-1)
+
+    def grads(carry, ij):
+        dq, dk, dv = carry
+        i, j = ij
+        s, kmask = scores(i, j)
+        mb, gb = at(m, i)[..., None], at(gf, i)
+        dead = mb <= _NEG_INF * 0.5
+        p = jnp.where(dead, kmask, jnp.exp(s - mb)) / at(l, i)[..., None]
+        dp = jnp.einsum("bqd,bkd->bqk", gb, at(vf, j))
+        ds = jnp.where(dead, 0.0, p * (dp - at(delta, i)[..., None]))
+        dq = put(dq, i, at(dq, i)
+                 + jnp.einsum("bqk,bkd->bqd", ds, at(kf, j)) * scale)
+        dk = put(dk, j, at(dk, j)
+                 + jnp.einsum("bqk,bqd->bkd", ds, at(qf, i)))
+        dv = put(dv, j, at(dv, j) + jnp.einsum("bqk,bqd->bkd", p, gb))
+        return (dq, dk, dv), None
+
+    (dq, dk, dv), _ = lax.scan(
+        grads, (jnp.zeros_like(qf), jnp.zeros_like(kf), jnp.zeros_like(vf)),
+        pairs)
+    return rows(dq, lq).astype(q.dtype), rows(dk, lk).astype(k.dtype), \
+        rows(dv, lk).astype(v.dtype)
+
+
 @functools.lru_cache(maxsize=1)
 def _flash_core_fn():
     """Module-singleton custom-VJP core (built lazily so importing this
@@ -313,14 +438,25 @@ def _flash_core_fn():
     def core(q, k, v, vl, causal, scale, interpret):
         return _run_kernel(q, k, v, vl, causal, scale, interpret)
 
+    def stacks_too_much(q, k):
+        bh, lq, d = q.shape
+        return -(-k.shape[1] // _BWD_CHUNK) * bh * lq * (d + 2) * 4 \
+            > _BWD_CARRY_BUDGET
+
     def core_fwd(q, k, v, vl, causal, scale, interpret):
-        return _run_kernel(q, k, v, vl, causal, scale, interpret), \
-            (q, k, v, vl)
+        out = _run_kernel(q, k, v, vl, causal, scale, interpret)
+        # the blocked backward reads the output; the scanned one makes
+        # its own and keeps what it always kept
+        return out, (q, k, v, vl, out if stacks_too_much(q, k) else None)
 
     def core_bwd(causal, scale, interpret, res, g):
-        q, k, v, vl = res
+        q, k, v, vl, out = res
         import jax.numpy as jnp
         with jax.named_scope("flash_attention_bwd"):
+            if out is not None:
+                dq, dk, dv = _blocked_backward(q, k, v, vl, causal, scale,
+                                               out, g)
+                return dq, dk, dv, jnp.zeros_like(vl)
             _, vjp = jax.vjp(
                 lambda a, b, c: _chunked_reference(a, b, c, vl, causal,
                                                    scale),

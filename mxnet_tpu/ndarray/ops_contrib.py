@@ -1005,6 +1005,20 @@ def _register_round3b():
                 aliases=("flash_attention",), use_jit=False,
                 vjp_maker=flash_attention_vjp_maker)
 
+    # ---- routed experts (parallel/moe.py, one chip's share) ---------------
+    def routed_experts_maker(top_k=1, first=0, scale=1.0, norm_topk=True):
+        from ..parallel.moe import routed_experts as _re
+
+        def fn(x, router_w, router_b, w_gate, w_up, w_down):
+            return _re(x, router_w, router_b, w_gate, w_up, w_down,
+                       top_k=top_k, first=first, scale=scale,
+                       norm_topk=norm_topk)
+        return fn
+    # use_jit=False: inside a compiled step its scopes (router, dispatch,
+    # experts, combine) stay the program's own, not a nested jit's
+    register_op("_contrib_routed_experts", routed_experts_maker,
+                aliases=("routed_experts",), use_jit=False)
+
     # ---- allclose --------------------------------------------------------
     def allclose_maker(rtol=1e-5, atol=1e-8, equal_nan=False):
         def fn(a, b):

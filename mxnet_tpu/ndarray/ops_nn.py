@@ -358,6 +358,42 @@ def _register():
         return fn
     register_op("LayerNorm", layernorm_maker, aliases=("layer_norm",))
 
+    def rmsnorm_maker(axis=-1, eps=1e-5):
+        """x * rsqrt(mean(x^2) + eps) * gamma over ``axis``; the mean of
+        squares is taken in float32 whatever x is kept in."""
+        def fn(x, gamma):
+            xf = x.astype(jnp.float32)
+            inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis,
+                                     keepdims=True) + eps)
+            shape = [1] * x.ndim
+            shape[axis % x.ndim] = x.shape[axis % x.ndim]
+            return (xf * inv * gamma.reshape(shape)).astype(x.dtype)
+        return fn
+    register_op("RMSNorm", rmsnorm_maker, aliases=("rms_norm",))
+
+    def rope_maker(base=10000.0, rotary_dim=None, seq_axis=1):
+        """Rotary position embedding, rotate-half convention, on the last
+        ``rotary_dim`` lanes of the last axis (all of them by default);
+        the lanes before pass through.  Position i is index i of
+        ``seq_axis``."""
+        def fn(x):
+            d = x.shape[-1]
+            r = d if rotary_dim is None else int(rotary_dim)
+            ax = seq_axis % x.ndim
+            inv = float(base) ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+            ang = jnp.arange(x.shape[ax], dtype=jnp.float32)[:, None] * inv
+            shape = [1] * x.ndim
+            shape[ax], shape[-1] = x.shape[ax], r
+            cos = jnp.tile(jnp.cos(ang), (1, 2)).reshape(shape)
+            sin = jnp.tile(jnp.sin(ang), (1, 2)).reshape(shape)
+            keep, rot = x[..., :d - r], x[..., d - r:].astype(jnp.float32)
+            half = jnp.concatenate([-rot[..., r // 2:], rot[..., :r // 2]],
+                                   axis=-1)
+            out = (rot * cos + half * sin).astype(x.dtype)
+            return out if r == d else jnp.concatenate([keep, out], axis=-1)
+        return fn
+    register_op("_contrib_rope", rope_maker, aliases=("rope",))
+
     def groupnorm_maker(num_groups=1, eps=1e-5, output_mean_var=False):
         def fn(x, gamma, beta):
             # (N, C, ...) -> stats per (N, group); gamma/beta are

@@ -1,14 +1,25 @@
-"""Mixture-of-Experts with expert parallelism (the 'ep' mesh axis).
+"""Mixture-of-Experts layers with expert parallelism.
 
 BEYOND reference parity: the 2018-era reference has no MoE (SURVEY.md
 §2.3 lists EP as absent), but the build mandate makes distributed
-first-class, so the framework ships a TPU-native MoE layer whose experts
-shard over an ``ep`` mesh axis.
+first-class, so the framework ships TPU-native expert layers.
 
-TPU-native design (the Switch/GShard dense-dispatch formulation): top-1
-routing with a capacity limit, expressed entirely as one-hot matmuls and
-batched matmuls — static shapes, everything lands on the MXU, and under
-``pjit`` with the expert-stacked weights sharded ``P('ep', ...)`` XLA
+``SparseMoE`` is the design: an expert layer that is TOLD WHICH EXPERTS IT
+HOLDS (``experts_held=(first, count)``, one chip's share of an
+expert-parallel deployment), routes every token over all ``num_experts``
+(sigmoid scores, selection by score plus a non-gradient bias, top-k,
+renormalised, scaled), and computes its own experts' part of the result
+for the tokens routed to them, dropping none: the token-assignments are
+sorted by expert, the rows of the held experts gathered into one buffer
+and multiplied group by group (``jax.lax.ragged_dot`` over the stacked
+expert weights), weighted and scatter-added back.  A shared expert runs
+beside them on every token.  What the experts held elsewhere would add is
+left out; on one chip the layer runs without its exchange.  The buffer has
+``top_k`` x tokens rows, one for every assignment, so none can fail to fit.
+
+``MoEFFN`` is the older Switch/GShard dense-dispatch form (top-1, a
+capacity limit, one-hot matmuls), kept for the ``ep`` mesh axis: under
+``pjit`` with its expert-stacked weights sharded ``P('ep', ...)`` XLA
 inserts the dispatch/combine all-to-alls over ICI itself.
 
     rules = ShardingRules(EP_RULES() + TP_RULES)
@@ -19,7 +30,8 @@ import math
 
 from ..gluon.block import HybridBlock
 
-__all__ = ["MoEFFN", "EP_RULES"]
+__all__ = ["MoEFFN", "SparseMoE", "routed_experts", "publish_routing",
+           "EP_RULES"]
 
 
 def EP_RULES():
@@ -96,3 +108,144 @@ class MoEFFN(HybridBlock):
         # dropped (over-capacity) tokens pass through as residual zeros;
         # standard Switch keeps the residual connection outside this block
         return F.reshape(out, shape=(B, S, D))
+
+
+def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
+                   first=0, scale=1.0, norm_topk=True):
+    """The routed part of a sparse-expert layer on one chip's share.
+
+    x (T, D); router_w (E, D) and router_b (E,) over ALL experts; w_gate,
+    w_up (G, D, H) and w_down (G, H, D) the ``G`` experts held here, which
+    are experts ``first .. first + G - 1``.  Returns ``(y (T, D), load
+    (G,) token-assignments per held expert)``.  The dispatch buffer has
+    ``top_k`` x T rows, which every assignment fits.  The router runs in
+    float32 at the highest precision whatever x is kept in: a rounded
+    score flips selections."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    g = w_gate.shape[0]
+    with jax.named_scope("router"):
+        s = jax.nn.sigmoid(jnp.einsum(
+            "td,ed->te", x.astype(jnp.float32),
+            router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST))
+        _, sel = lax.top_k(
+            s + lax.stop_gradient(router_b.astype(jnp.float32)), top_k)
+        gate = jnp.take_along_axis(s, sel, axis=-1)
+        if norm_topk:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        gate = gate * scale
+    with jax.named_scope("dispatch"):
+        # a token-assignment's key is its expert's index among the held
+        # ones; those of experts held elsewhere sort behind them all
+        local = sel.reshape(-1) - first
+        local = jnp.where((local >= 0) & (local < g), local, g)
+        load = jnp.zeros((g + 1,), jnp.int32).at[local].add(1)[:g]
+        order = jnp.argsort(local, stable=True)
+        live = jnp.arange(order.shape[0]) < jnp.sum(load)
+        token = order // top_k
+        # rows beyond the held experts' belong to no group: the grouped
+        # matmul leaves them (and, in the backward, their gradient)
+        # unwritten, so they are cut off here on the way in and, with
+        # that, on the gradient's way out
+        rows = jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+    with jax.named_scope("experts"):
+        h = lax.ragged_dot(rows, w_gate.astype(x.dtype), load)
+        u = lax.ragged_dot(rows, w_up.astype(x.dtype), load)
+        out = lax.ragged_dot(jax.nn.silu(h) * u, w_down.astype(x.dtype),
+                             load)
+    with jax.named_scope("combine"):
+        wgt = jnp.where(live, jnp.take(gate.reshape(-1), order), 0.0)
+        out = jnp.where(live[:, None], out, 0) * wgt[:, None].astype(x.dtype)
+        y = jnp.zeros_like(x).at[token].add(out)
+    return y, load.astype(jnp.float32)
+
+
+class SparseMoE(HybridBlock):
+    """One chip's share of a sparse-expert feed-forward (module docstring):
+    sigmoid top-k router over ``num_experts``, the ``experts_held`` routed
+    experts as stacked SwiGLU weights, a shared SwiGLU expert beside them.
+
+    Parameters
+    ----------
+    units, hidden_size : model width, one routed expert's width.
+    num_experts, top_k : the router's outputs and the experts a token takes.
+    experts_held : ``(first, count)``, the routed experts computed here;
+        all of them by default.
+    shared_hidden : the shared expert's width; 0 for none.
+    routed_scale, norm_topk : the gates are renormalised over the selected
+        experts (held here or not), then scaled.
+    """
+
+    def __init__(self, units, hidden_size, num_experts, top_k,
+                 experts_held=None, shared_hidden=0, routed_scale=1.0,
+                 norm_topk=True, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        first, count = experts_held if experts_held is not None \
+            else (0, num_experts)
+        if not (0 <= first and count >= 1 and first + count <= num_experts):
+            raise ValueError(f"experts_held {experts_held!r} is not a run "
+                             f"of the {num_experts} experts")
+        self._k, self._first, self._count = top_k, first, count
+        self._scale, self._norm = routed_scale, norm_topk
+        from ..observability.registry import registry
+        for name, value, doc in (
+                ("experts_routed", num_experts, "experts the router scores"),
+                ("experts_held", count, "routed experts computed here"),
+                ("top_k", top_k, "experts a token takes")):
+            registry().gauge(f"moe.{name}", doc + ", last layer built") \
+                .set(value)
+        from ..gluon.nn import SwiGLU
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, units))
+            self.router_bias = self.params.get(
+                "router_bias", shape=(num_experts,), init="zeros",
+                grad_req="null")
+            self.experts_gate = self.params.get(
+                "experts_gate", shape=(count, units, hidden_size))
+            self.experts_up = self.params.get(
+                "experts_up", shape=(count, units, hidden_size))
+            self.experts_down = self.params.get(
+                "experts_down", shape=(count, hidden_size, units))
+            # what the last step routed: overwritten by every forward in
+            # training, as a BatchNorm statistic is
+            self.expert_load = self.params.get(
+                "expert_load", shape=(count,), init="zeros", grad_req="null")
+            self.shared = SwiGLU(units, shared_hidden, prefix="shared_") \
+                if shared_hidden else None
+
+    def hybrid_forward(self, F, x, router_weight, router_bias, experts_gate,
+                       experts_up, experts_down, expert_load):
+        from .. import autograd
+        shape = x.shape
+        tok = F.reshape(x, shape=(-1, shape[-1]))
+        y, load = F.routed_experts(
+            tok, router_weight, router_bias, experts_gate, experts_up,
+            experts_down, top_k=self._k, first=self._first,
+            scale=self._scale, norm_topk=self._norm)
+        if autograd.is_training():
+            expert_load._set_data(load._read())
+        y = F.reshape(y, shape=shape)
+        return y if self.shared is None else y + self.shared(x)
+
+
+def publish_routing(trainer) -> dict:
+    """What the last step of ``trainer`` (a ``ShardedTrainer``) wrote into
+    its ``SparseMoE`` layers' aux buffers, as gauges: ``moe.expert_load_max``
+    and ``moe.expert_load_mean`` (token-assignments per held expert, in the
+    layer where max / mean is worst).  Waits for the step, so call it where
+    the loss is read anyway.  Returns the two."""
+    from ..observability.registry import registry
+    aux = trainer.aux_values()
+    loads = [v for k, v in aux.items() if k.endswith("expert_load")]
+    worst = max(loads, key=lambda v: float(v.max()) / max(float(v.mean()),
+                                                          1e-9))
+    out = {"expert_load_max": float(worst.max()),
+           "expert_load_mean": float(worst.mean())}
+    for name, value in out.items():
+        registry().gauge(f"moe.{name}", "of the last step read: token-"
+                         "assignments per held expert in the worst layer"
+                         ).set(value)
+    return out

@@ -102,6 +102,11 @@ class ShardedTrainer:
         with ``optimization_barrier``-chained sharding constraints so
         XLA's latency-hiding scheduler overlaps each bucket's
         collective with the remaining backward compute.
+    remat : sequence of Blocks — the blocks of the model whose forward
+        is rematerialised in the backward (``jax.checkpoint`` around each:
+        the step keeps a block's inputs and computes its inside again),
+        for a model whose activations would not fit beside its state.
+        Empty (the default) traces exactly the step without it.
     """
 
     def __init__(self, block, loss: Callable, optimizer,
@@ -112,6 +117,7 @@ class ShardedTrainer:
                  zero_stage: Optional[int] = None,
                  accum_steps: Optional[int] = None,
                  comm_bucket_mb: Optional[float] = None,
+                 remat: Sequence = (),
                  guard_nonfinite: bool = False,
                  dynamic_loss_scale: bool = False,
                  init_loss_scale: float = 2.0 ** 15,
@@ -146,6 +152,7 @@ class ShardedTrainer:
                 f"reduction), got {comm_bucket_mb!r}")
         self._bucket_mb = float(comm_bucket_mb)
         self._grad_buckets = None
+        self._remat = tuple(remat)
         # forced checkpoint layout: None = auto (_host_local_checkpoint
         # decides from the process group); tests/bench set True to
         # exercise the self-contained npz writer in a single process
@@ -193,9 +200,13 @@ class ShardedTrainer:
         import jax
         import jax.numpy as jnp
 
-        # one tiny eager forward to settle deferred param shapes
-        probes = [NDArray(jnp.asarray(v[:1]), ctx=self._ctx) for v in xs]
-        self._block(*probes)
+        # one tiny eager forward to settle deferred param shapes; a model
+        # whose shapes are all given needs none (one row of a long
+        # sequence is no tiny forward)
+        if any(p._deferred_init is not None
+               for p in self._block.collect_params().values()):
+            probes = [NDArray(jnp.asarray(v[:1]), ctx=self._ctx) for v in xs]
+            self._block(*probes)
 
         all_params = list(self._block.collect_params().values())
         self._train_params: List[Parameter] = \
@@ -316,7 +327,10 @@ class ShardedTrainer:
         import jax.numpy as jnp
 
         self._dispatch_metrics = _dispatch_metrics()
-        block, loss_blk = self._block, self._loss
+        block, loss_blk, remat = self._block, self._loss, self._remat
+        _metrics_registry().gauge(
+            "trainer.remat_blocks", "blocks the last trainer built "
+            "rematerialises in its backward").set(len(remat))
         tparams, aparams = self._train_params, self._aux_params
         fopt, ctx = self._fopt, self._ctx
 
@@ -327,7 +341,7 @@ class ShardedTrainer:
             tw = [NDArray(v, ctx=ctx) for v in pvals]
             aw = [NDArray(v, ctx=ctx) for v in avals]
             subs = {id(p): w for p, w in zip(tparams + aparams, tw + aw)}
-            with _TraceCtx(subs), \
+            with _TraceCtx(subs, remat if training else ()), \
                     _autograd._RecordingScope(False, training), \
                     _KeyScope(key):
                 out = block(*[NDArray(v, ctx=ctx) for v in xv])
@@ -1043,6 +1057,14 @@ class ShardedTrainer:
                 "sparse.grad_density",
                 "id-bucket rows / vocab across sparse tables (last "
                 "step)").set(rows / vocab_sum)
+
+    def aux_values(self) -> dict:
+        """``{parameter name: host array}`` of the non-gradient buffers as
+        the last step left them (a BatchNorm's running statistics, an
+        expert layer's load).  Waits for that step."""
+        import jax
+        return dict(zip((p.name for p in self._aux_params),
+                        jax.device_get(list(self._avals))))
 
     # -- supervised-retry support (ResilientTrainer) -----------------------
     def step_state(self):

@@ -330,8 +330,7 @@ SHORT_GAP_NS = 20e3   # shorter gaps lie between two operations of one program
 #: names jax puts into an op_name that are not scopes of the program
 _NOT_A_SCOPE = re.compile(
     r"^((p?jit|vmap|shard_map)\(.*|while|cond|body|branch_\d+_fun|"
-    r"closed_call|checkpoint|remat\d*|rematted_computation|"
-    r"custom_(jvp|vjp)_call(_jaxpr)?)$")
+    r"closed_call|custom_(jvp|vjp)_call(_jaxpr)?)$")
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:
@@ -440,7 +439,10 @@ def scope_of(op_name: str, depth: int):
     ``jvp``/``transpose``, which mark the backward), then jax's own names
     down to the primitive.  The scope is the program's part, cut to
     ``depth`` names, with a block's number starred so that twelve layers
-    make one row; ``""`` where the program named nothing."""
+    make one row; ``""`` where the program named nothing.  Under a
+    rematerialised block (``ShardedTrainer(remat=...)``) the scope reads
+    ``.../layer*/remat/mla/...``, and ``.../layer*/remat/recompute/mla/...``
+    for the forward that the backward runs again."""
     parts = op_name.split("/")
     way = "bwd" if any(p.startswith("transpose(") for p in parts) else "fwd"
     if parts and parts[0].startswith(("jit(", "pjit(")):
@@ -449,9 +451,19 @@ def scope_of(op_name: str, depth: int):
     for p in parts[:-1]:            # the last name is the primitive's
         while p.startswith(("transpose(", "jvp(")) and p.endswith(")"):
             p = p[p.index("(") + 1:-1]
+        if p == "checkpoint":
+            continue
+        if p == "rematted_computation":
+            p = "recompute"         # the forward, run again in the backward
         if not p or _NOT_A_SCOPE.match(p):
             break
-        own.append(re.sub(r"\d+$", "*", p))
+        p = re.sub(r"\d+$", "*", p)
+        if own and p == own[0]:
+            # the backward of a rematerialised block says its path twice
+            # (``.../layer1/remat/jvp(net0)/layer1/remat/checkpoint/...``):
+            # the root's name starts it again
+            own = []
+        own.append(p)
     return "/".join(own[:depth]), way
 
 

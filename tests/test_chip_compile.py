@@ -69,6 +69,8 @@ def _compiled_text(fn, *args):
     ((192, 512, 64), None, "bfloat16", True, False, False),
     ((8, 4096, 128), None, "float32", True, False, False),
     ((8, 1, 128), 300, "float32", True, True, True),
+    # the MLA cell: 20 heads of 256 over 8192 keys, causal, K-major
+    ((20, 8192, 256), None, "float32", False, True, False),
 ])
 def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
                                           valid_len, causal, pads):
@@ -90,6 +92,25 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
     text = _compiled_text(fwd, *args)
     assert "tpu_custom_call" in text
     assert (" pad(" in text) == pads
+
+
+def test_flash_attention_backward_at_8k_compiles_for_v5e(one_chip):
+    """Forward and blocked backward at the MLA cell's shape fit the chip:
+    the scanned backward would stack 10.8 GB of carries there."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+
+    arg = jax.ShapeDtypeStruct((20, 8192, 256), "float32", sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(arg, arg, arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
 
 
 def _resnet50_shapes():

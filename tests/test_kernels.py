@@ -524,6 +524,67 @@ def test_flash_attention_tile_choice_and_skipped_tiles(
     assert not out[0].any()          # no valid key: the row divides by 1
 
 
+@pytest.mark.parametrize("lq,lk,causal,ragged", [
+    (600, 600, True, False), (600, 600, True, True), (520, 520, False, False),
+    (130, 700, False, True), (200, 640, True, True), (640, 200, True, False),
+    (640, 200, True, True)],
+    ids=["causal", "causal_ragged", "full", "cross_ragged", "causal_lk_gt_lq",
+         "causal_lk_lt_lq_dead_rows", "causal_lk_lt_lq_ragged"])
+def test_flash_blocked_backward_matches_full_softmax(
+        monkeypatch, lq, lk, causal, ragged):
+    """The blocked backward (taken where the scanned one would stack too
+    much; here forced) gives the dq, dk, dv of the plain softmax
+    attention: several query and key blocks, pairs above the diagonal
+    left out, rows' valid lengths on both sides of a block's edge and
+    nought, rows that see no key (they weigh their valid keys evenly and
+    pass no gradient to q and k), Lk on either side of Lq."""
+    import jax
+    import jax.numpy as jnp
+    fa = _flash_module()
+    monkeypatch.setattr(fa, "_BWD_CARRY_BUDGET", 0)
+    calls, blocked = [], fa._blocked_backward
+    monkeypatch.setattr(fa, "_blocked_backward",
+                        lambda *a: calls.append(1) or blocked(*a))
+
+    d, scale = 16, 0.3
+    lens = [lk, 0, 1, 511, 513, lk - 1] if ragged else [lk, lk]
+    lens = np.array([min(n, lk) for n in lens], np.float32)
+    rs = np.random.RandomState(11)
+    q, k, v, g = (jnp.asarray(rs.randn(len(lens), n, d).astype(np.float32))
+                  for n in (lq, lk, lk, lq))
+
+    def full(q, k, v):
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+        keep = jnp.arange(lk)[None, None, :] < lens[:, None, None]
+        live = keep
+        if causal:
+            live = keep & (jnp.arange(lk)[None, None, :] <=
+                           jnp.arange(lq)[None, :, None] + (lk - lq))
+        dead = ~live.any(-1, keepdims=True)
+        p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+        p = jnp.where(dead, keep / jnp.maximum(keep.sum(-1, keepdims=True),
+                                               1), p)
+        return jnp.einsum("bqk,bkd->bqd", p, v)
+
+    def flashed(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, scale=scale,
+                                  valid_len=jnp.asarray(lens))
+
+    with jax.default_matmul_precision("highest"):
+        want_out, vjp = jax.vjp(full, q, k, v)
+        want = vjp(g)
+        got_out, vjp = jax.vjp(flashed, q, k, v)
+        got = vjp(g)
+    assert calls
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(want_out),
+                               atol=3e-5, rtol=0)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=0, err_msg=name)
+    if causal and lk < lq:
+        assert not np.asarray(got[0])[0, :lq - lk].any()   # dead rows: dq 0
+
+
 def test_flash_backward_keeps_its_chunk(monkeypatch):
     """The scanned backward has a chunk of its own (128): its HLO does not
     move with the forward's tiles, and it still sweeps ceil(Lk / 128)

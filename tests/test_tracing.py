@@ -316,6 +316,9 @@ def test_step_breakdown_names_loader_under_stall(traced, tmp_path):
     # 1.2s: comfortably above any residual (post-priming) compile wall,
     # so the stalled step owns the histogram's top exemplar bucket
     faults.set_fault_plan("loader_stall@4:1.2")
+    # the wall histogram is the process's: an earlier file on this worker
+    # may have left a slower step's exemplar in its top bucket
+    registry().reset("resilience.")
     tr = _mini_trainer()
     rng = np.random.RandomState(0)
     data = [(rng.randn(8).astype(np.float32), rng.randint(0, 4))

@@ -38,7 +38,9 @@ def test_bert_shapes_and_backward():
     l.backward()
     assert mlm.shape == (2, 12, 100)
     assert nsp.shape == (2, 2)
-    g = net.collect_params()["bertmodel0_word_embed_weight"].data().grad
+    # by the net's own prefix: its number says how many were built before
+    # it on this worker, which the order of the files decides
+    g = net.collect_params()[net.prefix + "word_embed_weight"].data().grad
     assert float(abs(g).sum().asnumpy()) > 0
 
 
