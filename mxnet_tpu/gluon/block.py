@@ -327,13 +327,37 @@ class _TraceCtx:
         return getattr(_TraceCtx._current, "value", None)
 
 
+def _keep_named():
+    """The policy of a rematerialised block: keep the values the program
+    has named as dearer to make again than to hold (one so far, the flash
+    kernel's output) and nothing else.  Each value it keeps adds its bytes
+    to the gauge ``trainer.remat_kept_bytes``, which the trainer sets to 0
+    before it traces a step: jax asks the policy once an equation while it
+    splits the block's jaxpr into what is kept and what is made again, so
+    the block is not traced a second time for the count."""
+    import jax
+    from ..kernels.flash_attention import KEPT_OUTPUT
+    from ..observability.registry import registry
+    named = jax.checkpoint_policies.save_only_these_names(KEPT_OUTPUT)
+    kept = registry().gauge("trainer.remat_kept_bytes")
+
+    def policy(prim, *avals, **params):
+        keep = named(prim, *avals, **params)
+        if keep:
+            kept.set(kept.value + sum(a.size * a.dtype.itemsize
+                                      for a in avals))
+        return keep
+    return policy
+
+
 def _remat_forward(tc: _TraceCtx, block: "Block", args):
     """``block.forward(*args)`` under ``jax.checkpoint``: the backward keeps
-    the block's inputs and computes everything inside it again.  The
-    block's parameters ride in as closed-over values.  What the block
-    writes in place (a BatchNorm statistic, an expert layer's load) and the
-    RNG key it draws from cross the boundary as explicit values, so that
-    nothing made inside leaks out of the checkpointed trace."""
+    the block's inputs and what ``_keep_named`` says, and computes
+    everything else inside it again.  The block's parameters ride in as
+    closed-over values.  What the block writes in place (a BatchNorm
+    statistic, an expert layer's load) and the RNG key it draws from cross
+    the boundary as explicit values, so that nothing made inside leaks out
+    of the checkpointed trace."""
     import jax
     written = [p for p in block.collect_params().values()
                if p.grad_req == "null" and id(p) in tc.substitutes]
@@ -362,7 +386,7 @@ def _remat_forward(tc: _TraceCtx, block: "Block", args):
             [w._read() if w._version > 0 else None for w in inner]
 
     with jax.named_scope("remat"):
-        flat, new_aux = jax.checkpoint(pure)(
+        flat, new_aux = jax.checkpoint(pure, policy=_keep_named())(
             [a._read() for a in arrays], [o._read() for o in outer], key)
     for o, v in zip(outer, new_aux):
         if v is not None:

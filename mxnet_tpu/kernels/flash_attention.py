@@ -66,6 +66,11 @@ _MAX_BLOCK_K = 256
 # rest is Q, the output and the sweep's own temporaries
 _KV_VMEM_BUDGET = 4 * 1024 * 1024
 _KV_MAJOR = 2048
+# the ``checkpoint_name`` of the kernel's output: dearer to make again
+# than to hold, so a rematerialised block keeps it across its checkpoint
+# (``gluon/block.py:_remat_forward``) and the backward does not run the
+# kernel a second time.  Outside a checkpoint the name lowers to nothing.
+KEPT_OUTPUT = "flash_attention_out"
 
 
 def _interpret(example=None) -> bool:
@@ -433,6 +438,7 @@ def _flash_core_fn():
     """Module-singleton custom-VJP core (built lazily so importing this
     module never imports jax)."""
     import jax
+    from jax.ad_checkpoint import checkpoint_name
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
     def core(q, k, v, vl, causal, scale, interpret):
@@ -444,7 +450,11 @@ def _flash_core_fn():
             > _BWD_CARRY_BUDGET
 
     def core_fwd(q, k, v, vl, causal, scale, interpret):
-        out = _run_kernel(q, k, v, vl, causal, scale, interpret)
+        # named here, before it is both the primal and a residual: a name
+        # put on by the caller would sit on another variable than the one
+        # the backward reads
+        out = checkpoint_name(
+            _run_kernel(q, k, v, vl, causal, scale, interpret), KEPT_OUTPUT)
         # the blocked backward reads the output; the scanned one makes
         # its own and keeps what it always kept
         return out, (q, k, v, vl, out if stacks_too_much(q, k) else None)
@@ -454,6 +464,19 @@ def _flash_core_fn():
         import jax.numpy as jnp
         with jax.named_scope("flash_attention_bwd"):
             if out is not None:
+                # where a rematerialised block has kept ``out``, nothing
+                # in its backward reads q, k, v at their own precision any
+                # more (the kernel's call did), and XLA narrows the
+                # projections that make them again to the bfloat16 of the
+                # matmuls below; a narrowed 84 MB operand of the second
+                # scan then fits VMEM, is left there across the loop and is
+                # copied out and sliced back in every pair (130 ms of the
+                # 8k cell's step, PERF.md section 6, PR 31).  A rounding to
+                # the precision they have is an identity that no conversion
+                # moves across: they arrive as the kernel took them.
+                bits = jnp.finfo(q.dtype)
+                q, k, v = (jax.lax.reduce_precision(x, bits.nexp, bits.nmant)
+                           for x in (q, k, v))
                 dq, dk, dv = _blocked_backward(q, k, v, vl, causal, scale,
                                                out, g)
                 return dq, dk, dv, jnp.zeros_like(vl)

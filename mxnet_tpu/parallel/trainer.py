@@ -331,6 +331,12 @@ class ShardedTrainer:
         _metrics_registry().gauge(
             "trainer.remat_blocks", "blocks the last trainer built "
             "rematerialises in its backward").set(len(remat))
+        # what those blocks keep for the backward all the same: each
+        # trace of the step counts anew (gluon/block.py:_keep_named adds)
+        kept_bytes = _metrics_registry().gauge(
+            "trainer.remat_kept_bytes", "bytes of named values that the "
+            "rematerialised blocks of the last step traced keep for the "
+            "backward")
         tparams, aparams = self._train_params, self._aux_params
         fopt, ctx = self._fopt, self._ctx
 
@@ -448,6 +454,7 @@ class ShardedTrainer:
             over microbatch sum-loss gradients equals the full-batch
             gradient, so the optimizer's rescale is unchanged."""
             def grads_of(pvals, avals, key, xv, yv, ls):
+                kept_bytes.set(0)
                 if accum == 1:
                     # trace-time probe: which sparse-marked tables does
                     # THIS trace's forward actually reach, and with how
@@ -1132,15 +1139,12 @@ class ShardedTrainer:
             return tuple(NDArray(o, ctx=self._ctx) for o in out)
         return NDArray(out, ctx=self._ctx)
 
-    def lower_step(self, x, y):
-        """The train step for this batch, lowered (``jax.stages.Lowered``)
-        against the live state without running or donating it.
-        ``.compile()`` gives the program as the backend builds it: its
-        ``as_text()`` is where a check reads which kernels
-        (``tpu_custom_call``) and collectives (``reduce-scatter``,
-        ``all-gather``) the step really carries, its
-        ``memory_analysis()`` what it needs on each device.  With a
-        persistent compilation cache on, that compile is a cache read."""
+    def trace_step(self, x, y):
+        """The train step for this batch, traced (``jax.stages.Traced``)
+        against the live state without running or donating it: its
+        ``.jaxpr`` is where a check reads what the backward makes again
+        (a ``pallas_call`` under a rematerialised block's ``checkpoint``),
+        its ``.lower()`` is ``lower_step``."""
         import jax
         import jax.numpy as jnp
         xv, yv = self.shard_batch(x, y)
@@ -1150,9 +1154,20 @@ class ShardedTrainer:
                    jnp.asarray(0.0, jnp.float32),
                    jnp.asarray(1.0, jnp.float32))
         guard = (self._gstate,) if self._guard else ()
-        return self._jit_step.lower(
+        return self._jit_step.trace(
             self._pvals, self._avals, self._state, *scalars, *guard,
             xv, yv)
+
+    def lower_step(self, x, y):
+        """The train step for this batch, lowered (``jax.stages.Lowered``)
+        against the live state without running or donating it.
+        ``.compile()`` gives the program as the backend builds it: its
+        ``as_text()`` is where a check reads which kernels
+        (``tpu_custom_call``) and collectives (``reduce-scatter``,
+        ``all-gather``) the step really carries, its
+        ``memory_analysis()`` what it needs on each device.  With a
+        persistent compilation cache on, that compile is a cache read."""
+        return self.trace_step(x, y).lower()
 
     def _checkpointer(self):
         # one long-lived async checkpointer: save() returns once the

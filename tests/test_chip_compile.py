@@ -113,6 +113,44 @@ def test_flash_attention_backward_at_8k_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
 
 
+@pytest.mark.parametrize("keeps,calls", [(True, 1), (False, 2)],
+                         ids=["output_kept", "nothing_kept"])
+def test_checkpointed_attention_block_runs_the_kernel_once_for_v5e(
+        one_chip, keeps, calls):
+    """A projection-attention-projection block at the MLA cell's shape
+    under ``jax.checkpoint`` with the policy of a rematerialised block
+    (``gluon/block.py:_keep_named``): the compiled gradient holds one flash
+    kernel, the forward's, whose output the backward reads; under a
+    checkpoint that keeps nothing it holds two."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.gluon.block import _keep_named
+    from mxnet_tpu.kernels import flash_attention
+
+    heads, seq, d, units = 20, 8192, 256, 128
+    x = jax.ShapeDtypeStruct((seq, units), "float32", sharding=one_chip)
+    w_in = jax.ShapeDtypeStruct((units, 3 * heads * d), "float32",
+                                sharding=one_chip)
+    w_out = jax.ShapeDtypeStruct((heads * d, units), "float32",
+                                 sharding=one_chip)
+
+    def block(x, w_in, w_out):
+        q, k, v = jnp.moveaxis(
+            (x @ w_in).reshape(seq, 3, heads, d), (1, 2), (0, 1))
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.moveaxis(out, 0, 1).reshape(seq, heads * d) @ w_out
+
+    def loss(x, w_in, w_out):
+        policy = _keep_named() if keeps else None
+        # squared: the cotangent reads the forward's result, so the
+        # forward is not dead beside the backward's own
+        return jnp.sum(
+            jax.checkpoint(block, policy=policy)(x, w_in, w_out) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, w_in, w_out)
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+
 def _resnet50_shapes():
     """Every trainable tensor of ResNet-50 v1 (161 of them, 25.6M
     elements): the tail of small tensors is what the multi-tensor apply

@@ -524,20 +524,26 @@ def test_flash_attention_tile_choice_and_skipped_tiles(
     assert not out[0].any()          # no valid key: the row divides by 1
 
 
-@pytest.mark.parametrize("lq,lk,causal,ragged", [
-    (600, 600, True, False), (600, 600, True, True), (520, 520, False, False),
-    (130, 700, False, True), (200, 640, True, True), (640, 200, True, False),
-    (640, 200, True, True)],
+@pytest.mark.parametrize("lq,lk,causal,ragged,unnamed", [
+    (600, 600, True, False, False), (600, 600, True, True, False),
+    (520, 520, False, False, False), (130, 700, False, True, False),
+    (200, 640, True, True, False), (640, 200, True, False, False),
+    (640, 200, True, True, False), (600, 600, True, True, True)],
     ids=["causal", "causal_ragged", "full", "cross_ragged", "causal_lk_gt_lq",
-         "causal_lk_lt_lq_dead_rows", "causal_lk_lt_lq_ragged"])
+         "causal_lk_lt_lq_dead_rows", "causal_lk_lt_lq_ragged",
+         "causal_ragged_output_unnamed"])
 def test_flash_blocked_backward_matches_full_softmax(
-        monkeypatch, lq, lk, causal, ragged):
+        monkeypatch, lq, lk, causal, ragged, unnamed):
     """The blocked backward (taken where the scanned one would stack too
     much; here forced) gives the dq, dk, dv of the plain softmax
     attention: several query and key blocks, pairs above the diagonal
     left out, rows' valid lengths on both sides of a block's edge and
     nought, rows that see no key (they weigh their valid keys evenly and
-    pass no gradient to q and k), Lk on either side of Lq."""
+    pass no gradient to q and k), Lk on either side of Lq.  ``unnamed``:
+    outside a checkpoint the name on the kernel's output (``KEPT_OUTPUT``)
+    is an identity, the output and the gradients are bit for bit those of
+    a kernel whose output carries no name; and q, k and v reach this
+    backward through a rounding to their own precision."""
     import jax
     import jax.numpy as jnp
     fa = _flash_module()
@@ -583,6 +589,26 @@ def test_flash_blocked_backward_matches_full_softmax(
                                    rtol=0, err_msg=name)
     if causal and lk < lq:
         assert not np.asarray(got[0])[0, :lq - lk].any()   # dead rows: dq 0
+    if unnamed:
+        names = []
+        with monkeypatch.context() as m:
+            m.setattr(jax.ad_checkpoint, "checkpoint_name",
+                      lambda x, name: names.append(name) or x)
+            fa._flash_core_fn.cache_clear()
+            try:
+                bare_out, vjp = jax.vjp(flashed, q, k, v)
+                bare = vjp(g)
+            finally:
+                fa._flash_core_fn.cache_clear()
+        assert names == [fa.KEPT_OUTPUT]
+        for a, b in zip((got_out, *got), (bare_out, *bare)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        # q, k and v reach the blocked backward through a rounding to
+        # their own precision (an identity that keeps XLA from narrowing
+        # what makes them again in a rematerialised block)
+        text = str(jax.make_jaxpr(lambda *a: jax.vjp(flashed, *a)[1](g))(
+            q, k, v))
+        assert text.count("reduce_precision[") == 3
 
 
 def test_flash_backward_keeps_its_chunk(monkeypatch):
