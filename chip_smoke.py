@@ -58,7 +58,7 @@ FLASH_BF16_ATOL = 1e-2      # kernel output rounded to bf16, |out| of order 1
 FLASH_BF16_RTOL = 1e-2
 FLASH_GRAD_RTOL = 2e-2      # backward runs at default (bf16-pass) precision
 DP_LOSS_RTOL = 5e-2         # dp=4 vs dp=1: same math, other reduction order
-# The comparison steps gently.  At bench.py's 0.1 on random labels the
+# The comparison steps gently.  At a rate of 0.1 on random labels the
 # trajectory amplifies rounding: in float32 on CPU devices a relative
 # difference of 1e-5 after step 1 was 4.5% after step 2.
 DP_COMPARE_LR = 1e-3

@@ -1,7 +1,7 @@
 """BERT pretraining example: the real masked-LM + NSP objective through
 the sharded trainer, with optional flash attention.
 
-Mirrors the round-4 bench config #3 as a user-facing recipe:
+BASELINE config #3 as a user-facing recipe:
   - 15% of (valid) tokens masked; labels are the original ids; the loss
     is CE over masked positions plus the NSP head's CE
   - padding arrives as (B,) valid LENGTHS (the GluonNLP valid_length
@@ -11,7 +11,7 @@ Mirrors the round-4 bench config #3 as a user-facing recipe:
 
 Run (synthetic data, tiny model):
   python examples/pretrain_bert_mlm.py --steps 20
-  MXNET_USE_FLASH_ATTENTION=1 python examples/pretrain_bert_mlm.py
+  MXNET_ATTENTION_KERNEL=flash python examples/pretrain_bert_mlm.py
 """
 import argparse
 import os

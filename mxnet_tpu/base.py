@@ -193,10 +193,6 @@ register_env("MXNET_ENGINE_BULK_FUSE", "exact", str,
              "bitwise-identical to unbulked) or 'aggressive' (full XLA "
              "fusion incl. taped segments; FMA contraction may shift "
              "results by ~1 ulp).")
-register_env("MXNET_ENFORCE_DETERMINISM", False, bool,
-             "Request deterministic kernel selection (XLA default is deterministic).")
-register_env("MXNET_GPU_MEM_POOL_RESERVE", 5, int,
-             "Percent of device memory to keep free (advisory under XLA).")
 register_env("MXNET_TEST_SEED", None, int, "Seed override for the test harness.")
 register_env("MXNET_SAFE_ACCUMULATION", True, bool,
              "Accumulate fp16/bf16 reductions in fp32.")
@@ -204,7 +200,8 @@ register_env("MXNET_DEFAULT_DTYPE", "float32", str,
              "Default dtype for new arrays (float32; set bfloat16 for TPU-native).")
 register_env("MXNET_MATMUL_PRECISION", "", str,
              "jax matmul precision override; 'highest' forces full fp32 "
-             "accumulation (reference-exact numerics, ~3x slower matmuls).")
+             "accumulation (reference-exact numerics; a float32 matmul "
+             "then takes several bfloat16 passes).")
 register_env("MXNET_OPTIMIZER_AGGREGATION_SIZE", 4, int,
              "Max weights updated per fused multi-tensor optimizer call.")
 register_env("MXNET_TEST_DEFAULT_CTX", "", str,
@@ -215,9 +212,6 @@ register_env("MXNET_PALLAS_INTERPRET", False, bool,
 register_env("MXNET_ATTENTION_KERNEL", "auto", str,
              "Attention path: 'auto' (flash when eligible), 'flash' "
              "(force the Pallas kernel), or 'xla' (full-softmax XLA path).")
-register_env("MXNET_USE_FLASH_ATTENTION", "", str,
-             "Legacy tri-state attention override: '1' forces flash, "
-             "'0' forces XLA, unset defers to MXNET_ATTENTION_KERNEL.")
 register_env("MXTPU_DIST_TIMEOUT", 300.0, float,
              "Per-attempt timeout (seconds) for joining the process group "
              "and for the coordination-service KV/barrier collectives.")
@@ -384,22 +378,6 @@ register_env("MXTPU_ELASTIC_REFORM_TIMEOUT", 60.0, float,
              "(view exchange, plan, acks, commit).  A survivor that "
              "cannot complete the round within it raises FleetLost "
              "instead of waiting forever on a fleet that cannot agree.")
-register_env("MXTPU_ZERO_STAGE", 0, int,
-             "Default ZeRO optimizer-state partitioning stage for "
-             "ShardedTrainer (0, 1 or 2).  0 = optimizer state "
-             "replicated on every chip (bitwise-identical to the "
-             "pre-ZeRO step); 1 = state sharded 1/dp per chip, "
-             "gradients reduce-scattered into each chip's slice and "
-             "updated params all-gathered inside the one jitted step; "
-             "2 = the gradient (accumulation) buffer is sharded too.  "
-             "The zero_stage= constructor argument overrides.")
-register_env("MXTPU_ACCUM_STEPS", 1, int,
-             "Default microbatched gradient accumulation for "
-             "ShardedTrainer: the step consumes its global batch as N "
-             "sequential microbatches under a lax.scan (per-microbatch "
-             "RNG split, rescale-correct vs the full batch), so global "
-             "batch scales past per-chip activation memory.  The "
-             "accum_steps= constructor argument overrides.")
 register_env("MXTPU_PREEMPT_COORD", True, bool,
              "Coordinated preemption checkpoints: in a multi-process "
              "group, a SIGTERM'd ResilientTrainer publishes a flush "

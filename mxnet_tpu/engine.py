@@ -187,8 +187,7 @@ class Engine:
         BulkSizeController's apply path.  Environment-backed on purpose:
         the ``bulk_size`` property reads the knob at segment creation,
         so the new cap takes effect on the very next segment, and child
-        processes (bench subprocesses, spawned workers) inherit the
-        tuned value."""
+        processes (spawned workers) inherit the tuned value."""
         os.environ["MXNET_ENGINE_BULK_SIZE"] = str(max(1, int(n)))
 
     @property

@@ -66,7 +66,6 @@ def _flash_eligible(F, mask, valid_len, drop) -> bool:
     #   MXNET_ATTENTION_KERNEL=xla    force the full-softmax XLA path
     #   unset/auto                    flash on the TPU backend when the
     #                                 mask is expressible, XLA otherwise
-    # (MXNET_USE_FLASH_ATTENTION=1 is honored as a legacy force-on.)
     # Eligibility regardless of policy: none-mask always works;
     # explicit ``valid_len`` lengths ride the kernel's per-row
     # masking.  An arbitrary (B*H,Sq,Sk) mask WITHOUT lengths falls
@@ -79,11 +78,6 @@ def _flash_eligible(F, mask, valid_len, drop) -> bool:
     # record), since the flash path has no probs tensor to drop.
     from ...base import get_env
     mode = get_env("MXNET_ATTENTION_KERNEL").lower()
-    legacy = get_env("MXNET_USE_FLASH_ATTENTION")
-    if legacy == "1":
-        mode = "flash"              # legacy force-on
-    elif legacy == "0":
-        mode = "xla"                # legacy explicit force-off
     if mode in ("xla", "off", "0"):
         return False
     if mask is not None and valid_len is None:
@@ -907,7 +901,7 @@ class CausalLM(HybridBlock):
 
 
 def causal_lm_small(vocab_size=257, **kwargs):
-    """Tiny decoder-only LM for tests/benches — the generation-serving
+    """Tiny decoder-only LM for tests — the generation-serving
     counterpart of ``bert_small``."""
     kwargs.setdefault("max_length", 256)
     return CausalLM(vocab_size=vocab_size, num_layers=2, units=64,

@@ -47,10 +47,9 @@ def _jnp_dual(clip: float, dtype_name: str, momentum: float | None):
     """The kernel's jnp twin over the same packed (rows, 128) buffers.
 
     Off-TPU production path: interpret-mode Pallas executes the kernel
-    grid step-by-step in Python (~30 ms per trainer step measured on the
-    host bench — 10x the whole rest of the step), which is a TESTING
-    vehicle, not a CPU backend.  XLA:CPU compiles this dual to the same
-    math.  Kernel-semantics tests opt back into real interpret mode with
+    grid step-by-step in Python, which is a TESTING vehicle, not a CPU
+    backend.  XLA:CPU compiles this dual to the same math.
+    Kernel-semantics tests opt back into real interpret mode with
     MXNET_PALLAS_INTERPRET=1."""
     import jax
     import jax.numpy as jnp

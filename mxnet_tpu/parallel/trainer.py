@@ -81,18 +81,18 @@ class ShardedTrainer:
         ('dp',) — add 'sp' on the sequence dim for context parallelism,
         e.g. data_spec=('dp', 'sp').
     zero_stage : {0, 1, 2} — optimizer-state partitioning over the 'dp'
-        axis (default: the ``MXTPU_ZERO_STAGE`` knob).  0 = replicated
-        state (bitwise-identical to the pre-ZeRO step); 1 = state
-        sharded, gradients reduce-scattered for the update, updated
-        params all-gathered; 2 = the gradient (accumulation) buffer is
-        sharded too.  Per-parameter fallback: a tensor whose dim 0
-        cannot split over dp keeps replicated state (see
+        axis.  0 (the default) = replicated state (bitwise-identical
+        to the pre-ZeRO step); 1 = state sharded, gradients
+        reduce-scattered for the update, updated params all-gathered;
+        2 = the gradient (accumulation) buffer is sharded too.
+        Per-parameter fallback: a tensor whose dim 0 cannot split over
+        dp keeps replicated state (see
         :func:`~mxnet_tpu.parallel.mesh.zero_sharding`).
-    accum_steps : int — microbatched gradient accumulation (default: the
-        ``MXTPU_ACCUM_STEPS`` knob).  The step consumes the same global
-        batch but runs it as N sequential microbatches under a
-        ``lax.scan``; peak activation memory drops ~N-fold while the
-        update is rescale-correct against the full batch.
+    accum_steps : int — microbatched gradient accumulation (default 1:
+        none).  The step consumes the same global batch but runs it as
+        N sequential microbatches under a ``lax.scan``; peak activation
+        memory drops ~N-fold while the update is rescale-correct against
+        the full batch.
     comm_bucket_mb : float — bucketed gradient reduce-scatter (default:
         the ``MXTPU_COMM_BUCKET_MB`` knob).  0 (off) keeps ONE fused
         reduction after the full backward — bitwise-identical to the
@@ -114,8 +114,8 @@ class ShardedTrainer:
                  rules: Optional[ShardingRules] = None,
                  data_spec: Sequence = ("dp",),
                  label_spec: Optional[Sequence] = None,
-                 zero_stage: Optional[int] = None,
-                 accum_steps: Optional[int] = None,
+                 zero_stage: int = 0,
+                 accum_steps: int = 1,
                  comm_bucket_mb: Optional[float] = None,
                  remat: Sequence = (),
                  guard_nonfinite: bool = False,
@@ -132,14 +132,10 @@ class ShardedTrainer:
         self._data_spec = tuple(data_spec)
         self._label_spec = tuple(label_spec) if label_spec is not None \
             else (self._data_spec[0],)
-        if zero_stage is None:
-            zero_stage = int(get_env("MXTPU_ZERO_STAGE"))
         if zero_stage not in (0, 1, 2):
             raise MXNetError(
                 f"zero_stage must be 0, 1 or 2, got {zero_stage!r}")
         self._zero = int(zero_stage)
-        if accum_steps is None:
-            accum_steps = int(get_env("MXTPU_ACCUM_STEPS"))
         if int(accum_steps) < 1:
             raise MXNetError(
                 f"accum_steps must be >= 1, got {accum_steps!r}")
@@ -154,8 +150,8 @@ class ShardedTrainer:
         self._grad_buckets = None
         self._remat = tuple(remat)
         # forced checkpoint layout: None = auto (_host_local_checkpoint
-        # decides from the process group); tests/bench set True to
-        # exercise the self-contained npz writer in a single process
+        # decides from the process group); tests set True to exercise
+        # the self-contained npz writer in a single process
         self.host_local_ckpt: Optional[bool] = None
         self._hl_writer = None       # in-flight async npz commit thread
         self._hl_error = None
@@ -1258,8 +1254,8 @@ class ShardedTrainer:
         they carry no cross-host sharding worth preserving anyway.  A
         mesh that genuinely spans processes (TPU pod) keeps the sharded
         orbax path.  ``self.host_local_ckpt`` (a plain attribute)
-        overrides the auto-detection either way — how the bench and
-        the torn-dir tests exercise the npz writer in one process."""
+        overrides the auto-detection either way — how the torn-dir
+        tests exercise the npz writer in one process."""
         if self.host_local_ckpt is not None:
             return bool(self.host_local_ckpt)
         from . import dist

@@ -15,7 +15,7 @@ batching**:
   plus the batcher thread and the dispatch handoff queue, so batch
   formation overlaps device execution;
 - :mod:`.buckets` — shape-bucketed batch assembly: padding-length
-  buckets (the BERT bench's padding machinery) x power-of-two batch
+  buckets (BERT's valid-length padding) x power-of-two batch
   buckets, one compiled executable per signature, with
   real/padded-element accounting for the batch-efficiency metric;
 - :mod:`.server` — the :class:`ModelServer` lifecycle (start / graceful

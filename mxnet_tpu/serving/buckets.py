@@ -4,9 +4,9 @@ A compiled CachedOp executable is pinned to ONE input signature
 (shapes + dtypes), so a server that dispatched every request shape
 as-is would recompile constantly — the recompile storm
 ``HybridBlock.CACHED_GRAPH_LIMIT`` warns about.  The classic fix (the
-reference's BucketingModule economics, and this repo's NMT bench row)
-is *bucketing*: pad variable dimensions up to a small fixed menu of
-sizes so the whole workload funnels through a handful of executables.
+reference's BucketingModule economics) is *bucketing*: pad variable
+dimensions up to a small fixed menu of sizes so the whole workload
+funnels through a handful of executables.
 
 Two bucket axes compose here:
 
@@ -14,9 +14,8 @@ Two bucket axes compose here:
   batch of 3 dispatches as a padded batch of 4), so batch assembly
   never introduces new signatures;
 - **length buckets** — optional per-sample padding of ``pad_axis`` to
-  the smallest configured length that fits (the BERT bench's
-  valid-length padding idiom, PERF.md round 4): a 20-token request
-  joins the 32-token bucket.
+  the smallest configured length that fits (BERT's valid-length
+  padding idiom): a 20-token request joins the 32-token bucket.
 
 Padding is real work the chip does for nothing, so the assembler
 reports it — with the two pad axes kept SEPARATE, because they waste

@@ -7,7 +7,7 @@ analog).  This module wraps it in the serving loop the north star's
 
     submit() -> AdmissionQueue (bounded, 429 past depth)
              -> batcher thread: shape-bucketed batch assembly
-                (padding-length buckets, the BERT bench idiom)
+                (padding-length buckets, BERT's valid-length idiom)
              -> dispatch workers: ONE CachedGraph.raw call per bucket,
                 batch formation overlapping device execution
              -> per-request results, metrics, flight-recorder records
@@ -691,7 +691,8 @@ class GenerationServer:
     ``"interleave"`` admits at most one prefill per decode iteration
     (smooth decode cadence for running requests), ``"step"`` prefills
     every admissible queued request before the next decode step (fastest
-    burst drain).  Read live per iteration; the bench measures both.
+    burst drain).  Read live per iteration; nothing on a chip has
+    measured either against the other.
 
     The model contract is three compiled entries sharing one parameter
     set (see ``gluon.model_zoo.transformer.CausalLM``):

@@ -33,8 +33,8 @@ observability spine already measures:
 All controllers share ONE daemon timer thread
 (:class:`TuningRuntime`), ticking every ``MXTPU_TUNE_INTERVAL``
 seconds.  Controllers are tick-driven and wall-clock-free inside, so
-tests (and the bench convergence loop) call ``controller.tick()`` /
-``runtime().tick_all()`` directly against synthetic metric streams.
+tests call ``controller.tick()`` / ``runtime().tick_all()`` directly
+against synthetic metric streams.
 
 Quick start::
 
@@ -82,8 +82,7 @@ class TuningRuntime:
     A controller whose ``tick()`` raises is counted
     (``tuning.errors``), warned about once, and *kept* — one misbehaving
     loop must not silence the other three.  ``tick_all()`` is the
-    synchronous entry tests and the bench convergence loop drive
-    directly."""
+    synchronous entry tests drive directly."""
 
     def __init__(self):
         self._controllers: List[Controller] = []

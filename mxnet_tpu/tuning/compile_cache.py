@@ -3,11 +3,11 @@ process.
 
 PERF.md documents multi-minute XLA compiles inside 2-minute chip
 windows: every restart — a preemption auto-resume, a ModelServer cold
-start, a bench subprocess — re-pays the full compile for graphs the
-previous process already built.  The in-memory caches this repo already
-keys carefully (``_segment_cache`` in ndarray/register.py, the
-per-signature ``HybridBlock._cached_graph``) die with the process; this
-module gives those same keys a disk tier.
+start — re-pays the full compile for graphs the previous process
+already built.  The in-memory caches this repo already keys carefully
+(``_segment_cache`` in ndarray/register.py, the per-signature
+``HybridBlock._cached_graph``) die with the process; this module gives
+those same keys a disk tier.
 
 Design:
 
@@ -251,9 +251,9 @@ def active() -> Optional[CompileCache]:
 
 def configure(path: str) -> CompileCache:
     """Explicit enable for a program that wants a cache whatever the
-    environment says (``bench.py``, ``chip_smoke.py``): where
-    ``JAX_COMPILATION_CACHE_DIR`` is set that directory is used and
-    ``path`` is ignored; where it is not, ``path`` is exported as
+    environment says (``chip_smoke.py``, the benchmark's harness):
+    where ``JAX_COMPILATION_CACHE_DIR`` is set that directory is used
+    and ``path`` is ignored; where it is not, ``path`` is exported as
     ``MXTPU_COMPILE_CACHE_DIR`` (child processes inherit it)."""
     if not _jax_cache_dir():
         os.environ[CACHE_DIR_ENV] = os.path.abspath(path)

@@ -233,7 +233,7 @@ def test_flash_attention_op_and_transformer_path(monkeypatch):
     att.initialize()
     x = mx.nd.array(rs.randn(2, 20, 32).astype(np.float32))
     base = att(x).asnumpy()
-    monkeypatch.setenv("MXNET_USE_FLASH_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "flash")
     flash = att(x).asnumpy()
     np.testing.assert_allclose(flash, base, atol=3e-5)
 
@@ -317,7 +317,7 @@ def test_flash_attention_gradients_match_full_softmax():
 
 
 def test_flash_attention_trains_transformer():
-    """MXNET_USE_FLASH_ATTENTION=1 on a dropout-free attention block:
+    """MXNET_ATTENTION_KERNEL=flash on a dropout-free attention block:
     training itself rides the flash kernel and converges like the XLA
     path."""
     import os
@@ -336,9 +336,9 @@ def test_flash_attention_trains_transformer():
         att.initialize(mx.init.Xavier())
         tr = gluon.Trainer(att.collect_params(), "adam",
                            {"learning_rate": 1e-2})
-        # baseline must explicitly DISABLE the flag so a pre-exported
+        # baseline must explicitly force XLA so a pre-exported
         # env var can't make both runs take the flash path
-        env = {"MXNET_USE_FLASH_ATTENTION": "1" if flag else "0"}
+        env = {"MXNET_ATTENTION_KERNEL": "flash" if flag else "xla"}
         old = {k: os.environ.get(k) for k in env}
         os.environ.update(env)
         try:
@@ -657,7 +657,7 @@ def test_flash_attention_padding_mask_transformer_path(monkeypatch):
     mask[1, :10] = 1
     base_mask = enc(x, mx.nd.array(mask)).asnumpy()
     np.testing.assert_allclose(base, base_mask, atol=1e-5)
-    monkeypatch.setenv("MXNET_USE_FLASH_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "flash")
     flash = enc(x, lens).asnumpy()
     # padded positions' outputs are don't-cares downstream; compare valid
     np.testing.assert_allclose(flash[0], base[0], atol=5e-5)
@@ -678,7 +678,7 @@ def test_flash_env_non_prefix_mask_falls_back_exact(monkeypatch):
     x = mx.nd.array(rs.randn(1, 8, 32).astype(np.float32))
     holes = mx.nd.array(np.array([[1, 0, 1, 1, 1, 0, 1, 1]], np.float32))
     base = enc(x, holes).asnumpy()
-    monkeypatch.setenv("MXNET_USE_FLASH_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "flash")
     flashed = enc(x, holes).asnumpy()
     np.testing.assert_allclose(flashed, base, atol=1e-6)
 
@@ -686,9 +686,7 @@ def test_flash_env_non_prefix_mask_falls_back_exact(monkeypatch):
 def test_attention_kernel_policy(monkeypatch):
     """MXNET_ATTENTION_KERNEL policy: 'flash'/'xla' force the path;
     'auto' (the default) picks flash only on the TPU backend, so on this
-    CPU-backed suite auto must resolve to the XLA softmax path.  The
-    legacy MXNET_USE_FLASH_ATTENTION var keeps force-on ('1') and
-    force-off ('0') meanings."""
+    CPU-backed suite auto must resolve to the XLA softmax path."""
     import jax
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.transformer import MultiHeadAttention
@@ -698,7 +696,6 @@ def test_attention_kernel_policy(monkeypatch):
     F = mx.nd
 
     monkeypatch.delenv("MXNET_ATTENTION_KERNEL", raising=False)
-    monkeypatch.delenv("MXNET_USE_FLASH_ATTENTION", raising=False)
     on_tpu = jax.default_backend() == "tpu"
     assert att._flash_eligible(F, None, None) == on_tpu
 
@@ -708,12 +705,4 @@ def test_attention_kernel_policy(monkeypatch):
     assert not att._flash_eligible(F, object(), None)
 
     monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "xla")
-    assert not att._flash_eligible(F, None, None)
-
-    # legacy spellings override the new policy var
-    monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "xla")
-    monkeypatch.setenv("MXNET_USE_FLASH_ATTENTION", "1")
-    assert att._flash_eligible(F, None, None)
-    monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "flash")
-    monkeypatch.setenv("MXNET_USE_FLASH_ATTENTION", "0")
     assert not att._flash_eligible(F, None, None)

@@ -878,6 +878,35 @@ def test_readme_knob_table_in_sync():
         "README knob table is stale: regenerate with " \
         "`python -m mxnet_tpu.tools.mxlint --knobs-md`"
 
+
+def test_no_dead_suite_and_readme_names_only_tracked_files():
+    """`BENCHMARK.json` + `benchmarks/` is the one yardstick: no second
+    suite (`bench.py`) and no banked record (`*_rNN.json`) at the root,
+    and every `.py` path the README backticks is a file of the tree
+    (whole, by its tail under a package, or as a glob)."""
+    import fnmatch
+    import re
+    import subprocess
+    try:
+        tracked = subprocess.run(
+            ["git", "-C", REPO, "ls-files"], capture_output=True,
+            text=True, check=True).stdout.split()
+    except (OSError, subprocess.CalledProcessError):
+        tracked = []
+    if not tracked:     # a checkout without its .git: the files on disk
+        tracked = [os.path.relpath(os.path.join(d, f), REPO)
+                   for d, _, fs in os.walk(REPO) for f in fs]
+    root = [f for f in tracked if "/" not in f]
+    assert "bench.py" not in root
+    assert not [f for f in root if re.search(r"_r[0-9][0-9]\.json$", f)]
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as f:
+        named = set(re.findall(r"`([^`\s]*\.py)`", f.read()))
+    missing = sorted(
+        n for n in named
+        if not any(f == n or f.endswith("/" + n) or fnmatch.fnmatch(f, n)
+                   for f in tracked))
+    assert not missing, f"README names files not in the tree: {missing}"
+
 # -- PR-20: the flow-sensitive (CFG) tier ------------------------------------
 
 from mxnet_tpu.tools.mxlint import cfg as mxcfg  # noqa: E402

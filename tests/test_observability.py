@@ -806,14 +806,11 @@ def test_no_adhoc_timing_pairs_in_package():
 
 @pytest.mark.slow
 def test_instrumentation_overhead_under_guard():
-    """The acceptance bound, measured the way bench.py reports it: the
-    registry instrumentation on the bulked-dispatch path (one counter
-    bump per op + three bumps, one histogram observe and one
-    perf_counter pair per segment) must cost well under 3% of the
-    measured per-op dispatch time."""
-    import sys
-    sys.path.insert(0, REPO)
-    from bench import _metrics_overhead_pct
+    """The acceptance bound: the registry instrumentation on the
+    bulked-dispatch path (one counter bump per op + three bumps, one
+    histogram observe and one perf_counter pair per segment) must cost
+    well under 3% of the measured per-op dispatch time."""
+    from tests._overhead import _metrics_overhead_pct
     eng = engine()
     x = mx.nd.ones((4096,))
     y = x

@@ -313,8 +313,8 @@ def test_step_breakdown_names_loader_under_stall(traced, tmp_path):
     # set_fault_plan, not the env knob: active_plan() memoizes the env
     # parse once per process, so in a full-suite run a monkeypatched
     # env var would be ignored
-    # 1.2s: comfortably above any residual (post-priming) compile wall,
-    # so the stalled step owns the histogram's top exemplar bucket
+    # 1.2s: far above what the prefetched batches absorb, so the
+    # stalled step's consumer-visible wait names the loader
     faults.set_fault_plan("loader_stall@4:1.2")
     # the wall histogram is the process's: an earlier file on this worker
     # may have left a slower step's exemplar in its top bucket
@@ -341,9 +341,10 @@ def test_step_breakdown_names_loader_under_stall(traced, tmp_path):
     assert len(recs) == 6
     assert all(set(BREAKDOWN_STAGES) == set(r["breakdown"]) and
                r["trace_id"] for r in recs)
-    stalled = [r for r in recs if r["bottleneck"] == "loader"]
-    assert stalled, [r["bottleneck"] for r in recs]
-    sr = stalled[0]
+    # the stalled step is the one that waited longest for its batch
+    # (on a loaded host another step may name the loader too)
+    sr = max(recs, key=lambda r: r["breakdown"]["loader"])
+    assert sr["bottleneck"] == "loader", [r["bottleneck"] for r in recs]
     # prefetched batches absorb part of the stall; the consumer-visible
     # wait still dominates the step
     assert sr["breakdown"]["loader"] > 100_000
@@ -355,10 +356,17 @@ def test_step_breakdown_names_loader_under_stall(traced, tmp_path):
     names = {s["name"] for s in traced.find(sr["trace_id"])}
     assert {"resilience.step", "resilience.step_us",
             "loader.wait"} <= names
-    # p99 exemplar of the wall histogram resolves to the stalled trace
+    # the wall histogram's exemplars resolve to the stalled trace: the
+    # bucket that took the stalled step's wall (loader wait included)
+    # names its trace.  On an idle host that is the top bucket; beside
+    # five busy workers another step may out-wait the stall, so the
+    # bucket is found by what it must hold, not by its rank
     ex = registry().get("resilience.step_wall_us").exemplars()
-    tid = ex[max(ex)][-1][0]
-    assert tid == sr["trace_id"]
+    hits = [(bound, wall) for bound, lst in ex.items()
+            for tid, wall, _ in lst if tid == sr["trace_id"]]
+    assert len(hits) == 1, (sr["trace_id"], ex)
+    bound, wall = hits[0]
+    assert sr["breakdown"]["loader"] <= wall <= bound
     # crash dump: step records + span ring side by side
     path = flight.dump("test")
     payload = json.load(open(path))
@@ -413,8 +421,7 @@ def test_tracing_overhead_under_guard(monkeypatch):
     sampling off the instrumented-call-site probe must be noise next to
     one dispatched segment, and a fully sampled span must stay tens of
     microseconds."""
-    sys.path.insert(0, REPO)
-    from bench import _tracing_costs
+    from tests._overhead import _tracing_costs
     off_us, on_us = _tracing_costs()
     # a per-dispatch-batch probe against the measured per-op cost:
     # one probe per ~15-op segment must stay under the 3% budget

@@ -181,11 +181,11 @@ def test_translate_scores_and_edge_cases():
     att.initialize()
     x = nd.array(np.random.RandomState(1).randn(1, 6, 16)
                  .astype(np.float32))
-    os.environ["MXNET_USE_FLASH_ATTENTION"] = "1"
+    os.environ["MXNET_ATTENTION_KERNEL"] = "flash"
     try:
         with autograd.train_mode():
             a = att(x).asnumpy()
             b = att(x).asnumpy()
     finally:
-        del os.environ["MXNET_USE_FLASH_ATTENTION"]
+        del os.environ["MXNET_ATTENTION_KERNEL"]
     assert not np.allclose(a, b)
