@@ -267,9 +267,9 @@ def flash_kernel_check(shape, seed):
                       f"flash bf16 {name} {shape}: max |err| {err.max()} "
                       f"beyond {FLASH_BF16_ATOL}+{FLASH_BF16_RTOL}*|ref|")
             errs[f"{jnp.dtype(dtype).name}_{name}"] = float(f"{err.max():.2e}")
-    # the backward (a scanned jnp formulation behind the kernel's custom
-    # VJP, at the chip's default matmul precision) against the reference's
-    # gradients, relative to the largest of them
+    # the backward (two Pallas kernels of its own behind the kernel's
+    # custom VJP, at the chip's default matmul precision) against the
+    # reference's gradients, relative to the largest of them
     cot = jax.device_put(rng.standard_normal(shape, dtype=np.float32),
                          CTX.device)
     grads = [jax.jit(jax.grad(
@@ -281,12 +281,18 @@ def flash_kernel_check(shape, seed):
     check(gerr <= FLASH_GRAD_RTOL,
           f"flash gradients {shape} off by {gerr} of the largest reference "
           "gradient")
+    mosaic("flash attention backward", q, jax.jit(jax.grad(
+        lambda a, b, c: jnp.sum(flash_attention(a, b, c, valid_len=vl) * cot),
+        argnums=(0, 1, 2))).lower(q, k, v).compile().as_text())
     say("kernel/flash_attention", lowering="tpu_custom_call",
         shape=shape, max_abs_err=errs,
         tol=f"fp32 3e-5; bf16 {FLASH_BF16_ATOL}+{FLASH_BF16_RTOL}*|ref|",
         grad_max_rel_err=float(f"{gerr:.2e}"), grad_tol=FLASH_GRAD_RTOL,
         tiling={n: int(registry().get(f"kernels.flash_attention.{n}").read())
-                for n in ("block_q", "block_k", "kv_resident", "grid_steps")})
+                for n in ("block_q", "block_k", "kv_resident", "grid_steps")},
+        backward_tiling={
+            n: int(registry().get(f"kernels.flash_attention_bwd.{n}").read())
+            for n in ("block_q", "block_k", "grid_steps")})
 
 
 def make_bert():

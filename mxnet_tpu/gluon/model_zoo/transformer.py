@@ -72,8 +72,8 @@ def _flash_eligible(F, mask, valid_len, drop) -> bool:
     # back to the XLA path — a 2-D mask cannot be proven to be a
     # prefix mask under trace, and collapsing a non-prefix mask to a
     # length silently corrupts attention (caught in round-4 review).
-    # The kernel is differentiable (custom VJP over the chunked
-    # formulation), so training may ride it too — EXCEPT when this
+    # The kernel is differentiable (its custom VJP runs the backward's
+    # own Pallas kernels), so training may ride it too — EXCEPT when this
     # block has attention dropout and dropout is live (train_mode/
     # record), since the flash path has no probs tensor to drop.
     from ...base import get_env
@@ -165,8 +165,8 @@ class MultiHeadAttention(HybridBlock):
         v = self._split_heads(F, v, b, sk)
         scale = 1.0 / math.sqrt(self._units // self._heads)
         if self._flash_eligible(F, mask, valid_len):
-            # tiled online-softmax Pallas kernel with a chunked-scan
-            # custom VJP — differentiable, no (Lq, Lk) score matrix in
+            # tiled online-softmax Pallas kernel whose custom VJP is two
+            # Pallas kernels — differentiable, no (Lq, Lk) score matrix in
             # either direction (kernels/flash_attention.py)
             if valid_len is None:
                 out = F.flash_attention(q, k, v, scale=scale)

@@ -26,11 +26,16 @@ key, and a K-major block wholly beyond the length is neither computed
 nor fetched (its index map is clamped to the last block that holds one).
 Under a causal mask with Lk >= Lq the keys beyond a query tile's last row
 bound the sweep in the same way: tiles and blocks wholly above the diagonal
-are neither visited nor fetched.  The backward is jnp: the vjp of a
-chunked scan where its stacked carries fit ``_BWD_CARRY_BUDGET``, else
-``_blocked_backward``, which stacks nothing.
+are neither visited nor fetched.
 The head keeps its own width (a head of 64 is a block 64 lanes wide);
 only ragged Lq/Lk are padded, to the tile.
+
+The backward is two Pallas kernels (``_build_backward``; tiles from
+``_bwd_tiling``, by the same rules and the same budget): ``dq``, which
+also makes the rows' statistics from the scores it computes itself, and
+``dkv``.  A block of scores, probabilities and ds lives and dies in VMEM;
+the tiles the forward skips are skipped; nothing of the forward but q, k
+and v is a residual.
 
 Numerics: f32 accumulation regardless of input dtype, f32 operands
 multiplied at full precision, bf16 operands as they are (the
@@ -45,16 +50,6 @@ from __future__ import annotations
 import functools
 
 _NEG_INF = -1e30
-# the chunk of the scanned backward: a constant of its own, so the
-# forward's tiles never change the backward's HLO
-_BWD_CHUNK = 128
-# what the scanned backward may stack as carries (one running max,
-# denominator and accumulator per chunk: Lk / 128 x BH x Lq x (D + 2)
-# float32).  A shape over it takes the blocked backward below, which
-# stacks nothing: 64 chunks of (20, 8192, 256) would be 10.8 GB.
-_BWD_CARRY_BUDGET = 1 << 30
-# the blocked backward's query and key block
-_BWD_BLOCK = 512
 # the forward's tiles (read on a v5e at (192, 512, 64) float32 with the
 # benchmark's lengths, PERF.md section 6, PR 29): a query tile of 512 rows
 # and a key tile of 256 were the fastest pair; keys that fit one lane
@@ -95,19 +90,25 @@ def _tiling(lq: int, lk: int, d: int, itemsize: int):
     block_q = min(_round_up(lq, sublanes), _MAX_BLOCK_Q)
     lqp = _round_up(lq, block_q)
     block_k = min(_round_up(lk, 128), _MAX_BLOCK_K)
-    lkp = _round_up(lk, block_k)
-    # K and V, two buffers each; VMEM rows are whole lanes, so a 64-wide
-    # block takes the room of 128
-    per_key = 2 * 2 * _round_up(dp, 128) * itemsize
-    if lkp * per_key <= _KV_VMEM_BUDGET:
-        kv_block = lkp
-    else:
-        # as many keys as the budget holds, in whole key tiles (a head of
-        # 256 float32 lanes holds 1024 keys where one of 128 holds 2048)
-        kv_block = min(_KV_MAJOR, max(
-            block_k, _KV_VMEM_BUDGET // per_key // block_k * block_k))
-        lkp = _round_up(lk, kv_block)
+    kv_block, lkp = _major(lk, block_k, dp, itemsize)
     return block_q, lqp, block_k, kv_block, lkp, dp
+
+
+def _major(rows: int, tile: int, dp: int, itemsize: int):
+    """(block, padded rows) of an operand pair that a kernel sweeps (K and
+    V; in the backward's ``dkv`` Q and the cotangent): resident whole when
+    the pair fits ``_KV_VMEM_BUDGET``, else in blocks of as many rows as
+    the budget holds, in whole tiles (a head of 256 float32 lanes holds
+    1024 where one of 128 holds 2048)."""
+    # two arrays, two buffers each; VMEM rows are whole lanes, so a 64-wide
+    # block takes the room of 128
+    per_row = 2 * 2 * _round_up(dp, 128) * itemsize
+    padded = _round_up(rows, tile)
+    if padded * per_row <= _KV_VMEM_BUDGET:
+        return padded, padded
+    block = min(_KV_MAJOR,
+                max(tile, _KV_VMEM_BUDGET // per_row // tile * tile))
+    return block, _round_up(rows, block)
 
 
 @functools.lru_cache(maxsize=None)
@@ -275,162 +276,354 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
     )
 
 
-def _chunked_reference(q, k, v, vl, causal: bool, scale: float):
-    """Pure-jnp online-softmax attention, chunked over KV blocks with
-    lax.scan — numerically identical to the kernel (same masks, same
-    dead-row semantics) and DIFFERENTIABLE.  The custom VJP below runs
-    the Pallas kernel forward and differentiates THIS formulation
-    backward, so training never materializes the (Lq, Lk) score matrix
-    either (per-step residuals are O(Lq·D·Lk/_BWD_CHUNK))."""
+def _bwd_tiling(lq: int, lk: int, d: int, itemsize: int):
+    """(block_q, q_block, padded Lq, block_k, key_block, kv_block, padded
+    Lk, padded D) of the backward's two kernels, from the shape alone.  A
+    score block is (block_k, block_q) in both.  ``dq`` holds one query tile
+    of ``block_q`` rows and sweeps K and V, resident whole (kv_block =
+    padded Lk) when they fit ``_KV_VMEM_BUDGET``, else in K-major blocks of
+    ``kv_block`` keys; ``dkv`` holds ``key_block`` keys (as many key tiles
+    as a query tile has rows) and sweeps Q and the cotangent the same way,
+    in blocks of ``q_block`` rows.  Queries ride the lanes of a score
+    block, so a query tile is whole lanes.  The forward's pair of tiles
+    (512, 256) read fastest here too (PERF.md section 6, PR 33)."""
+    dp = d if d % 64 == 0 else _round_up(d, 128)
+    block_q = min(_round_up(lq, 128), _MAX_BLOCK_Q)
+    block_k = min(_round_up(lk, 128), _MAX_BLOCK_K)
+    key_block = min(_round_up(lk, block_k), max(_MAX_BLOCK_Q, block_k))
+    q_block, lqp = _major(lq, block_q, dp, itemsize)
+    kv_block, lkp = _major(lk, key_block, dp, itemsize)
+    return block_q, q_block, lqp, block_k, key_block, kv_block, lkp, dp
+
+
+@functools.lru_cache(maxsize=None)
+def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
+                    scale: float, dtype_name: str, interpret: bool):
+    """The backward's two kernels for one call's (unpadded) shape; they
+    take the operands padded as ``_bwd_tiling`` says.  Both compute their
+    (block_k, block_q) score blocks with the keys down the sublanes and
+    the queries along the lanes: a row's statistics are then lane-dense
+    (1, block_q) rows that broadcast and reduce down the sublanes (the
+    other way round they are columns, and every use of one is a pass
+    through the cross-lane unit: 1.58 against 0.99 ms a call at the BERT
+    cell's shape, PERF.md section 6, PR 33), and ``p^T g``, ``ds^T q`` are
+    plain products.  A block of scores, probabilities and ds lives and
+    dies in VMEM.
+
+    ``dq`` (grid: batch·heads, query tiles, K-major blocks) sweeps the keys
+    of a query tile once and makes, online and from the scores it computes
+    itself, the row's maximum ``m``, denominator ``l``,
+    ``delta = sum_j p_ij dp_ij / l`` and, because dq is linear in delta,
+    dq itself: with ``a = sum_j p dp k`` and ``b = sum_j p k`` of the
+    unnormalised p (rescaled like ``l`` as the maximum moves),
+    ``dq = scale (a - delta b) / l``.  A row of
+    ``ds = p (dp - delta)`` so sums to nought by construction, whatever
+    rounding the scores took (a ``delta`` taken from the forward's
+    full-precision output does not: the key bias drifts, PERF.md section
+    6, PR 30), and the backward needs no statistic and no output of the
+    forward.  It writes ``m + log l``, delta and ``1 / l``, a row each of
+    an (8, block_q) block a query tile, for ``dkv`` (grid: batch·heads, key
+    blocks, Q-major blocks), which makes the same scores again bit for
+    bit, ``p = exp(s - m - log l)``, ``ds``, and adds ``p^T g`` to dv and
+    ``ds^T q`` to dk.
+
+    Key tiles at or beyond a row's valid length, and under a causal mask
+    with Lk >= Lq the tiles wholly above the diagonal, are neither visited
+    nor fetched, as in the forward; a key tile wholly beyond the length
+    gets zeros.  Operands reach the MXU as XLA's default precision gives
+    them to it (float32 rounded to bfloat16 once, bfloat16 as it is);
+    products, statistics and accumulators are float32."""
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..observability.registry import registry
 
-    bh, lq, d = q.shape
-    lk = k.shape[1]
-    pad = (-lk) % _BWD_CHUNK
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-    nk = k.shape[1] // _BWD_CHUNK
-    qf = q.astype(jnp.float32) * scale
-    kb = k.astype(jnp.float32).reshape(bh, nk, _BWD_CHUNK, d)
-    vb = v.astype(jnp.float32).reshape(bh, nk, _BWD_CHUNK, d)
-    q_idx = jnp.arange(lq)
-    vl_eff = jnp.minimum(vl.astype(jnp.float32), jnp.float32(lk))  # (bh,)
-
-    # remat: without checkpointing, vjp-of-scan stacks each step's p
-    # (bh, Lq, _BWD_CHUNK) — a full probability matrix across steps; with
-    # it, backward recomputes per-block and stores only the carries
-    # (O(Lq·(D+2)·nk))
-    @jax.checkpoint
-    def step(carry, blk):
-        m, l, acc = carry
-        k_blk, v_blk, ki = blk
-        s = jnp.einsum("bqd,bkd->bqk", qf, k_blk)
-        k_ids = ki * _BWD_CHUNK + jnp.arange(_BWD_CHUNK)
-        kmask = (k_ids[None, :].astype(jnp.float32)
-                 < vl_eff[:, None])[:, None, :]        # (bh, 1, BK)
-        mask = kmask
-        if causal:
-            mask = mask & (k_ids[None, None, :] <=
-                           q_idx[None, :, None] + (lk - lq))
-        s = jnp.where(mask, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        dead = m_new <= (_NEG_INF * 0.5)
-        p = jnp.where(dead[..., None],
-                      jnp.broadcast_to(kmask.astype(jnp.float32),
-                                       p.shape), p)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bqk,bkd->bqd", p, v_blk)
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((bh, lq), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bh, lq), jnp.float32)
-    a0 = jnp.zeros((bh, lq, d), jnp.float32)
-    blks = (jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0),
-            jnp.arange(nk))
-    (m, l, acc), _ = lax.scan(step, (m0, l0, a0), blks)
-    l = jnp.where(l == 0.0, 1.0, l)
-    return (acc / l[..., None]).astype(q.dtype)
-
-
-def _blocked_backward(q, k, v, vl, causal: bool, scale: float, out, g):
-    """(dq, dk, dv) of the attention whose output is ``out``, for the
-    cotangent ``g``, with nothing stacked: one scan over the (query block,
-    key block) pairs that hold a weight finds each row's max and
-    denominator, a second forms ``p = exp(s - m) / l`` again pair by pair
-    and adds ``dv += p^T g``, ``ds = p (g v^T - rowsum(g out))``,
-    ``dq += ds k``, ``dk += ds^T q`` into the gradients it carries.  Every
-    array is kept block-major, (blocks, BH, block, D), so that a pair
-    reads and writes whole leading slabs.  Under a causal mask with
-    Lk >= Lq the pairs above the diagonal are not in the list.  Same masks
-    and dead-row rule as the kernel."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    bh, lq, d = q.shape
-    lk = k.shape[1]
-    bq = min(_BWD_BLOCK, _round_up(lq, 8))
-    bk = min(_BWD_BLOCK, _round_up(lk, 128))
-    nq, nk = -(-lq // bq), -(-lk // bk)
+    dtype = jnp.dtype(dtype_name)
+    block_q, q_block, lqp, block_k, key_block, kv_block, lkp, dp = \
+        _bwd_tiling(lq, lk, d, dtype.itemsize)
+    nq, nkv = lqp // block_q, lkp // kv_block
+    nkb, nqb = lkp // key_block, lqp // q_block
+    mxu = jnp.bfloat16 if dtype == jnp.float32 else dtype
     off = lk - lq
+    diagonal = causal and off >= 0          # as the forward's
 
-    def blocks(x, n, b):
-        x = x.astype(jnp.float32)
-        x = jnp.pad(x, ((0, 0), (0, n * b - x.shape[1]), (0, 0)))
-        return jnp.moveaxis(x.reshape(bh, n, b, d), 1, 0)
+    reg = registry()
+    reg.counter("kernels.flash_attention_bwd.builds",
+                "flash backward kernel pairs built (one per shape)").inc()
+    for name, value in (("block_q", block_q), ("block_k", block_k),
+                        ("grid_steps", bh * (nq * nkv + nkb * nqb))):
+        reg.gauge(f"kernels.flash_attention_bwd.{name}",
+                  "tiling of the last flash backward kernels built"
+                  ).set(value)
 
-    def rows(x, n):
-        return jnp.moveaxis(x, 0, 1).reshape(bh, -1, d)[:, :n]
-    qf, gf, of = blocks(q, nq, bq) * scale, blocks(g, nq, bq), \
-        blocks(out, nq, bq)
-    kf, vf = blocks(k, nk, bk), blocks(v, nk, bk)
-    pairs = [(i, j) for i in range(nq) for j in range(nk)
-             if not (causal and off >= 0) or j * bk <= (i + 1) * bq - 1 + off]
-    pairs = (jnp.asarray([p[0] for p in pairs], jnp.int32),
-             jnp.asarray([p[1] for p in pairs], jnp.int32))
-    vl_eff = jnp.minimum(vl.astype(jnp.float32), jnp.float32(lk))
+    def nt(a, b):
+        return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    def at(x, i):
-        return lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
+    def nn(a, b):
+        return lax.dot_general(a.astype(mxu), b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    def put(x, i, blk):
-        return lax.dynamic_update_index_in_dim(x, blk, i, 0)
+    def tn(a, b):
+        return lax.dot_general(a, b.astype(mxu), (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    def scores(i, j):
-        s = jnp.einsum("bqd,bkd->bqk", at(qf, i), at(kf, j))
-        k_ids = j * bk + jnp.arange(bk)
-        kmask = (k_ids[None, :].astype(jnp.float32)
-                 < vl_eff[:, None])[:, None, :]
-        mask = kmask
-        if causal:
-            q_ids = i * bq + jnp.arange(bq)
-            mask = mask & (k_ids[None, None, :] <=
-                           q_ids[None, :, None] + off)
-        return jnp.where(mask, s, _NEG_INF), kmask.astype(jnp.float32)
+    def scaled(x):
+        # the scale rides q into the MXU, as the einsums of a jnp backward
+        # give it: both kernels then make the same scores bit for bit
+        return (x.astype(jnp.float32) * scale).astype(mxu)
 
-    def stats(carry, ij):
-        m, l = carry
-        i, j = ij
-        s, kmask = scores(i, j)
-        mb = at(m, i)
-        m_new = jnp.maximum(mb, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where((m_new <= _NEG_INF * 0.5)[..., None], kmask, p)
-        l_new = at(l, i) * jnp.exp(mb - m_new) + jnp.sum(p, axis=-1)
-        return (put(m, i, m_new), put(l, i, l_new)), None
+    # a row that sees no key among the tiles visited: only a causal mask
+    # with Lk < Lq makes one (with Lk >= Lq every row sees key 0, and a
+    # row of length 0 visits nothing).  It weighs its valid keys evenly
+    # and passes nothing to q and k, as in the forward.
+    dead_rows = causal and not diagonal
 
-    (m, l), _ = lax.scan(
-        stats, (jnp.full((nq, bh, bq), _NEG_INF, jnp.float32),
-                jnp.zeros((nq, bh, bq), jnp.float32)), pairs)
-    l = jnp.where(l == 0.0, 1.0, l)
-    delta = jnp.sum(gf * of, axis=-1)
+    def positions():
+        """Of a score block (keys down the sublanes, queries along the
+        lanes, in both kernels: a row's statistics are then lane-dense
+        rows that broadcast and reduce down the sublanes): each entry's
+        key, and key less query, both counted from the block's corner."""
+        key = lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+        return key, (key - lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1) if causal else None)
 
-    def grads(carry, ij):
-        dq, dk, dv = carry
-        i, j = ij
-        s, kmask = scores(i, j)
-        mb, gb = at(m, i)[..., None], at(gf, i)
-        dead = mb <= _NEG_INF * 0.5
-        p = jnp.where(dead, kmask, jnp.exp(s - mb)) / at(l, i)[..., None]
-        dp = jnp.einsum("bqd,bkd->bqk", gb, at(vf, j))
-        ds = jnp.where(dead, 0.0, p * (dp - at(delta, i)[..., None]))
-        dq = put(dq, i, at(dq, i)
-                 + jnp.einsum("bqk,bkd->bqd", ds, at(kf, j)) * scale)
-        dk = put(dk, j, at(dk, j)
-                 + jnp.einsum("bqk,bqd->bkd", ds, at(qf, i)))
-        dv = put(dv, j, at(dv, j) + jnp.einsum("bqk,bqd->bkd", p, gb))
-        return (dq, dk, dv), None
+    def mask(s, key, rel, length, q0, k0):
+        """Scores masked as the forward masks them, and the valid keys."""
+        kmask = key < length - k0
+        keep = kmask & (rel <= q0 + off - k0) if causal else kmask
+        return jnp.where(keep, s, _NEG_INF), kmask
 
-    (dq, dk, dv), _ = lax.scan(
-        grads, (jnp.zeros_like(qf), jnp.zeros_like(kf), jnp.zeros_like(vf)),
-        pairs)
-    return rows(dq, lq).astype(q.dtype), rows(dk, lk).astype(k.dtype), \
-        rows(dv, lk).astype(v.dtype)
+    def weights(s, kmask, lse, linv, dp_, delta):
+        """(p, ds) of a block from its rows' statistics."""
+        p = jnp.exp(s - lse)
+        if dead_rows:
+            dead = lse <= _NEG_INF * 0.5
+            p = jnp.where(dead, kmask.astype(jnp.float32) * linv, p)
+        ds = p * (dp_ - delta)
+        if dead_rows:
+            ds = jnp.where(dead, 0.0, ds)
+        return p, ds
+
+    def key_limit(length, qi):
+        if not diagonal:
+            return length
+        return jnp.minimum(length, (qi + 1) * block_q + off)
+
+    # -- dq, and the statistics -------------------------------------------
+    def dq_kernel(vl_ref, q_ref, g_ref, k_ref, v_ref, dq_ref, st_ref,
+                  m_ref, l_ref, n_ref, c_ref, a_ref, b_ref):
+        b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        k0 = kj * kv_block
+        length = jnp.minimum(vl_ref[b], lk)
+        # of this K-major block: the tiles that hold a key the query tile
+        # can weigh
+        tiles = jnp.clip(pl.cdiv(key_limit(length, qi) - k0, block_k), 0,
+                         kv_block // block_k)
+        q, g = scaled(q_ref[0]), g_ref[0].astype(mxu)
+        key, rel = positions()
+
+        @pl.when(kj == 0)
+        def _():
+            m_ref[...] = jnp.full((1, block_q), _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((1, block_q), jnp.float32)
+            n_ref[...] = jnp.zeros((1, block_q), jnp.float32)
+            a_ref[...] = jnp.zeros((dp, block_q), jnp.float32)
+            b_ref[...] = jnp.zeros((dp, block_q), jnp.float32)
+            # dp is counted from the row's dp at key 0, the one key every
+            # live row sees: dq below is a difference of two sums, and
+            # where a row's weight sits on few keys (its only one; a
+            # first key that draws most of it) both are then small
+            c_ref[...] = nt(v_ref[0, :8, :].astype(mxu), g)[:1]
+
+        c = c_ref[...]
+
+        def tile(t, carry):
+            m, l, n, a, b_ = carry
+            start = pl.multiple_of(t * block_k, block_k)
+            k = k_ref[0, pl.ds(start, block_k), :].astype(mxu)
+            v = v_ref[0, pl.ds(start, block_k), :].astype(mxu)
+            s, kmask = mask(nt(k, q), key, rel, length, qi * block_q,
+                            k0 + start)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if dead_rows:
+                p = jnp.where(m_new <= _NEG_INF * 0.5,
+                              kmask.astype(jnp.float32), p)
+            corr = jnp.exp(m - m_new)
+            pdp = p * (nt(v, g) - c)
+            return (m_new, l * corr + jnp.sum(p, axis=0, keepdims=True),
+                    n * corr + jnp.sum(pdp, axis=0, keepdims=True),
+                    a * corr + tn(k, pdp), b_ * corr + tn(k, p))
+
+        refs = (m_ref, l_ref, n_ref, a_ref, b_ref)
+        for r, x in zip(refs, lax.fori_loop(
+                0, tiles, tile, tuple(r[...] for r in refs))):
+            r[...] = x
+
+        @pl.when(kj == nkv - 1)
+        def _():
+            # dq is linear in delta: with a = sum_j p dp k and b = sum_j p k
+            # of the unnormalised p, dq = scale (a - delta b) / l, whatever
+            # dp is counted from.  The statistics ``dkv`` needs, a row
+            # each: m + log l (so p = exp(s - it)), delta, and for a dead
+            # row 1 / l, its valid keys' even weight; a row with no valid
+            # key divides by 1
+            m, l = m_ref[...], l_ref[...]
+            l = jnp.where(l == 0.0, 1.0, l)
+            delta = n_ref[...] / l
+            dq = (a_ref[...] - delta * b_ref[...]) * (scale / l)
+            if dead_rows:
+                dq = jnp.where(m <= _NEG_INF * 0.5, 0.0, dq)
+            dq_ref[0] = dq.T.astype(dtype)
+            lse = jnp.where(m <= _NEG_INF * 0.5, _NEG_INF, m + jnp.log(l))
+            row = lax.broadcasted_iota(jnp.int32, (8, block_q), 0)
+            st_ref[0, 0] = jnp.where(
+                row == 0, lse, jnp.where(row == 1, delta + c, 1.0 / l))
+
+    def kv_index(b, i, j, vl_ref):
+        # the sweep stops at the last K-major block that holds a key the
+        # tile can weigh; a block beyond it maps to that one: no DMA
+        last = jnp.maximum(
+            pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
+            0)
+        return (b, jnp.minimum(j, last), 0)
+
+    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j, vl: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, kv_block, dp), kv_index)
+    stats = jax.ShapeDtypeStruct((bh, nq, 8, block_q), jnp.float32)
+    dq_call = pl.pallas_call(
+        dq_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nq, nkv),
+            in_specs=[q_spec, q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, pl.BlockSpec(
+                (1, 1, 8, block_q), lambda b, i, j, vl: (b, i, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((1, block_q), jnp.float32)] * 4
+            + [pltpu.VMEM((dp, block_q), jnp.float32)] * 2),
+        out_shape=[jax.ShapeDtypeStruct((bh, lqp, dp), dtype), stats],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_attention_bwd_dq",
+    )
+
+    # -- dk and dv --------------------------------------------------------
+    q_tiles = q_block // block_q
+
+    def dkv_kernel(vl_ref, k_ref, v_ref, q_ref, g_ref, st_ref, dk_ref,
+                   dv_ref, dk_acc, dv_acc):
+        b, kb, qb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        length = jnp.minimum(vl_ref[b], lk)
+        k0, q0 = kb * key_block, qb * q_block
+        key, rel = positions()
+
+        @pl.when(qb == 0)
+        def _():
+            dk_acc[...] = jnp.zeros((key_block, dp), jnp.float32)
+            dv_acc[...] = jnp.zeros((key_block, dp), jnp.float32)
+
+        def key_tile(t, _):
+            start = pl.multiple_of(t * block_k, block_k)
+            rows = pl.ds(start, block_k)
+            k = k_ref[0, rows, :].astype(mxu)
+            v = v_ref[0, rows, :].astype(mxu)
+            kt = k0 + start
+
+            def query_tile(u, carry):
+                dk, dv = carry
+                at = pl.ds(pl.multiple_of(u * block_q, block_q), block_q)
+                q, g = scaled(q_ref[0, at, :]), g_ref[0, at, :].astype(mxu)
+                st = st_ref[0, u]
+                s, kmask = mask(nt(k, q), key, rel, length,
+                                q0 + u * block_q, kt)
+                p, ds = weights(s, kmask, st[0:1], st[2:3], nt(v, g),
+                                st[1:2])
+                return dk + nn(ds, q), dv + nn(p, g)
+
+            # under the diagonal, the first query tile of this Q-major
+            # block whose last row sees the tile's first key
+            first = (jnp.clip(kt - off - q0, 0, q_block) // block_q
+                     if diagonal else 0)
+            dk, dv = lax.fori_loop(
+                first, q_tiles, query_tile,
+                (jnp.zeros((block_k, dp), jnp.float32),) * 2)
+            dk_acc[rows, :] += dk
+            dv_acc[rows, :] += dv
+            return 0
+
+        # key tiles that hold a valid key; the others keep their zeros
+        lax.fori_loop(
+            0, jnp.clip(pl.cdiv(length - k0, block_k), 0,
+                        key_block // block_k),
+            key_tile, 0)
+
+        @pl.when(qb == nqb - 1)
+        def _():
+            dk_ref[0] = dk_acc[...].astype(dtype)
+            dv_ref[0] = dv_acc[...].astype(dtype)
+
+    def q_index(b, kb, qb, vl_ref):
+        # Q-major blocks wholly above the diagonal map to the first that
+        # is not, and every block of a key block beyond the length to the
+        # last: the pipeline sees the block it holds and issues no DMA
+        first = (jnp.clip(kb * key_block - off, 0, lqp - 1) // q_block
+                 if diagonal else 0)
+        beyond = kb * key_block >= jnp.minimum(vl_ref[b], lk)
+        return jnp.where(beyond, nqb - 1, jnp.maximum(qb, first))
+
+    kb_spec = pl.BlockSpec((1, key_block, dp),
+                           lambda b, kb, qb, vl: (b, kb, 0))
+    qb_spec = pl.BlockSpec(
+        (1, q_block, dp),
+        lambda b, kb, qb, vl: (b, q_index(b, kb, qb, vl), 0))
+    dkv_call = pl.pallas_call(
+        dkv_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nkb, nqb),
+            in_specs=[kb_spec, kb_spec, qb_spec, qb_spec, pl.BlockSpec(
+                (1, q_tiles, 8, block_q),
+                lambda b, kb, qb, vl: (b, q_index(b, kb, qb, vl), 0, 0))],
+            out_specs=[kb_spec, kb_spec],
+            scratch_shapes=[pltpu.VMEM((key_block, dp), jnp.float32)] * 2),
+        out_shape=[jax.ShapeDtypeStruct((bh, lkp, dp), dtype)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_attention_bwd_dkv",
+    )
+    return dq_call, dkv_call
+
+
+def _run_backward(q, k, v, vl, g, causal: bool, scale: float,
+                  interpret: bool):
+    """(dq, dk, dv) of the attention the forward kernel computes, for the
+    cotangent ``g``, by the two kernels of ``_build_backward``."""
+    import jax
+    import jax.numpy as jnp
+
+    bh, lq, d = q.shape
+    lk = k.shape[1]
+    _, _, lqp, _, _, _, lkp, dp = _bwd_tiling(
+        lq, lk, d, jnp.result_type(q).itemsize)
+
+    with jax.named_scope("flash_attention_pad"):
+        qp, gp, kp, vp = (_pad_to(x, rows, dp) for x, rows in (
+            (q, lqp), (g.astype(q.dtype), lqp), (k, lkp), (v, lkp)))
+    dq_call, dkv_call = _build_backward(
+        bh, lq, lk, d, bool(causal), float(scale), jnp.result_type(q).name,
+        bool(interpret))
+    lens = vl.astype(jnp.int32)
+    dq, stats = dq_call(lens, qp, gp, kp, vp)
+    dk, dv = dkv_call(lens, kp, vp, qp, gp, stats)
+    if dq.shape == q.shape and dk.shape == k.shape:
+        return dq, dk, dv
+    with jax.named_scope("flash_attention_pad"):
+        return dq[:, :lq, :d], dk[:, :lk, :d], dv[:, :lk, :d]
 
 
 @functools.lru_cache(maxsize=1)
@@ -444,55 +637,39 @@ def _flash_core_fn():
     def core(q, k, v, vl, causal, scale, interpret):
         return _run_kernel(q, k, v, vl, causal, scale, interpret)
 
-    def stacks_too_much(q, k):
-        bh, lq, d = q.shape
-        return -(-k.shape[1] // _BWD_CHUNK) * bh * lq * (d + 2) * 4 \
-            > _BWD_CARRY_BUDGET
-
     def core_fwd(q, k, v, vl, causal, scale, interpret):
-        # named here, before it is both the primal and a residual: a name
-        # put on by the caller would sit on another variable than the one
-        # the backward reads
+        # named here, before it is the primal: a name put on by the caller
+        # would sit on another variable than the one a checkpoint's
+        # policy is asked about.  The backward reads neither the output
+        # nor any statistic of the forward: its kernels make their own.
         out = checkpoint_name(
             _run_kernel(q, k, v, vl, causal, scale, interpret), KEPT_OUTPUT)
-        # the blocked backward reads the output; the scanned one makes
-        # its own and keeps what it always kept
-        return out, (q, k, v, vl, out if stacks_too_much(q, k) else None)
+        return out, (q, k, v, vl)
 
     def core_bwd(causal, scale, interpret, res, g):
-        q, k, v, vl, out = res
+        q, k, v, vl = res
         import jax.numpy as jnp
         with jax.named_scope("flash_attention_bwd"):
-            if out is not None:
-                # where a rematerialised block has kept ``out``, nothing
-                # in its backward reads q, k, v at their own precision any
-                # more (the kernel's call did), and XLA narrows the
-                # projections that make them again to the bfloat16 of the
-                # matmuls below; a narrowed 84 MB operand of the second
-                # scan then fits VMEM, is left there across the loop and is
-                # copied out and sliced back in every pair (130 ms of the
-                # 8k cell's step, PERF.md section 6, PR 31).  A rounding to
-                # the precision they have is an identity that no conversion
-                # moves across: they arrive as the kernel took them.
-                bits = jnp.finfo(q.dtype)
-                q, k, v = (jax.lax.reduce_precision(x, bits.nexp, bits.nmant)
-                           for x in (q, k, v))
-                dq, dk, dv = _blocked_backward(q, k, v, vl, causal, scale,
-                                               out, g)
-                return dq, dk, dv, jnp.zeros_like(vl)
-            _, vjp = jax.vjp(
-                lambda a, b, c: _chunked_reference(a, b, c, vl, causal,
-                                                   scale),
-                q, k, v)
-            dq, dk, dv = vjp(g)
-            # vl is a mask, not a weight
-            return dq, dk, dv, jnp.zeros_like(vl)
+            dq, dk, dv = _run_backward(q, k, v, vl, g, causal, scale,
+                                       interpret)
+        # vl is a mask, not a weight
+        return dq, dk, dv, jnp.zeros_like(vl)
     core.defvjp(core_fwd, core_bwd)
     return core
 
 
 def _flash_core(q, k, v, vl, causal: bool, scale: float, interpret: bool):
     return _flash_core_fn()(q, k, v, vl, causal, scale, interpret)
+
+
+def _pad_to(x, rows: int, dp: int):
+    """``x`` padded to (rows, dp): only what the chosen tiles still need, a
+    ragged Lq/Lk and a head whose width a kernel does not take as it is."""
+    import jax.numpy as jnp
+    if x.shape[1:] == (rows, dp):
+        return x
+    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]),
+                       (0, dp - x.shape[2])))
 
 
 def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
@@ -503,17 +680,11 @@ def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
     lk = k.shape[1]
     _, lqp, _, _, lkp, dp = _tiling(lq, lk, d, jnp.result_type(q).itemsize)
 
-    def pad_to(x, rows):
-        # only what the chosen tiles still need: ragged Lq/Lk, and a head
-        # whose width the kernel does not take as it is
-        if x.shape[1:] == (rows, dp):
-            return x
-        return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, dp - d)))
-
     # the kernel's own pad and unpad carry a name of their own: their
     # device time is the kernel's to answer for
     with jax.named_scope("flash_attention_pad"):
-        qp, kp, vp = pad_to(q, lqp), pad_to(k, lkp), pad_to(v, lkp)
+        qp, kp, vp = (_pad_to(x, rows, dp)
+                      for x, rows in ((q, lqp), (k, lkp), (v, lkp)))
     call = _build_call(bh, lq, lk, d, bool(causal), float(scale),
                        jnp.result_type(q).name, bool(interpret))
     out = call(vl.astype(jnp.int32), qp, kp, vp)
@@ -533,9 +704,12 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     ``valid_len`` enables per-sequence key-padding masks — shape (B,) or
     (B*H,); keys at positions >= valid_len[i] are masked exactly like the
     additive -1e9 padding mask of the XLA path.
-    DIFFERENTIABLE: the forward runs the Pallas kernel, the backward
-    differentiates an equivalent chunked jnp formulation — gradients also
-    never touch an (Lq, Lk) score matrix.
+    DIFFERENTIABLE: the forward runs the Pallas kernel, the backward two
+    more (``dq`` and ``dkv``), at the precision XLA's default gives a
+    matmul on the chip (float32 operands rounded to bfloat16 once,
+    float32 accumulation) — gradients also never touch an (Lq, Lk) score
+    matrix, and key tiles beyond a row's ``valid_len`` cost nothing in
+    either direction.
     """
     import jax.numpy as jnp
 

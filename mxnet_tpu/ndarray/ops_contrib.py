@@ -962,9 +962,9 @@ def _register_round3b():
                 aliases=("index_array",), differentiable=False)
 
     # ---- flash attention (kernels/flash_attention.py Pallas kernel) ------
-    # DIFFERENTIABLE: the Pallas forward carries a custom VJP that
-    # differentiates an equivalent chunked jnp formulation, so neither
-    # direction materializes the (Lq, Lk) score matrix.  Eager dispatch
+    # DIFFERENTIABLE: the Pallas forward carries a custom VJP whose
+    # backward is two Pallas kernels of its own, so neither direction
+    # materializes the (Lq, Lk) score matrix.  Eager dispatch
     # (use_jit=False) keeps the Mosaic-vs-interpret choice keyed on the
     # data's actual device.
     def flash_attention_maker(causal=False, scale=None):

@@ -94,23 +94,60 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
     assert (" pad(" in text) == pads
 
 
-def test_flash_attention_backward_at_8k_compiles_for_v5e(one_chip):
-    """Forward and blocked backward at the MLA cell's shape fit the chip:
-    the scanned backward would stack 10.8 GB of carries there."""
+def _kernel_calls(text, name):
+    """Mosaic custom calls of the compiled ``text`` whose kernel is named
+    ``name``: XLA names the instruction after the ``pallas_call``, with the
+    transformations it went through before it (``jvp_<name>_``), which is
+    also how a device trace names the operation."""
+    return sum(name in line.split(" = ")[0] for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
+
+
+# the backward's two kernels at the shapes the cells meet: the BERT cell
+# (K and V resident, lengths, a head at its own 64 lanes) in float32 and
+# bfloat16; the MLA cell (20 heads of 256 over 8192 keys, causal: K-major
+# and Q-major blocks, the diagonal through them); a ragged causal Lk > Lq
+# and a causal Lk < Lq, whose first rows see no key
+@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal", [
+    ((192, 512, 64), None, "float32", True, False),
+    ((192, 512, 64), None, "bfloat16", True, False),
+    ((20, 8192, 256), None, "float32", False, True),
+    ((4, 200, 64), 640, "float32", True, True),
+    ((4, 640, 128), 200, "float32", True, True),
+], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k", "causal_lk_gt_lq",
+        "causal_lk_lt_lq_dead_rows"])
+def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
+                                                   dtype, valid_len, causal):
+    """Forward and the backward's kernels compile for the chip and fit it:
+    one Mosaic call each for the forward, ``dq`` and ``dkv``, and at the
+    MLA cell's shape temporaries far under 2 GiB (a scanned backward would
+    stack 10.8 GB of carries there; a blocked one made eight block-major
+    float32 copies, 1.3 GB)."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import flash_attention
 
-    arg = jax.ShapeDtypeStruct((20, 8192, 256), "float32", sharding=one_chip)
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((shape[0], lk or shape[1], shape[2]), dtype,
+                              sharding=one_chip)
+    args = [q, kv, kv]
+    if valid_len:
+        args.append(jax.ShapeDtypeStruct((shape[0],), "float32",
+                                         sharding=one_chip))
 
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       interpret=False))
+    def loss(q, k, v, vl=None):
+        out = flash_attention(q, k, v, causal=causal, valid_len=vl,
+                              interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
-        .lower(arg, arg, arg).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+        .lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert _kernel_calls(text, name) == 1, name
+    assert _kernel_calls(text, "flash_attention_fwd") <= 1
+    if shape[1] == 8192:
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 @pytest.mark.parametrize("keeps,calls", [(True, 1), (False, 2)],
@@ -119,9 +156,10 @@ def test_checkpointed_attention_block_runs_the_kernel_once_for_v5e(
         one_chip, keeps, calls):
     """A projection-attention-projection block at the MLA cell's shape
     under ``jax.checkpoint`` with the policy of a rematerialised block
-    (``gluon/block.py:_keep_named``): the compiled gradient holds one flash
-    kernel, the forward's, whose output the backward reads; under a
-    checkpoint that keeps nothing it holds two."""
+    (``gluon/block.py:_keep_named``): the compiled gradient holds one
+    forward flash kernel, counted by its name, whose output the
+    projection's backward reads; under a checkpoint that keeps nothing it
+    holds two.  The backward's two kernels are there once either way."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.gluon.block import _keep_named
@@ -148,7 +186,10 @@ def test_checkpointed_attention_block_runs_the_kernel_once_for_v5e(
             jax.checkpoint(block, policy=policy)(x, w_in, w_out) ** 2)
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, w_in, w_out)
-    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert _kernel_calls(text, "flash_attention_fwd") == calls
+    assert _kernel_calls(text, "flash_attention_bwd_dq") == 1
+    assert _kernel_calls(text, "flash_attention_bwd_dkv") == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == calls + 2
 
 
 def _resnet50_shapes():

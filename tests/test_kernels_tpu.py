@@ -154,3 +154,58 @@ def test_flash_attention_valid_len_matches_on_chip(tpu, shape):
         ref = jnp.einsum("bqk,bkd->bqd",
                          jax.nn.softmax(jnp.where(keep, s, -1e30), -1), v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape,causal", [((192, 512, 64), False),
+                                          ((8, 2048, 256), True)],
+                         ids=["bert_cell_lengths", "causal_k_major"])
+def test_flash_attention_backward_kernels_match_on_chip(tpu, shape, causal,
+                                                        dtype):
+    """The backward's two Mosaic kernels against the full softmax's
+    gradients at highest precision: the BERT cell's heads with its lengths
+    (K and V resident, tiles beyond a length skipped) and causal rows long
+    enough for K-major and Q-major blocks with the diagonal through them,
+    float32 and bfloat16 operands.  The kernels multiply at the chip's
+    default precision (operands rounded to bfloat16 once), so each gradient
+    is held to 2 % of the reference's largest entry and 1 % as a vector;
+    the gradient of a bias added to every key stays what one rounding of
+    ``ds`` leaves (under 1 % of dk's own norm)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    from chip_smoke import flash_lengths
+
+    bh, seq, d = shape
+    rs = np.random.default_rng(2)
+    q, k, v, g = (jnp.asarray(rs.standard_normal(shape, np.float32))
+                  .astype(dtype) for _ in range(4))
+    vl = (jnp.full((bh,), float(seq), jnp.float32) if causal
+          else jnp.asarray(flash_lengths(rs, bh, seq)))
+
+    def full(q, k, v):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(d)
+        keep = jnp.arange(seq)[None, None, :] < vl[:, None, None]
+        if causal:
+            keep = keep & (jnp.arange(seq)[None, None, :]
+                           <= jnp.arange(seq)[None, :, None])
+        return jnp.einsum("bqk,bkd->bqd",
+                          jax.nn.softmax(jnp.where(keep, s, -1e30), -1), v)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda q, k, v, g: jax.vjp(full, q, k, v)[1](
+            g.astype(jnp.float32)))(q, k, v, g)
+    got = jax.jit(lambda q, k, v, g: jax.vjp(
+        lambda a, b, c: flash_attention(a, b, c, causal=causal,
+                                        valid_len=vl), q, k, v)[1](g))(
+        q, k, v, g)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert np.abs(a - b).max() <= 2e-2 * np.abs(b).max(), name
+        assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b), name
+    dk = np.asarray(got[1], np.float32)
+    bias = dk.sum(axis=1)
+    assert np.sqrt((bias ** 2).mean()) <= 1e-2 * np.sqrt(
+        (dk ** 2).sum(axis=1).mean())
+

@@ -17,6 +17,7 @@ from mxnet_tpu.gluon.model_zoo.transformer import (BERTModel, MLAMoELM,
 from mxnet_tpu.observability.registry import registry
 from mxnet_tpu.parallel.moe import SparseMoE, publish_routing
 from tests import _mla_moe_reference as R
+from tests._jaxpr import pallas_call_names
 
 ATTN = (("attn_norm_g", "attn_norm.gamma"), ("q_down_w", "mla.q_down.weight"),
         ("q_norm_g", "mla.q_norm.gamma"), ("q_up_w", "mla.q_up.weight"),
@@ -371,43 +372,21 @@ def test_rematerialised_step_is_the_plain_step():
                if k.endswith("expert_load"))
 
 
-def _count(jaxpr, primitive):
-    """Equations of ``primitive`` in ``jaxpr``, sub-jaxprs included."""
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-    n = 0
-    for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == primitive
-        for sub in jax.tree.leaves(
-                list(eqn.params.values()),
-                is_leaf=lambda x: isinstance(x, (Jaxpr, ClosedJaxpr))):
-            if isinstance(sub, ClosedJaxpr):
-                sub = sub.jaxpr
-            if isinstance(sub, Jaxpr):
-                n += _count(sub, primitive)
-    return n
-
-
-@pytest.mark.parametrize("backward", ["scanned", "blocked"])
+@pytest.mark.parametrize("kernel,a_layer", [
+    ("flash_attention_fwd", 1), ("flash_attention_bwd", 2)],
+    ids=["forward", "backward"])
 def test_rematerialised_step_runs_the_flash_kernel_once_a_block(
-        monkeypatch, backward):
+        monkeypatch, kernel, a_layer):
     """A rematerialised block keeps the flash kernel's output across its
-    checkpoint: the step's jaxpr holds one ``pallas_call`` for each of the
-    four attention layers (three blocks and the MTP module's), as the
-    plain step does, where a checkpoint that keeps nothing holds two; and
-    ``trainer.remat_kept_bytes`` reads the four outputs' bytes, 0 where no
-    block is rematerialised."""
-    import importlib
+    checkpoint: the step's jaxpr holds one forward ``pallas_call``, counted
+    by its name, for each of the four attention layers (three blocks and
+    the MTP module's), as the plain step does, where a checkpoint that
+    keeps nothing holds two; the backward's two kernels run once a layer
+    whatever is kept; and ``trainer.remat_kept_bytes`` reads the four
+    outputs' bytes, 0 where no block is rematerialised."""
     from mxnet_tpu.gluon import block as block_mod
-    # the package binds the function under the module's name
-    fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
     monkeypatch.setenv("MXNET_ATTENTION_KERNEL", "flash")
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
-    blocked = []
-    if backward == "blocked":
-        monkeypatch.setattr(fa, "_BWD_CARRY_BUDGET", 0)
-        was = fa._blocked_backward
-        monkeypatch.setattr(fa, "_blocked_backward",
-                            lambda *a: blocked.append(1) or was(*a))
     cfg = R.tiny_config(n_routed_experts_held=2, experts_held_first=2)
     tokens = tokens_for(cfg)
     # one output: (batch 2 x 2 heads, 24 rows, a head of 16) float32
@@ -415,14 +394,17 @@ def test_rematerialised_step_runs_the_flash_kernel_once_a_block(
 
     def calls(remat):
         tr, _ = _trainer(cfg, remat)
-        return _count(tr.trace_step((tokens,), tokens).jaxpr, "pallas_call")
+        names = pallas_call_names(
+            tr.trace_step((tokens,), tokens).jaxpr)
+        assert all(n.startswith("flash_attention_") for n in names)
+        return sum(n.startswith(kernel) for n in names)
 
     kept = registry().gauge("trainer.remat_kept_bytes")
-    assert calls(remat=False) == 4 and kept.value == 0
-    assert calls(remat=True) == 4 and kept.value == 4 * out_bytes
-    assert bool(blocked) == (backward == "blocked")
+    assert calls(remat=False) == 4 * a_layer and kept.value == 0
+    assert calls(remat=True) == 4 * a_layer and kept.value == 4 * out_bytes
     monkeypatch.setattr(block_mod, "_keep_named", lambda: None)
-    assert calls(remat=True) == 8 and kept.value == 0
+    again = 8 if kernel == "flash_attention_fwd" else 4 * a_layer
+    assert calls(remat=True) == again and kept.value == 0
 
 
 def _tiny_bert_step_text(**kw):
