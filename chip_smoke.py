@@ -295,6 +295,32 @@ def flash_kernel_check(shape, seed):
             for n in ("block_q", "block_k", "grid_steps")})
 
 
+def hybrid_attention_block_check(seed):
+    """The hybrid decoder's attention block at the benchmark cell's shape
+    (``olmo_hybrid_7b_l4.pretrain_s2k``: 30 heads of 128 over 2048 tokens,
+    q/k norms, causal, no rotary) takes the flash path on its default
+    selector: the last kernel built has K and V resident and 30 x 4 query
+    tiles (the shapes checked before it leave other grids)."""
+    from mxnet_tpu.gluon.model_zoo.transformer import QKNormAttention
+    from mxnet_tpu.observability.registry import registry
+    block = QKNormAttention(3840, 30, prefix="smoke_attn_")
+    block.initialize(ctx=CTX)
+    block.hybridize()
+    x = mx.nd.array(np.random.default_rng(seed).standard_normal(
+        (1, 2048, 3840), dtype=np.float32), ctx=CTX)
+    out = block(x)
+    on_chip("hybrid attention block output", out._read())
+    check(np.isfinite(out.asnumpy()).all(),
+          "hybrid attention block: output not finite")
+    tiling = {n: int(registry().get(f"kernels.flash_attention.{n}").read())
+              for n in ("block_q", "block_k", "kv_resident", "grid_steps")}
+    check(tiling["kv_resident"] == 1 and tiling["grid_steps"] == 120,
+          f"hybrid attention block at (30, 2048, 128) did not take the flash "
+          f"path: tiling {tiling}")
+    say("kernel/flash_attention", block="QKNormAttention",
+        shape=(30, 2048, 128), causal=True, tiling=tiling)
+
+
 def make_bert():
     from mxnet_tpu.gluon.model_zoo.transformer import bert_base
     return bert_base(dropout=0.0)
@@ -624,6 +650,7 @@ def main():
         train_resnet50(args.seed)
         for shape in FLASH_SHAPES:
             flash_kernel_check(shape, args.seed)
+        hybrid_attention_block_check(args.seed)
         train_bert_base(args.seed)
         imperative_mlp(args.seed)
         multi_sgd_kernel_check(args.seed)

@@ -33,7 +33,8 @@ __all__ = ["SlidingWindowSelfAttention", "LongformerEncoderCell",
            "BERTModel", "bert_base", "bert_small", "transformer_nmt_base",
            "CausalLMCell", "CausalLM", "causal_lm_small",
            "MLAttention", "MLADecoderCell", "MTPModule", "MLAMoELM",
-           "TP_RULES"]
+           "GatedDeltaNet", "QKNormAttention", "HybridDecoderCell",
+           "OlmoHybridLM", "TP_RULES"]
 
 #: megatron-style tensor-parallel PartitionSpecs for this family — pass to
 #: parallel.ShardingRules(TP_RULES)
@@ -913,6 +914,23 @@ def causal_lm_small(vocab_size=257, **kwargs):
 # pre-norm causal decoder of the DeepSeek-V3 / GLM-4.x line
 # ---------------------------------------------------------------------------
 
+def _causal_attention(F, q, k, v, scale, kernel_serves=True):
+    """Causal softmax attention over heads-first (B*H, S, D) operands, for
+    training: the flash kernel where the selector takes it and its shapes
+    serve, else the full softmax through XLA."""
+    import jax
+    if kernel_serves and _flash_eligible(F, None, None, None):
+        return F.flash_attention(q, k, v, causal=True, scale=scale)
+    s = q.shape[1]
+    with jax.named_scope("attention_xla"):
+        keep = F.reshape(
+            F.arange(s).reshape((1, s)) <= F.arange(s).reshape((s, 1)),
+            shape=(1, s, s))
+        scores = F.batch_dot(q, k, transpose_b=True) * scale
+        return F.batch_dot(_masked_softmax(
+            F, scores, F.broadcast_to(keep, shape=scores.shape)), v)
+
+
 class MLAttention(HybridBlock):
     """Multi-head latent attention, causal, for training (no cache): the
     queries go through a ``q_lora_rank`` bottleneck, keys and values are
@@ -973,17 +991,8 @@ class MLAttention(HybridBlock):
                              shape=(b * h, s, width))
         q, k, v = heads_first(q, nope + rd), heads_first(k, nope + rd), \
             heads_first(v, vd)
-        scale = 1.0 / math.sqrt(nope + rd)
-        if vd == nope + rd and _flash_eligible(F, None, None, None):
-            out = F.flash_attention(q, k, v, causal=True, scale=scale)
-        else:
-            with jax.named_scope("attention_xla"):
-                keep = F.reshape(
-                    F.arange(s).reshape((1, s)) <= F.arange(s).reshape((s, 1)),
-                    shape=(1, s, s))
-                scores = F.batch_dot(q, k, transpose_b=True) * scale
-                out = F.batch_dot(_masked_softmax(
-                    F, scores, F.broadcast_to(keep, shape=scores.shape)), v)
+        out = _causal_attention(F, q, k, v, 1.0 / math.sqrt(nope + rd),
+                                kernel_serves=vd == nope + rd)
         out = F.transpose(F.reshape(out, shape=(b, h, s, vd)),
                           axes=(0, 2, 1, 3))
         return self.proj(F.reshape(out, shape=(b, s, h * vd)))
@@ -1102,3 +1111,214 @@ class MLAMoELM(HybridBlock):
         nxt = F.concat(F.slice_axis(tokens, axis=1, begin=1, end=None),
                        F.slice_axis(tokens, axis=1, begin=0, end=1), dim=1)
         return logits, self.head(self.mtp(x, self.embed(nxt)))
+
+
+# ---------------------------------------------------------------------------
+# Linear attention beside full attention: the post-norm hybrid decoder of the
+# OLMo line (three gated delta-rule blocks to one attention block)
+# ---------------------------------------------------------------------------
+
+def _uniform(shape, low, high):
+    import numpy as np
+    import jax.random as jr
+    from ... import random as _grandom
+    return np.asarray(jr.uniform(_grandom.next_key(), shape, minval=low,
+                                 maxval=high))
+
+
+def _init_a_log(_, arr):
+    """``A_log = log U(1, 16)``: each head forgets at a rate of its own."""
+    import numpy as np
+    arr[:] = np.log(_uniform(arr.shape, 1.0, 16.0))
+
+
+def _init_dt_bias(_, arr):
+    """``softplus(dt_bias) = exp(U(log 0.001, log 0.1))``."""
+    import numpy as np
+    dt = np.exp(_uniform(arr.shape, math.log(0.001), math.log(0.1)))
+    arr[:] = dt + np.log(-np.expm1(-dt))
+
+
+class GatedDeltaNet(HybridBlock):
+    """Gated delta-rule mixer (Gated DeltaNet), causal, for training (no
+    cache): ``q``, ``k`` and ``v`` each go through a depthwise causal
+    convolution of ``conv_size`` taps and SiLU; per head ``q`` and ``k`` are
+    l2-normalised (``q`` also divided by ``sqrt(key_dim)``), ``beta =
+    sigmoid(x Wb)``, doubled where ``allow_neg_eigval``, ``g = -exp(A_log)
+    softplus(x Wa + dt_bias)``; ``F.gated_delta_rule`` carries a state of
+    (key_dim, value_dim) a head over the tokens; each head's output goes
+    through one shared RMSNorm of ``value_dim`` and an output gate
+    ``SiLU(x Wg)``, then the output projection."""
+
+    def __init__(self, units, num_heads, key_dim, value_dim, conv_size=4,
+                 allow_neg_eigval=True, epsilon=1e-6, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._h, self._dk, self._dv = num_heads, key_dim, value_dim
+        self._beta_scale = 2.0 if allow_neg_eigval else 1.0
+
+        def lin(out, inp, name):
+            return Dense(out, use_bias=False, flatten=False, in_units=inp,
+                         prefix=name)
+        with self.name_scope():
+            self.q = lin(num_heads * key_dim, units, "q_")
+            self.k = lin(num_heads * key_dim, units, "k_")
+            self.v = lin(num_heads * value_dim, units, "v_")
+            self.gate = lin(num_heads * value_dim, units, "gate_")
+            self.a = lin(num_heads, units, "a_")
+            self.b = lin(num_heads, units, "b_")
+            self.q_conv = self.params.get(
+                "q_conv", shape=(num_heads * key_dim, conv_size))
+            self.k_conv = self.params.get(
+                "k_conv", shape=(num_heads * key_dim, conv_size))
+            self.v_conv = self.params.get(
+                "v_conv", shape=(num_heads * value_dim, conv_size))
+            self.a_log = self.params.get("a_log", shape=(num_heads,),
+                                         init=_init_a_log)
+            self.dt_bias = self.params.get("dt_bias", shape=(num_heads,),
+                                           init=_init_dt_bias)
+            self.o_norm = RMSNorm(epsilon=epsilon, in_channels=value_dim,
+                                  prefix="o_norm_")
+            self.proj = lin(units, num_heads * value_dim, "proj_")
+
+    def hybrid_forward(self, F, x, q_conv, k_conv, v_conv, a_log, dt_bias):
+        import jax
+        b, s = x.shape[0], x.shape[1]
+        h, dk, dv = self._h, self._dk, self._dv
+        q, k, v = self.q(x), self.k(x), self.v(x)
+
+        def short(t, taps, width):
+            return F.reshape(F.silu(F.causal_conv1d(t, taps)),
+                             shape=(b, s, h, width))
+
+        def l2norm(t):
+            return t * F.rsqrt(F.sum(F.square(t), axis=-1, keepdims=True)
+                               + 1e-6)
+        with jax.named_scope("conv"):
+            q, k, v = short(q, q_conv, dk), short(k, k_conv, dk), \
+                short(v, v_conv, dv)
+            q, k = l2norm(q) * (1.0 / math.sqrt(dk)), l2norm(k)
+        with jax.named_scope("decay"):
+            beta = F.sigmoid(self.b(x)) * self._beta_scale
+            g = -F.exp(a_log) * F.Activation(self.a(x) + dt_bias,
+                                             act_type="softrelu")
+        o = F.gated_delta_rule(q, k, v, g, beta)
+        gate = F.reshape(self.gate(x), shape=(b, s, h, dv))
+        with jax.named_scope("norm"):
+            o = self.o_norm(o) * F.silu(gate)
+        return self.proj(F.reshape(o, shape=(b, s, h * dv)))
+
+
+class QKNormAttention(HybridBlock):
+    """Causal multi-head attention with an RMSNorm over all heads' lanes of
+    ``q`` and of ``k`` before the split, and no position embedding; for
+    training (no cache).  Through the flash kernel where it serves."""
+
+    def __init__(self, units, num_heads, epsilon=1e-6, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        if units % num_heads:
+            raise MXNetError(f"units {units} do not divide by {num_heads} "
+                             "heads")
+        self._heads = num_heads
+
+        def lin(name):
+            return Dense(units, use_bias=False, flatten=False,
+                         in_units=units, prefix=name)
+        with self.name_scope():
+            self.q, self.k, self.v = lin("q_"), lin("k_"), lin("v_")
+            self.q_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                  prefix="q_norm_")
+            self.k_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                  prefix="k_norm_")
+            self.proj = lin("proj_")
+
+    def hybrid_forward(self, F, x):
+        import jax
+        b, s, u = x.shape
+        h = self._heads
+        d = u // h
+        q, k, v = self.q(x), self.k(x), self.v(x)
+        with jax.named_scope("qk_norm"):
+            q, k = self.q_norm(q), self.k_norm(k)
+
+        def heads_first(t):
+            return F.reshape(F.transpose(F.reshape(t, shape=(b, s, h, d)),
+                                         axes=(0, 2, 1, 3)),
+                             shape=(b * h, s, d))
+        out = _causal_attention(F, heads_first(q), heads_first(k),
+                                heads_first(v), 1.0 / math.sqrt(d))
+        out = F.transpose(F.reshape(out, shape=(b, h, s, d)),
+                          axes=(0, 2, 1, 3))
+        return self.proj(F.reshape(out, shape=(b, s, u)))
+
+
+class HybridDecoderCell(HybridBlock):
+    """Post-norm block: ``h = x + RMSNorm(Mixer(x))``, then ``h +
+    RMSNorm(SwiGLU(h))``; ``mixer`` builds the block's mixer, of either
+    kind."""
+
+    def __init__(self, units, mixer, hidden_size, epsilon=1e-6, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.mixer = mixer()
+            self.mixer_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                      prefix="mixer_norm_")
+            self.ffn = SwiGLU(units, hidden_size, prefix="ffn_")
+            self.ffn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                    prefix="ffn_norm_")
+
+    def hybrid_forward(self, F, x):
+        x = x + self.mixer_norm(self.mixer(x))
+        return x + self.ffn_norm(self.ffn(x))
+
+
+class OlmoHybridLM(HybridBlock):
+    """Causal language model whose blocks are given by ``layer_types``:
+    ``"linear_attention"`` is a ``GatedDeltaNet`` block, ``"full_attention"``
+    a ``QKNormAttention`` block, each followed by a SwiGLU of
+    ``hidden_size``, post-norm; a final RMSNorm and an untied head.
+    ``net(tokens)`` returns the logits (B, S, vocab); ``remat_blocks`` lists
+    the blocks a trainer rematerialises."""
+
+    def __init__(self, vocab_size, units, layer_types, num_heads, hidden_size,
+                 linear_num_heads, linear_key_head_dim, linear_value_head_dim,
+                 linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+                 epsilon=1e-6, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        import functools
+        mixers = {
+            "linear_attention": functools.partial(
+                GatedDeltaNet, units, linear_num_heads, linear_key_head_dim,
+                linear_value_head_dim, conv_size=linear_conv_kernel_dim,
+                allow_neg_eigval=linear_allow_neg_eigval, epsilon=epsilon,
+                prefix="gdn_"),
+            "full_attention": functools.partial(
+                QKNormAttention, units, num_heads, epsilon=epsilon,
+                prefix="attn_")}
+        for i, kind in enumerate(layer_types):
+            if kind not in mixers:
+                raise MXNetError(f"layer_types[{i}] = {kind!r} is neither of "
+                                 f"{sorted(mixers)}")
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, units, prefix="embed_")
+            self.cells = HybridSequential(prefix="")
+            for i, kind in enumerate(layer_types):
+                self.cells.add(HybridDecoderCell(
+                    units, mixers[kind], hidden_size, epsilon=epsilon,
+                    prefix=f"layer{i}_"))
+            self.final_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                      prefix="final_norm_")
+            self.head = Dense(vocab_size, use_bias=False, flatten=False,
+                              in_units=units, prefix="head_")
+
+    @property
+    def remat_blocks(self):
+        return list(self.cells)
+
+    def hybrid_forward(self, F, tokens):
+        x = self.embed(tokens)
+        for cell in self.cells:
+            x = cell(x)
+        return self.head(self.final_norm(x))

@@ -1019,6 +1019,26 @@ def _register_round3b():
     register_op("_contrib_routed_experts", routed_experts_maker,
                 aliases=("routed_experts",), use_jit=False)
 
+    # ---- gated delta rule and its short convolution
+    # (kernels/gated_delta_rule.py) -----------------------------------------
+    def gated_delta_rule_maker():
+        from ..kernels.gated_delta_rule import gated_delta_rule as _gdr
+
+        def fn(q, k, v, g, beta):
+            # the outputs alone: the state after the last token is the
+            # kernel's second result, for the cache that will need it
+            return _gdr(q, k, v, g, beta)[0]
+        return fn
+    # use_jit=False, as routed_experts: its scopes stay the program's own
+    register_op("_contrib_gated_delta_rule", gated_delta_rule_maker,
+                aliases=("gated_delta_rule",), use_jit=False)
+
+    def causal_conv1d_maker():
+        from ..kernels.gated_delta_rule import causal_conv1d as _conv
+        return _conv
+    register_op("_contrib_causal_conv1d", causal_conv1d_maker,
+                aliases=("causal_conv1d",), use_jit=False)
+
     # ---- allclose --------------------------------------------------------
     def allclose_maker(rtol=1e-5, atol=1e-8, equal_nan=False):
         def fn(a, b):

@@ -333,6 +333,9 @@ class ShardedTrainer:
             "trainer.remat_kept_bytes", "bytes of named values that the "
             "rematerialised blocks of the last step traced keep for the "
             "backward")
+        # and so do the delta rule's state passes, which add their
+        # iterations (kernels/gated_delta_rule.py:_count_scan)
+        scan_steps = _metrics_registry().gauge("gdn.scan_steps")
         tparams, aparams = self._train_params, self._aux_params
         fopt, ctx = self._fopt, self._ctx
 
@@ -451,6 +454,7 @@ class ShardedTrainer:
             gradient, so the optimizer's rescale is unchanged."""
             def grads_of(pvals, avals, key, xv, yv, ls):
                 kept_bytes.set(0)
+                scan_steps.set(0)
                 if accum == 1:
                     # trace-time probe: which sparse-marked tables does
                     # THIS trace's forward actually reach, and with how

@@ -71,6 +71,8 @@ def _compiled_text(fn, *args):
     ((8, 1, 128), 300, "float32", True, True, True),
     # the MLA cell: 20 heads of 256 over 8192 keys, causal, K-major
     ((20, 8192, 256), None, "float32", False, True, False),
+    # the hybrid cell's attention block: 30 heads of 128 over 2048, causal
+    ((30, 2048, 128), None, "float32", False, True, False),
 ])
 def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
                                           valid_len, causal, pads):
@@ -114,8 +116,9 @@ def _kernel_calls(text, name):
     ((20, 8192, 256), None, "float32", False, True),
     ((4, 200, 64), 640, "float32", True, True),
     ((4, 640, 128), 200, "float32", True, True),
+    ((30, 2048, 128), None, "float32", False, True),
 ], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k", "causal_lk_gt_lq",
-        "causal_lk_lt_lq_dead_rows"])
+        "causal_lk_lt_lq_dead_rows", "hybrid_cell_2k"])
 def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
                                                    dtype, valid_len, causal):
     """Forward and the backward's kernels compile for the chip and fit it:
@@ -148,6 +151,29 @@ def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
     assert _kernel_calls(text, "flash_attention_fwd") <= 1
     if shape[1] == 8192:
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
+    """The chunked delta rule at the hybrid cell's shape (one row of 2048
+    tokens, 30 heads of 96 / 192), forward and backward, compiles for the
+    chip: two ``while`` loops (the state pass and its backward) and
+    temporaries under 1 GiB (0.90 GB when written: some twenty arrays of
+    (30, 32, 64, 64..192) float32 that the batched parts keep for their
+    backward, among them 71 MB of chunk states)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels.gated_delta_rule import gated_delta_rule
+
+    def operand(*shape):
+        return jax.ShapeDtypeStruct(shape, "float32", sharding=one_chip)
+    args = [operand(1, 2048, 30, 96), operand(1, 2048, 30, 96),
+            operand(1, 2048, 30, 192), operand(1, 2048, 30),
+            operand(1, 2048, 30)]
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)[0]),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    assert compiled.as_text().count(" while(") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 @pytest.mark.parametrize("keeps,calls", [(True, 1), (False, 2)],
