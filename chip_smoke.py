@@ -12,7 +12,9 @@ each at the full width of a model the repo supports (random weights from
 - train / BERT-base — MLM+NSP loss with per-row valid lengths, batch 8,
   seq 256, attention on its default selector (the Pallas flash kernel), and
   the kernel against the full-softmax XLA result at those shapes and at
-  the benchmark cell's (batch 16, seq 512, its valid lengths);
+  the benchmark cell's (batch 16, seq 512, its valid lengths), and over
+  tokens-major operands (heads as blocks of the lanes) at the three
+  benchmark cells' shapes against the heads-first call;
 - imperative / Gluon MLP — un-hybridized 784-128-64-10 inside
   ``with mx.tpu(0):``, ``gluon.Trainer`` sgd+momentum (the ``multi_sgd``
   Mosaic kernel), bulked segments;
@@ -73,6 +75,10 @@ BERT_BATCH, BERT_SEQ, BERT_VOCAB = 8, 256, 30522
 # BERT-base's heads at this script's batch 8 x seq 256, and at the
 # benchmark cell's batch 16 x seq 512 (bert_base.pretrain_s512)
 FLASH_SHAPES = ((96, 256, 64), (192, 512, 64))
+# the benchmark cells' attention as their models hand it to the kernels,
+# tokens-major: (batch, tokens, heads, lanes of a head, causal)
+FLASH_CELLS = ((16, 512, 12, 64, False), (1, 8192, 20, 256, True),
+               (1, 2048, 30, 128, True))
 LM = dict(vocab_size=30522, num_layers=12, units=768, hidden_size=3072,
           num_heads=12, max_length=1024)    # BERT-base widths
 
@@ -293,6 +299,89 @@ def flash_kernel_check(shape, seed):
         backward_tiling={
             n: int(registry().get(f"kernels.flash_attention_bwd.{n}").read())
             for n in ("block_q", "block_k", "grid_steps")})
+
+
+def tokens_major_against_heads_first(rng, b, seq, heads, d, causal,
+                                     dtype=jnp.float32):
+    """The kernels over tokens-major operands, (B, L, heads * d): a head is
+    a block of the lanes (two 64-lane heads to a block of 128), against the
+    heads-first call on the transposed operands, forward and gradients,
+    from separate q, k, v and from one fused array read in place.  Returns
+    the largest output difference, the gradients' largest difference over
+    the reference's largest entry and as vectors, the gauges the
+    tokens-major build set, and its compiled text."""
+    from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
+    q, k, v, cot = (jax.device_put(rng.standard_normal(
+        (b, seq, heads * d), dtype=np.float32), CTX.device).astype(dtype)
+        for _ in range(4))
+    vl = None if causal else jax.device_put(
+        flash_lengths(rng, b * 12, seq)[::12], CTX.device)
+
+    def heads_first(t):
+        return t.reshape(b, seq, heads, d).transpose(0, 2, 1, 3)
+
+    def reference(q, k, v):
+        out = flash_attention(heads_first(q), heads_first(k), heads_first(v),
+                              causal=causal, valid_len=vl)
+        return out.transpose(0, 2, 1, 3).reshape(b, seq, heads * d)
+
+    def lanes(q, k, v):
+        return flash_attention(q, k, v, causal=causal, valid_len=vl,
+                               num_heads=heads)
+
+    def fused(x):
+        return flash_attention(x, x, x, causal=causal, valid_len=vl,
+                               num_heads=heads, head_dim=d,
+                               first_head=(0, heads, 2 * heads))
+
+    def both(f):
+        return jax.jit(lambda *a: (f(*a), jax.vjp(f, *a)[1](cot)))
+
+    want_out, want = both(reference)(q, k, v)
+    got_out, got = both(lanes)(q, k, v)
+    gauges = {n.replace("_bwd.", "bwd_").lstrip("."): int(
+        registry().get(f"kernels.flash_attention{n}").read())
+        for n in (".lane_heads", ".tokens_major", ".grid_steps",
+                  "_bwd.lane_heads")}
+    fused_out, (fused_grad,) = both(fused)(jnp.concatenate([q, k, v], -1))
+    on_chip("tokens-major flash attention output", got_out, fused_out)
+    f32 = [[np.asarray(x, np.float32) for x in xs] for xs in (
+        (got_out, fused_out), (want_out, want_out),
+        got + tuple(jnp.split(fused_grad, 3, -1)), want + want)]
+    return dict(
+        out_err=max(np.abs(a - r).max() for a, r in zip(*f32[:2])),
+        grad_max_rel=max(np.abs(a - r).max() / np.abs(r).max()
+                         for a, r in zip(*f32[2:])),
+        grad_vector_rel=max(np.linalg.norm(a - r) / np.linalg.norm(r)
+                            for a, r in zip(*f32[2:])),
+        gauges=gauges,
+        text=both(lanes).lower(q, k, v).compile().as_text())
+
+
+def flash_tokens_major_check(seed):
+    """``tokens_major_against_heads_first`` at the three benchmark cells'
+    shapes, as their models hand them to the kernels."""
+    rng = np.random.default_rng(seed)
+    for b, seq, heads, d, causal in FLASH_CELLS:
+        shape = (b, seq, heads, d)
+        read = tokens_major_against_heads_first(rng, *shape, causal)
+        mosaic("tokens-major flash attention",
+               jax.device_put(np.zeros(1, np.float32), CTX.device),
+               read.pop("text"))
+        gauges = read["gauges"]
+        check(gauges["tokens_major"] == 1
+              and gauges["lane_heads"] == gauges["bwd_lane_heads"]
+              == max(128 // d, 1),
+              f"tokens-major flash at {shape}: gauges {gauges}")
+        check(read["out_err"] <= 3e-5, f"tokens-major flash {shape}: max "
+              f"|err| {read['out_err']} from the heads-first call > 3e-5")
+        check(read["grad_max_rel"] <= FLASH_GRAD_RTOL,
+              f"tokens-major flash gradients {shape} off by "
+              f"{read['grad_max_rel']} of the largest heads-first gradient")
+        say("kernel/flash_attention", operands="tokens-major", shape=shape,
+            causal=causal, **{k: float(f"{v:.2e}") for k, v in read.items()
+                              if k != "gauges"}, **gauges)
 
 
 def hybrid_attention_block_check(seed):
@@ -650,6 +739,7 @@ def main():
         train_resnet50(args.seed)
         for shape in FLASH_SHAPES:
             flash_kernel_check(shape, args.seed)
+        flash_tokens_major_check(args.seed)
         hybrid_attention_block_check(args.seed)
         train_bert_base(args.seed)
         imperative_mlp(args.seed)

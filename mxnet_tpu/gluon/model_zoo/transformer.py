@@ -150,38 +150,43 @@ class MultiHeadAttention(HybridBlock):
         allowed when they express the SAME prefix mask (the XLA path
         uses ``mask``, flash uses ``valid_len``)."""
         b, sq = x.shape[0], x.shape[1]
+        h, d = self._heads, self._units // self._heads
         if self._self:
-            qkv = self.qkv(x)
-            q, k, v = F.split(qkv, num_outputs=3, axis=-1)
-            sk = sq
+            q = kv = self.qkv(x)
+            first, sk = (0, h, 2 * h), sq
         else:
             if mem is None:
                 raise MXNetError("cross-attention needs memory input")
-            q = self.q_proj(x)
-            kv = self.kv(mem)
-            k, v = F.split(kv, num_outputs=2, axis=-1)
-            sk = mem.shape[1]
-        q = self._split_heads(F, q, b, sq)
-        k = self._split_heads(F, k, b, sk)
-        v = self._split_heads(F, v, b, sk)
-        scale = 1.0 / math.sqrt(self._units // self._heads)
+            q, kv = self.q_proj(x), self.kv(mem)
+            first, sk = (0, 0, h), mem.shape[1]
+        scale = 1.0 / math.sqrt(d)
         if self._flash_eligible(F, mask, valid_len):
             # tiled online-softmax Pallas kernel whose custom VJP is two
             # Pallas kernels — differentiable, no (Lq, Lk) score matrix in
-            # either direction (kernels/flash_attention.py)
-            if valid_len is None:
-                out = F.flash_attention(q, k, v, scale=scale)
-            else:
-                out = F.flash_attention(q, k, v, valid_len, scale=scale)
+            # either direction — that reads each head where the fused
+            # projection left it, as a block of the array's lanes: no head
+            # is split off or merged back (kernels/flash_attention.py)
+            lens = () if valid_len is None else (valid_len,)
+            out = F.flash_attention(q, kv, kv, *lens, scale=scale,
+                                    num_heads=h, head_dim=d,
+                                    first_head=first)
         else:
             import jax
+            if self._self:
+                q, k, v = F.split(kv, num_outputs=3, axis=-1)
+            else:
+                k, v = F.split(kv, num_outputs=2, axis=-1)
+            q = self._split_heads(F, q, b, sq)
+            k = self._split_heads(F, k, b, sk)
+            v = self._split_heads(F, v, b, sk)
             with jax.named_scope("attention_xla"):
                 scores = F.batch_dot(q, k, transpose_b=True) * scale
                 att = _masked_softmax(F, scores, mask)
                 if self.drop is not None:
                     att = self.drop(att)
                 out = F.batch_dot(att, v)
-        return self.proj(self._merge_heads(F, out, b, sq))
+            out = self._merge_heads(F, out, b, sq)
+        return self.proj(out)
 
     def _flash_eligible(self, F, mask, valid_len) -> bool:
         return _flash_eligible(F, mask, valid_len, self.drop)
@@ -914,21 +919,37 @@ def causal_lm_small(vocab_size=257, **kwargs):
 # pre-norm causal decoder of the DeepSeek-V3 / GLM-4.x line
 # ---------------------------------------------------------------------------
 
-def _causal_attention(F, q, k, v, scale, kernel_serves=True):
-    """Causal softmax attention over heads-first (B*H, S, D) operands, for
-    training: the flash kernel where the selector takes it and its shapes
-    serve, else the full softmax through XLA."""
+def _causal_attention(F, q, k, v, scale, heads=None, kernel_serves=True):
+    """Causal softmax attention for training: the flash kernel where the
+    selector takes it and its shapes serve, else the full softmax through
+    XLA.  The operands are heads-first, (B*H, S, D), or with ``heads`` given
+    tokens-major, (B, S, heads * D) as a projection leaves them: the kernel
+    then reads a head as a block of the lanes, and only the XLA path
+    splits the heads off."""
     import jax
     if kernel_serves and _flash_eligible(F, None, None, None):
-        return F.flash_attention(q, k, v, causal=True, scale=scale)
-    s = q.shape[1]
+        return F.flash_attention(q, k, v, causal=True, scale=scale,
+                                 num_heads=heads)
+    b, s = q.shape[0], q.shape[1]
+
+    def heads_first(t):
+        if heads is None:
+            return t
+        return F.reshape(F.transpose(F.reshape(t, shape=(b, s, heads, -1)),
+                                     axes=(0, 2, 1, 3)),
+                         shape=(b * heads, s, -1))
     with jax.named_scope("attention_xla"):
+        q, k, v = heads_first(q), heads_first(k), heads_first(v)
         keep = F.reshape(
             F.arange(s).reshape((1, s)) <= F.arange(s).reshape((s, 1)),
             shape=(1, s, s))
         scores = F.batch_dot(q, k, transpose_b=True) * scale
-        return F.batch_dot(_masked_softmax(
+        out = F.batch_dot(_masked_softmax(
             F, scores, F.broadcast_to(keep, shape=scores.shape)), v)
+    if heads is None:
+        return out
+    return F.reshape(F.transpose(F.reshape(out, shape=(b, heads, s, -1)),
+                                 axes=(0, 2, 1, 3)), shape=(b, s, -1))
 
 
 class MLAttention(HybridBlock):
@@ -986,6 +1007,13 @@ class MLAttention(HybridBlock):
                                  size=h), dim=-1)
         v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
 
+        # heads-first, not the lanes of (b, s, h * 256): q, k and v are made
+        # a head at a time (rotary lanes, one shared rotary key, v cut out
+        # of a head's 448), so XLA writes them out once either way ((b, s,
+        # 20, 256) and (b, s, 5120) are tiled differently on the chip), and
+        # written heads-first a K-major block is one contiguous slab: the
+        # backward's kernels, which fetch each block many times, read
+        # 2.5-8.6 % slower from 1 KB rows (PERF.md section 6, PR 35)
         def heads_first(t, width):
             return F.reshape(F.transpose(t, axes=(0, 2, 1, 3)),
                              shape=(b * h, s, width))
@@ -1235,22 +1263,12 @@ class QKNormAttention(HybridBlock):
 
     def hybrid_forward(self, F, x):
         import jax
-        b, s, u = x.shape
-        h = self._heads
-        d = u // h
         q, k, v = self.q(x), self.k(x), self.v(x)
         with jax.named_scope("qk_norm"):
             q, k = self.q_norm(q), self.k_norm(k)
-
-        def heads_first(t):
-            return F.reshape(F.transpose(F.reshape(t, shape=(b, s, h, d)),
-                                         axes=(0, 2, 1, 3)),
-                             shape=(b * h, s, d))
-        out = _causal_attention(F, heads_first(q), heads_first(k),
-                                heads_first(v), 1.0 / math.sqrt(d))
-        out = F.transpose(F.reshape(out, shape=(b, h, s, d)),
-                          axes=(0, 2, 1, 3))
-        return self.proj(F.reshape(out, shape=(b, s, u)))
+        return self.proj(_causal_attention(
+            F, q, k, v, 1.0 / math.sqrt(x.shape[2] // self._heads),
+            heads=self._heads))
 
 
 class HybridDecoderCell(HybridBlock):

@@ -10,11 +10,44 @@ O(Lq·Lk) to O(Lq·D + Lk·D) — exactly the memory-bound regime SURVEY §6
 flags for long sequences (ring attention in parallel/ring.py handles the
 multi-chip axis; this kernel is the single-chip inner loop).
 
+Layouts (``flash_attention``; which form engages follows from the
+operands' shape and the head count alone, never from a switch a user
+sets).  *Tokens-major*, with the head count given: q is (B, Lq, ..) and
+k, v are (B, Lk, ..) exactly as the projections leave them, head beside
+head in the lanes, and the result, dq, dk and dv are (B, L, H·d).  **The
+head is a lane block of the array, chosen by the index map, not an axis
+made by a transpose**: a head of whole lane groups (d % 128 == 0) is the
+block ``(1, rows, d)`` at ``(b, i, h)``; two heads of 64 share the
+128-lane block ``(1, rows, 128)`` (Mosaic takes no 64-lane block out of a
+wider array) and one grid step does both, each with the other's lanes of
+the operand it holds still set to nought, so a contraction over all 128
+lanes is one head's (the other's add exact zeros, and 64 deep fills half
+of the 128-deep MXU either way) and of every product that comes out 128
+wide each head keeps its own half.  HBM rows are then whole 128-lane rows.
+Each operand also says at which head of its array it starts, so a fused
+projection (one (B, L, 3·H·d) array) is read where it lies; its gradient
+comes back as one concatenation of dq, dk, dv.  *Heads-first*, the case
+H = 1: (B·H, L, d) (or (B, H, L, D)) is a tokens-major array of batch B·H
+whose one head is the whole last axis, at its own width (a head of 64 is a
+block 64 lanes wide); ``valid_len`` is then a length a row of B·H.  A head
+count or width that lane blocks do not serve (an odd count of 64-lane
+heads, d = 32, 96, ...) is split off by a transpose inside the entry and
+takes the heads-first form.  Gauges ``kernels.flash_attention.lane_heads``
+(heads a block holds), ``.tokens_major`` (1: H > 1 heads read as lane
+blocks) and ``kernels.flash_attention_bwd.lane_heads`` say which engaged.
+Which form a caller should hand over is its own to know: tokens-major
+spares the copies only where the operands already lie (B, L, H·d) in
+memory (BERT's fused projection, a plain q/k/v projection), and a lane
+block is a strided DMA, rows of d lanes at a stride of the array's width:
+the MLA block, which makes q, k and v a head at a time and whose K-major
+blocks are fetched many times over, reads faster heads-first (PERF.md
+section 6, PR 35).
+
 Tiling (``_tiling``, chosen from the shape and the dtype under one VMEM
 budget, never from a constant a user sets): the grid is
-(batch·heads, Lq/block_q, Lk/kv_block).  A head's K and V stay resident
-in VMEM as one block whenever they fit the budget (kv_block = Lk, the
-last grid axis has one step); longer keys ride a K-major, sequential grid
+(batch, lane blocks, Lq/block_q, Lk/kv_block).  A lane block's K and V
+stay resident in VMEM whole whenever they fit the budget (kv_block = Lk,
+the last grid axis has one step); longer keys ride a K-major, sequential grid
 axis of blocks of at most ``_KV_MAJOR`` keys, as many as the budget holds
 (1024 of a 256-lane float32 head), with the running max/denominator/
 accumulator in VMEM scratch between its steps.  Inside a block the sweep
@@ -27,8 +60,8 @@ nor fetched (its index map is clamped to the last block that holds one).
 Under a causal mask with Lk >= Lq the keys beyond a query tile's last row
 bound the sweep in the same way: tiles and blocks wholly above the diagonal
 are neither visited nor fetched.
-The head keeps its own width (a head of 64 is a block 64 lanes wide);
-only ragged Lq/Lk are padded, to the tile.
+Only a ragged Lq/Lk is padded, to the tile, and a lone head to a width
+the MXU contracts over.
 
 The backward is two Pallas kernels (``_build_backward``; tiles from
 ``_bwd_tiling``, by the same rules and the same budget): ``dq``, which
@@ -48,6 +81,7 @@ it for a described v5e; chip_smoke.py runs it).
 from __future__ import annotations
 
 import functools
+import typing
 
 _NEG_INF = -1e30
 # the forward's tiles (read on a v5e at (192, 512, 64) float32 with the
@@ -111,11 +145,64 @@ def _major(rows: int, tile: int, dp: int, itemsize: int):
     return block, _round_up(rows, block)
 
 
+def _lane_blocks(heads: int, d: int, first, widths):
+    """(heads a lane block holds, lanes of a block) for ``heads`` heads of
+    ``d`` lanes that start at head ``first[i]`` of an array ``widths[i]``
+    lanes wide (q, k, v in turn), or None where no lane block serves and
+    the caller makes the heads-first form itself.  A head of whole lane
+    groups is its own block; two heads of 64 share one 128-lane block
+    (Mosaic takes no 64-lane block out of a wider array), so they come in
+    pairs that start on an even head; one head that is the whole array
+    keeps its own width, padded to what the MXU contracts over."""
+    if d % 128 == 0:
+        return 1, d
+    if d == 64 and heads % 2 == 0 and not any(f % 2 for f in first) \
+            and not any(w % 128 for w in widths):
+        return 2, 128
+    if heads == 1 and not any(first) and all(w == d for w in widths):
+        return 1, d
+    return None
+
+
+def _own(x, h: int, hb: int):
+    """``x`` with the lanes of every head of its block but ``h`` set to
+    nought (a block holds ``hb`` heads side by side, the first in the low
+    lanes): a contraction over all its lanes is then head ``h``'s alone,
+    the others adding exact zeros."""
+    import jax.numpy as jnp
+    from jax import lax
+    if hb == 1:
+        return x
+    head = lax.broadcasted_iota(jnp.int32, x.shape, 1) // (x.shape[1] // hb)
+    return jnp.where(head == h, x, jnp.zeros_like(x))
+
+
+def _each(parts, width: int, axis: int = 1):
+    """One block from a result a head that came out all ``width`` lanes
+    (``axis`` 0: sublanes) wide: of ``parts[h]`` head ``h``'s own share.
+    Parts may be rows or columns that broadcast along ``axis``."""
+    import jax.numpy as jnp
+    from jax import lax
+    whole = parts[0]
+    for h in range(1, len(parts)):
+        shape = list(jnp.broadcast_shapes(whole.shape, parts[h].shape))
+        shape[axis] = width
+        head = lax.broadcasted_iota(jnp.int32, shape, axis) // (
+            width // len(parts))
+        whole = jnp.where(head == h, parts[h], whole)
+    return whole
+
+
 @functools.lru_cache(maxsize=None)
 def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
-                scale: float, dtype_name: str, interpret: bool):
+                scale: float, dtype_name: str, interpret: bool,
+                blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0)):
     """The kernel for one call's (unpadded) shape; it takes the operands
-    padded as ``_tiling`` says.  Each build says which tiling engaged in
+    padded as ``_tiling`` says.  ``d`` is the width of a lane block: of
+    every operand's last axis the grid's second axis owns ``blocks`` of
+    them in turn, from block ``first[i]`` of operand i on, and a block
+    holds ``lane_heads`` heads side by side (heads-first operands are one
+    block, the whole last axis).  Each build says which tiling engaged in
     the ``kernels.flash_attention.*`` gauges."""
     import jax
     import jax.numpy as jnp
@@ -130,13 +217,16 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
     nq, nkv = lqp // block_q, lkp // kv_block
     tiles = kv_block // block_k
     precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    hb = lane_heads
 
     reg = registry()
     reg.counter("kernels.flash_attention.builds",
                 "flash forward kernels built (one per shape)").inc()
     for name, value in (("block_q", block_q), ("block_k", block_k),
                         ("kv_resident", int(nkv == 1)),
-                        ("grid_steps", bh * nq * nkv)):
+                        ("grid_steps", bh * blocks * nq * nkv),
+                        ("lane_heads", hb),
+                        ("tokens_major", int(blocks * hb > 1))):
         reg.gauge(f"kernels.flash_attention.{name}",
                   "tiling of the last flash forward kernel built").set(value)
 
@@ -156,21 +246,19 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         """Online softmax of one query tile over the key tiles of K-major
         block ``kj`` that hold a key below ``vl`` (the tile's key limit): a
         key at or beyond it has weight 0 whether the row is live or dead,
-        so the tiles beyond it are never visited."""
+        so the tiles beyond it are never visited.  Where the block holds
+        two heads, each is swept with the other's lanes of q set to
+        nought (its scores are then a contraction over all the lanes, the
+        other head's adding exact zeros), and of ``p v``, which comes out
+        all lanes wide, each head keeps its own."""
         k0 = kj * kv_block
+        qs = [_own(q, h, hb) for h in range(hb)]
 
         def tile(t, carry):
-            m, l, acc = carry
+            ms, ls, acc = carry
             start = pl.multiple_of(t * block_k, block_k)
             k = k_ref[0, pl.ds(start, block_k), :]
             v = v_ref[0, pl.ds(start, block_k), :]
-            # operands stay in the input dtype, the scale is applied to
-            # the f32 scores: bf16 products are exact in the MXU's f32
-            # accumulator, and f32 operands ask for full precision (the
-            # MXU's default would round them to bf16: 5e-3 off at seq 256)
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), precision=precision,
-                preferred_element_type=jnp.float32) * scale   # (BQ, BK)
             # mask K padding (and the causal upper triangle)
             k_idx = k0 + start + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
@@ -184,93 +272,121 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                 q_idx = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 mask = mask & (k_idx <= q_idx + (lk - lq))
-            s = jnp.where(mask, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if causal:
-                # rows whose every key so far is masked (causal bound
-                # < 0): the reference softmaxes a uniform -NEG_INF row,
-                # i.e. uniform attention over the valid keys — exp(0)=1
-                # here would instead spread over PADDED slots, so
-                # substitute the valid mask as the weights (masks are
-                # prefixes, so a row dead in this tile is dead in every
-                # tile).  Without ``causal`` every visited tile holds a
-                # valid key and no row is dead.
-                dead = m_new <= (_NEG_INF * 0.5)
-                p = jnp.where(dead, kmask.astype(jnp.float32), p)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_new = acc * corr + lax.dot_general(
-                p.astype(dtype), v, (((1,), (0,)), ((), ())),
-                precision=precision, preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+            ms_new, ls_new, corrs, pvs = [], [], [], []
+            for q, m, l in zip(qs, ms, ls):
+                # operands stay in the input dtype, the scale is applied to
+                # the f32 scores: bf16 products are exact in the MXU's f32
+                # accumulator, and f32 operands ask for full precision (the
+                # MXU's default would round them to bf16: 5e-3 off at seq
+                # 256)
+                s = lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32) * scale  # (BQ, BK)
+                s = jnp.where(mask, s, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if causal:
+                    # rows whose every key so far is masked (causal bound
+                    # < 0): the reference softmaxes a uniform -NEG_INF row,
+                    # i.e. uniform attention over the valid keys — exp(0)=1
+                    # here would instead spread over PADDED slots, so
+                    # substitute the valid mask as the weights (masks are
+                    # prefixes, so a row dead in this tile is dead in every
+                    # tile).  Without ``causal`` every visited tile holds a
+                    # valid key and no row is dead.
+                    dead = m_new <= (_NEG_INF * 0.5)
+                    p = jnp.where(dead, kmask.astype(jnp.float32), p)
+                corrs.append(jnp.exp(m - m_new))
+                ms_new.append(m_new)
+                ls_new.append(l * corrs[-1]
+                              + jnp.sum(p, axis=1, keepdims=True))
+                pvs.append(lax.dot_general(
+                    p.astype(dtype), v, (((1,), (0,)), ((), ())),
+                    precision=precision, preferred_element_type=jnp.float32))
+            return (tuple(ms_new), tuple(ls_new),
+                    acc * _each(corrs, dp) + _each(pvs, dp))
 
         n = jnp.clip(pl.cdiv(vl - k0, block_k), 0, tiles)
         return lax.fori_loop(0, n, tile, carry)
 
     def start():
-        return (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
-                jnp.zeros((block_q, 1), jnp.float32),
+        return ((jnp.full((block_q, 1), _NEG_INF, jnp.float32),) * hb,
+                (jnp.zeros((block_q, 1), jnp.float32),) * hb,
                 jnp.zeros((block_q, dp), jnp.float32))
 
-    def finish(o_ref, l, acc):
+    def finish(o_ref, ls, acc):
+        l = _each(ls, dp)
         # rows with no valid keys (padded queries) divide by 1 instead
         o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(dtype)
 
     def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, *carries):
-        b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
         # per-sequence valid key length (padding mask support): the tile
         # padding bound ``lk`` is static; vl tightens it per row
         vl = key_limit(jnp.minimum(vl_ref[b], lk), qi)
         if nkv == 1:
-            _, l, acc = sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start())
-            finish(o_ref, l, acc)
+            _, ls, acc = sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start())
+            finish(o_ref, ls, acc)
             return
-        m_ref, l_ref, acc_ref = carries
+        m_refs, l_refs, acc_ref = carries[:hb], carries[hb:-1], carries[-1]
+
+        def keep(ms, ls, acc):
+            for r, x in zip(m_refs + l_refs, ms + ls):
+                r[...] = x
+            acc_ref[...] = acc
 
         @pl.when(kj == 0)
         def _():
-            m_ref[...], l_ref[...], acc_ref[...] = start()
+            keep(*start())
 
         @pl.when(kj * kv_block < vl)
         def _():
-            m_ref[...], l_ref[...], acc_ref[...] = sweep(
-                vl, q_ref[0], k_ref, v_ref, qi, kj,
-                (m_ref[...], l_ref[...], acc_ref[...]))
+            keep(*sweep(vl, q_ref[0], k_ref, v_ref, qi, kj,
+                        (tuple(r[...] for r in m_refs),
+                         tuple(r[...] for r in l_refs), acc_ref[...])))
 
         @pl.when(kj == nkv - 1)
         def _():
-            finish(o_ref, l_ref[...], acc_ref[...])
+            finish(o_ref, tuple(r[...] for r in l_refs), acc_ref[...])
 
-    def kv_index(b, i, j, vl_ref):
+    def last_block(b, i, vl_ref):
         # a K-major block wholly beyond the row's length maps to the last
         # block that holds a valid key: the pipeline sees the same block
         # index again and issues no DMA for it
-        last = jnp.maximum(
+        return jnp.maximum(
             pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
             0)
-        return (b, jnp.minimum(j, last), 0)
 
     # Mosaic takes neither a rank-1 block of one element nor rank-1 loop
     # carries: the per-row length rides scalar memory, and the running
-    # max/denominator are (block_q, 1) columns.
-    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j, vl: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, kv_block, dp), kv_index)
-    scratch = [] if nkv == 1 else [
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, dp), jnp.float32)]
+    # max/denominator are (block_q, 1) columns.  A head (or a pair) is the
+    # lane block ``h`` of its operand, counted from the operand's first.
+    fq, fk, fv = first
+
+    def q_spec(at):
+        return pl.BlockSpec((1, block_q, dp),
+                            lambda b, h, i, j, vl: (b, i, at + h))
+
+    def kv_spec(at):
+        return pl.BlockSpec(
+            (1, kv_block, dp),
+            lambda b, h, i, j, vl: (b, jnp.minimum(j, last_block(b, i, vl)),
+                                    at + h))
+    scratch = [] if nkv == 1 else (
+        [pltpu.VMEM((block_q, 1), jnp.float32)] * (2 * hb)
+        + [pltpu.VMEM((block_q, dp), jnp.float32)])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, nq, nkv),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            grid=(bh, blocks, nq, nkv),
+            in_specs=[q_spec(fq), kv_spec(fk), kv_spec(fv)],
+            out_specs=q_spec(0),
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((bh, lqp, dp), dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, lqp, blocks * dp), dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",
     )
@@ -298,9 +414,14 @@ def _bwd_tiling(lq: int, lk: int, d: int, itemsize: int):
 
 @functools.lru_cache(maxsize=None)
 def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
-                    scale: float, dtype_name: str, interpret: bool):
+                    scale: float, dtype_name: str, interpret: bool,
+                    blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0)):
     """The backward's two kernels for one call's (unpadded) shape; they
-    take the operands padded as ``_bwd_tiling`` says.  Both compute their
+    take the operands padded as ``_bwd_tiling`` says, and lane blocks as
+    the forward does (``_build_call``: ``d`` lanes a block, ``blocks`` of
+    them from block ``first[i]`` of q, k and v on, ``lane_heads`` heads to
+    a block; the cotangent, dq, dk and dv hold the call's heads alone,
+    from block 0).  Both compute their
     (block_k, block_q) score blocks with the keys down the sublanes and
     the queries along the lanes: a row's statistics are then lane-dense
     (1, block_q) rows that broadcast and reduce down the sublanes (the
@@ -327,6 +448,13 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     bit, ``p = exp(s - m - log l)``, ``ds``, and adds ``p^T g`` to dv and
     ``ds^T q`` to dk.
 
+    Where a block holds two heads, a kernel sets the other head's lanes
+    to nought in the operand it holds still (``dq``: q and the cotangent;
+    ``dkv``: k and v), so that a product over all the lanes is one head's;
+    the products that come out all lanes wide (``a``, ``b`` down the
+    sublanes; dk, dv along the lanes) keep each head's own part, and a
+    query tile's (8, block_q) block of statistics holds three rows a head.
+
     Key tiles at or beyond a row's valid length, and under a causal mask
     with Lk >= Lq the tiles wholly above the diagonal, are neither visited
     nor fetched, as in the forward; a key tile wholly beyond the length
@@ -348,12 +476,15 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     mxu = jnp.bfloat16 if dtype == jnp.float32 else dtype
     off = lk - lq
     diagonal = causal and off >= 0          # as the forward's
+    hb = lane_heads
 
     reg = registry()
     reg.counter("kernels.flash_attention_bwd.builds",
                 "flash backward kernel pairs built (one per shape)").inc()
     for name, value in (("block_q", block_q), ("block_k", block_k),
-                        ("grid_steps", bh * (nq * nkv + nkb * nqb))):
+                        ("grid_steps",
+                         bh * blocks * (nq * nkv + nkb * nqb)),
+                        ("lane_heads", hb)):
         reg.gauge(f"kernels.flash_attention_bwd.{name}",
                   "tiling of the last flash backward kernels built"
                   ).set(value)
@@ -415,53 +546,70 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     # -- dq, and the statistics -------------------------------------------
     def dq_kernel(vl_ref, q_ref, g_ref, k_ref, v_ref, dq_ref, st_ref,
                   m_ref, l_ref, n_ref, c_ref, a_ref, b_ref):
-        b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
         k0 = kj * kv_block
         length = jnp.minimum(vl_ref[b], lk)
         # of this K-major block: the tiles that hold a key the query tile
         # can weigh
         tiles = jnp.clip(pl.cdiv(key_limit(length, qi) - k0, block_k), 0,
                          kv_block // block_k)
-        q, g = scaled(q_ref[0]), g_ref[0].astype(mxu)
+        qs = [scaled(_own(q_ref[0], h, hb)) for h in range(hb)]
+        gs = [_own(g_ref[0], h, hb).astype(mxu) for h in range(hb)]
         key, rel = positions()
 
         @pl.when(kj == 0)
         def _():
-            m_ref[...] = jnp.full((1, block_q), _NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((1, block_q), jnp.float32)
-            n_ref[...] = jnp.zeros((1, block_q), jnp.float32)
+            m_ref[...] = jnp.full((hb, block_q), _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((hb, block_q), jnp.float32)
+            n_ref[...] = jnp.zeros((hb, block_q), jnp.float32)
             a_ref[...] = jnp.zeros((dp, block_q), jnp.float32)
             b_ref[...] = jnp.zeros((dp, block_q), jnp.float32)
             # dp is counted from the row's dp at key 0, the one key every
             # live row sees: dq below is a difference of two sums, and
             # where a row's weight sits on few keys (its only one; a
             # first key that draws most of it) both are then small
-            c_ref[...] = nt(v_ref[0, :8, :].astype(mxu), g)[:1]
+            for h, g in enumerate(gs):
+                c_ref[h:h + 1, :] = nt(v_ref[0, :8, :].astype(mxu), g)[:1]
 
-        c = c_ref[...]
+        def rows(ref):
+            return tuple(ref[h:h + 1, :] for h in range(hb))
+
+        cs = rows(c_ref)
 
         def tile(t, carry):
-            m, l, n, a, b_ = carry
+            ms, ls, ns, a, b_ = carry
             start = pl.multiple_of(t * block_k, block_k)
             k = k_ref[0, pl.ds(start, block_k), :].astype(mxu)
             v = v_ref[0, pl.ds(start, block_k), :].astype(mxu)
-            s, kmask = mask(nt(k, q), key, rel, length, qi * block_q,
-                            k0 + start)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if dead_rows:
-                p = jnp.where(m_new <= _NEG_INF * 0.5,
-                              kmask.astype(jnp.float32), p)
-            corr = jnp.exp(m - m_new)
-            pdp = p * (nt(v, g) - c)
-            return (m_new, l * corr + jnp.sum(p, axis=0, keepdims=True),
-                    n * corr + jnp.sum(pdp, axis=0, keepdims=True),
-                    a * corr + tn(k, pdp), b_ * corr + tn(k, p))
+            ms_new, ls_new, ns_new, corrs, kas, kbs = [], [], [], [], [], []
+            for q, g, c, m, l, n in zip(qs, gs, cs, ms, ls, ns):
+                s, kmask = mask(nt(k, q), key, rel, length, qi * block_q,
+                                k0 + start)
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if dead_rows:
+                    p = jnp.where(m_new <= _NEG_INF * 0.5,
+                                  kmask.astype(jnp.float32), p)
+                corr = jnp.exp(m - m_new)
+                pdp = p * (nt(v, g) - c)
+                ms_new.append(m_new)
+                ls_new.append(l * corr + jnp.sum(p, axis=0, keepdims=True))
+                ns_new.append(n * corr + jnp.sum(pdp, axis=0, keepdims=True))
+                corrs.append(corr)
+                kas.append(tn(k, pdp))
+                kbs.append(tn(k, p))
+            corr = _each(corrs, dp, 0)
+            return (tuple(ms_new), tuple(ls_new), tuple(ns_new),
+                    a * corr + _each(kas, dp, 0),
+                    b_ * corr + _each(kbs, dp, 0))
 
-        refs = (m_ref, l_ref, n_ref, a_ref, b_ref)
-        for r, x in zip(refs, lax.fori_loop(
-                0, tiles, tile, tuple(r[...] for r in refs))):
-            r[...] = x
+        ms, ls, ns, a, b_ = lax.fori_loop(
+            0, tiles, tile, (rows(m_ref), rows(l_ref), rows(n_ref),
+                             a_ref[...], b_ref[...]))
+        for ref, xs in ((m_ref, ms), (l_ref, ls), (n_ref, ns)):
+            for h, x in enumerate(xs):
+                ref[h:h + 1, :] = x
+        a_ref[...], b_ref[...] = a, b_
 
         @pl.when(kj == nkv - 1)
         def _():
@@ -471,42 +619,60 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
             # each: m + log l (so p = exp(s - it)), delta, and for a dead
             # row 1 / l, its valid keys' even weight; a row with no valid
             # key divides by 1
-            m, l = m_ref[...], l_ref[...]
-            l = jnp.where(l == 0.0, 1.0, l)
-            delta = n_ref[...] / l
-            dq = (a_ref[...] - delta * b_ref[...]) * (scale / l)
+            # (three rows a head of the block, the last repeated below)
+            ms = rows(m_ref)
+            ls = [jnp.where(l == 0.0, 1.0, l) for l in rows(l_ref)]
+            deltas = [n / l for n, l in zip(rows(n_ref), ls)]
+            dq = (a_ref[...] - _each(deltas, dp, 0) * b_ref[...]) * (
+                scale / _each(ls, dp, 0))
             if dead_rows:
-                dq = jnp.where(m <= _NEG_INF * 0.5, 0.0, dq)
+                dq = jnp.where(_each(ms, dp, 0) <= _NEG_INF * 0.5, 0.0, dq)
             dq_ref[0] = dq.T.astype(dtype)
-            lse = jnp.where(m <= _NEG_INF * 0.5, _NEG_INF, m + jnp.log(l))
+            stats = []
+            for m, l, delta, c in zip(ms, ls, deltas, cs):
+                stats += [jnp.where(m <= _NEG_INF * 0.5, _NEG_INF,
+                                    m + jnp.log(l)), delta + c, 1.0 / l]
             row = lax.broadcasted_iota(jnp.int32, (8, block_q), 0)
-            st_ref[0, 0] = jnp.where(
-                row == 0, lse, jnp.where(row == 1, delta + c, 1.0 / l))
+            st = stats[-1]
+            for r in reversed(range(len(stats) - 1)):
+                st = jnp.where(row == r, stats[r], st)
+            st_ref[0, 0, 0] = st
 
-    def kv_index(b, i, j, vl_ref):
+    def last_block(b, i, vl_ref):
         # the sweep stops at the last K-major block that holds a key the
         # tile can weigh; a block beyond it maps to that one: no DMA
-        last = jnp.maximum(
+        return jnp.maximum(
             pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
             0)
-        return (b, jnp.minimum(j, last), 0)
 
-    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j, vl: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, kv_block, dp), kv_index)
-    stats = jax.ShapeDtypeStruct((bh, nq, 8, block_q), jnp.float32)
+    fq, fk, fv = first
+
+    def q_spec(at):
+        return pl.BlockSpec((1, block_q, dp),
+                            lambda b, h, i, j, vl: (b, i, at + h))
+
+    def kv_spec(at):
+        return pl.BlockSpec(
+            (1, kv_block, dp),
+            lambda b, h, i, j, vl: (b, jnp.minimum(j, last_block(b, i, vl)),
+                                    at + h))
+    stats = jax.ShapeDtypeStruct((bh, blocks, nq, 8, block_q), jnp.float32)
     dq_call = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, nq, nkv),
-            in_specs=[q_spec, q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, pl.BlockSpec(
-                (1, 1, 8, block_q), lambda b, i, j, vl: (b, i, 0, 0))],
-            scratch_shapes=[pltpu.VMEM((1, block_q), jnp.float32)] * 4
+            grid=(bh, blocks, nq, nkv),
+            in_specs=[q_spec(fq), q_spec(0), kv_spec(fk), kv_spec(fv)],
+            out_specs=[q_spec(0), pl.BlockSpec(
+                (1, 1, 1, 8, block_q),
+                lambda b, h, i, j, vl: (b, h, i, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((hb, block_q), jnp.float32)] * 4
             + [pltpu.VMEM((dp, block_q), jnp.float32)] * 2),
-        out_shape=[jax.ShapeDtypeStruct((bh, lqp, dp), dtype), stats],
+        out_shape=[jax.ShapeDtypeStruct((bh, lqp, blocks * dp), dtype),
+                   stats],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="flash_attention_bwd_dq",
     )
@@ -516,7 +682,7 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
 
     def dkv_kernel(vl_ref, k_ref, v_ref, q_ref, g_ref, st_ref, dk_ref,
                    dv_ref, dk_acc, dv_acc):
-        b, kb, qb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        b, kb, qb = pl.program_id(0), pl.program_id(2), pl.program_id(3)
         length = jnp.minimum(vl_ref[b], lk)
         k0, q0 = kb * key_block, qb * q_block
         key, rel = positions()
@@ -529,20 +695,27 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         def key_tile(t, _):
             start = pl.multiple_of(t * block_k, block_k)
             rows = pl.ds(start, block_k)
-            k = k_ref[0, rows, :].astype(mxu)
-            v = v_ref[0, rows, :].astype(mxu)
+            ks = [_own(k_ref[0, rows, :], h, hb).astype(mxu)
+                  for h in range(hb)]
+            vs = [_own(v_ref[0, rows, :], h, hb).astype(mxu)
+                  for h in range(hb)]
             kt = k0 + start
 
             def query_tile(u, carry):
                 dk, dv = carry
                 at = pl.ds(pl.multiple_of(u * block_q, block_q), block_q)
                 q, g = scaled(q_ref[0, at, :]), g_ref[0, at, :].astype(mxu)
-                st = st_ref[0, u]
-                s, kmask = mask(nt(k, q), key, rel, length,
-                                q0 + u * block_q, kt)
-                p, ds = weights(s, kmask, st[0:1], st[2:3], nt(v, g),
-                                st[1:2])
-                return dk + nn(ds, q), dv + nn(p, g)
+                st = st_ref[0, 0, u]
+                dks, dvs = [], []
+                for h, (k, v) in enumerate(zip(ks, vs)):
+                    s, kmask = mask(nt(k, q), key, rel, length,
+                                    q0 + u * block_q, kt)
+                    p, ds = weights(s, kmask, st[3 * h:3 * h + 1],
+                                    st[3 * h + 2:3 * h + 3], nt(v, g),
+                                    st[3 * h + 1:3 * h + 2])
+                    dks.append(nn(ds, q))
+                    dvs.append(nn(p, g))
+                return dk + _each(dks, dp), dv + _each(dvs, dp)
 
             # under the diagonal, the first query tile of this Q-major
             # block whose last row sees the tile's first key
@@ -575,55 +748,117 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         beyond = kb * key_block >= jnp.minimum(vl_ref[b], lk)
         return jnp.where(beyond, nqb - 1, jnp.maximum(qb, first))
 
-    kb_spec = pl.BlockSpec((1, key_block, dp),
-                           lambda b, kb, qb, vl: (b, kb, 0))
-    qb_spec = pl.BlockSpec(
-        (1, q_block, dp),
-        lambda b, kb, qb, vl: (b, q_index(b, kb, qb, vl), 0))
+    def kb_spec(at):
+        return pl.BlockSpec((1, key_block, dp),
+                            lambda b, h, kb, qb, vl: (b, kb, at + h))
+
+    def qb_spec(at):
+        return pl.BlockSpec(
+            (1, q_block, dp),
+            lambda b, h, kb, qb, vl: (b, q_index(b, kb, qb, vl), at + h))
     dkv_call = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, nkb, nqb),
-            in_specs=[kb_spec, kb_spec, qb_spec, qb_spec, pl.BlockSpec(
-                (1, q_tiles, 8, block_q),
-                lambda b, kb, qb, vl: (b, q_index(b, kb, qb, vl), 0, 0))],
-            out_specs=[kb_spec, kb_spec],
+            grid=(bh, blocks, nkb, nqb),
+            in_specs=[kb_spec(fk), kb_spec(fv), qb_spec(fq), qb_spec(0),
+                      pl.BlockSpec(
+                          (1, 1, q_tiles, 8, block_q),
+                          lambda b, h, kb, qb, vl: (
+                              b, h, q_index(b, kb, qb, vl), 0, 0))],
+            out_specs=[kb_spec(0), kb_spec(0)],
             scratch_shapes=[pltpu.VMEM((key_block, dp), jnp.float32)] * 2),
-        out_shape=[jax.ShapeDtypeStruct((bh, lkp, dp), dtype)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((bh, lkp, blocks * dp),
+                                        dtype)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
     )
     return dq_call, dkv_call
 
 
-def _run_backward(q, k, v, vl, g, causal: bool, scale: float,
-                  interpret: bool):
+class _Call(typing.NamedTuple):
+    """What a call's kernels are built from beside the operands' shapes
+    (static: the custom VJP's one non-differentiable argument)."""
+    causal: bool
+    scale: float
+    interpret: bool
+    heads: int          # heads of the call
+    d: int              # lanes of a head
+    src: tuple          # which of the call's arrays q, k and v are read from
+    first: tuple        # ... and the head of it that each starts at
+
+
+def _blocks(call: _Call, arrays):
+    """(lane blocks of the call, heads to a block, lanes of a block, each
+    operand's first block) by ``_lane_blocks``, which serves (the entry
+    has seen to it)."""
+    hb, lanes = _lane_blocks(call.heads, call.d, call.first,
+                             [arrays[i].shape[2] for i in call.src])
+    return (call.heads // hb, hb, lanes,
+            tuple(f * call.d // lanes for f in call.first))
+
+
+def _run_backward(arrays, vl, g, call: _Call):
     """(dq, dk, dv) of the attention the forward kernel computes, for the
-    cotangent ``g``, by the two kernels of ``_build_backward``."""
+    cotangent ``g``, by the two kernels of ``_build_backward``: each the
+    call's heads side by side, as ``g`` is."""
     import jax
     import jax.numpy as jnp
 
-    bh, lq, d = q.shape
-    lk = k.shape[1]
+    q, k, v = (arrays[i] for i in call.src)
+    bh, lq, lk = q.shape[0], q.shape[1], k.shape[1]
+    blocks, hb, lanes, first = _blocks(call, arrays)
     _, _, lqp, _, _, _, lkp, dp = _bwd_tiling(
-        lq, lk, d, jnp.result_type(q).itemsize)
+        lq, lk, lanes, jnp.result_type(q).itemsize)
 
     with jax.named_scope("flash_attention_pad"):
-        qp, gp, kp, vp = (_pad_to(x, rows, dp) for x, rows in (
+        qp, gp, kp, vp = (_pad_to(x, rows, dp - lanes) for x, rows in (
             (q, lqp), (g.astype(q.dtype), lqp), (k, lkp), (v, lkp)))
     dq_call, dkv_call = _build_backward(
-        bh, lq, lk, d, bool(causal), float(scale), jnp.result_type(q).name,
-        bool(interpret))
+        bh, lq, lk, lanes, call.causal, call.scale, jnp.result_type(q).name,
+        call.interpret, blocks, hb, first)
     lens = vl.astype(jnp.int32)
     dq, stats = dq_call(lens, qp, gp, kp, vp)
     dk, dv = dkv_call(lens, kp, vp, qp, gp, stats)
-    if dq.shape == q.shape and dk.shape == k.shape:
+    if dq.shape == g.shape and dk.shape[1] == lk:
         return dq, dk, dv
     with jax.named_scope("flash_attention_pad"):
-        return dq[:, :lq, :d], dk[:, :lk, :d], dv[:, :lk, :d]
+        width = g.shape[2]
+        return dq[:, :lq, :width], dk[:, :lk, :width], dv[:, :lk, :width]
+
+
+def _cotangents(arrays, call: _Call, grads):
+    """The cotangent of each of the call's arrays from (dq, dk, dv): an
+    array that holds one operand and no more takes its gradient as it is;
+    a fused projection takes its operands' gradients side by side, in one
+    concatenation (nought for lanes that no operand read); operands that
+    read the same lanes add up."""
+    import jax.numpy as jnp
+
+    out = []
+    for j, x in enumerate(arrays):
+        parts = sorted(((call.first[i] * call.d, grads[i])
+                        for i in range(3) if call.src[i] == j),
+                       key=lambda part: part[0])
+        width, pieces, at = x.shape[2], [], 0
+        if any(a + ga.shape[2] > b for (a, ga), (b, _) in
+               zip(parts, parts[1:])):
+            out.append(sum(jnp.pad(ga, ((0, 0), (0, 0),
+                                        (a, width - a - ga.shape[2])))
+                           for a, ga in parts))
+            continue
+        for a, ga in parts + [(width, None)]:
+            if a > at:
+                pieces.append(jnp.zeros(x.shape[:2] + (a - at,), x.dtype))
+            if ga is not None:
+                pieces.append(ga)
+                at = a + ga.shape[2]
+        out.append(pieces[0] if len(pieces) == 1
+                   else jnp.concatenate(pieces, axis=2))
+    return tuple(out)
 
 
 @functools.lru_cache(maxsize=1)
@@ -633,77 +868,104 @@ def _flash_core_fn():
     import jax
     from jax.ad_checkpoint import checkpoint_name
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-    def core(q, k, v, vl, causal, scale, interpret):
-        return _run_kernel(q, k, v, vl, causal, scale, interpret)
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+    def core(arrays, vl, call):
+        return _run_kernel(arrays, vl, call)
 
-    def core_fwd(q, k, v, vl, causal, scale, interpret):
+    def core_fwd(arrays, vl, call):
         # named here, before it is the primal: a name put on by the caller
         # would sit on another variable than the one a checkpoint's
         # policy is asked about.  The backward reads neither the output
         # nor any statistic of the forward: its kernels make their own.
-        out = checkpoint_name(
-            _run_kernel(q, k, v, vl, causal, scale, interpret), KEPT_OUTPUT)
-        return out, (q, k, v, vl)
+        out = checkpoint_name(_run_kernel(arrays, vl, call), KEPT_OUTPUT)
+        return out, (arrays, vl)
 
-    def core_bwd(causal, scale, interpret, res, g):
-        q, k, v, vl = res
+    def core_bwd(call, res, g):
+        arrays, vl = res
         import jax.numpy as jnp
         with jax.named_scope("flash_attention_bwd"):
-            dq, dk, dv = _run_backward(q, k, v, vl, g, causal, scale,
-                                       interpret)
-        # vl is a mask, not a weight
-        return dq, dk, dv, jnp.zeros_like(vl)
+            grads = _run_backward(arrays, vl, g, call)
+            # vl is a mask, not a weight
+            return _cotangents(arrays, call, grads), jnp.zeros_like(vl)
     core.defvjp(core_fwd, core_bwd)
     return core
 
 
-def _flash_core(q, k, v, vl, causal: bool, scale: float, interpret: bool):
-    return _flash_core_fn()(q, k, v, vl, causal, scale, interpret)
+def _flash_core(arrays, vl, call: _Call):
+    return _flash_core_fn()(arrays, vl, call)
 
 
-def _pad_to(x, rows: int, dp: int):
-    """``x`` padded to (rows, dp): only what the chosen tiles still need, a
-    ragged Lq/Lk and a head whose width a kernel does not take as it is."""
+def _pad_to(x, rows: int, lanes: int = 0):
+    """``x`` padded to ``rows`` rows and by ``lanes`` lanes: only what the
+    chosen tiles still need, a ragged Lq/Lk and a lone head whose width a
+    kernel does not take as it is."""
     import jax.numpy as jnp
-    if x.shape[1:] == (rows, dp):
+    if x.shape[1] == rows and not lanes:
         return x
-    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]),
-                       (0, dp - x.shape[2])))
+    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, lanes)))
 
 
-def _run_kernel(q, k, v, vl, causal: bool, scale: float, interpret: bool):
+def _run_kernel(arrays, vl, call: _Call):
     import jax
     import jax.numpy as jnp
 
-    bh, lq, d = q.shape
-    lk = k.shape[1]
-    _, lqp, _, _, lkp, dp = _tiling(lq, lk, d, jnp.result_type(q).itemsize)
+    q, k, v = (arrays[i] for i in call.src)
+    bh, lq, lk = q.shape[0], q.shape[1], k.shape[1]
+    blocks, hb, lanes, first = _blocks(call, arrays)
+    _, lqp, _, _, lkp, dp = _tiling(lq, lk, lanes,
+                                    jnp.result_type(q).itemsize)
 
     # the kernel's own pad and unpad carry a name of their own: their
     # device time is the kernel's to answer for
     with jax.named_scope("flash_attention_pad"):
-        qp, kp, vp = (_pad_to(x, rows, dp)
+        qp, kp, vp = (_pad_to(x, rows, dp - lanes)
                       for x, rows in ((q, lqp), (k, lkp), (v, lkp)))
-    call = _build_call(bh, lq, lk, d, bool(causal), float(scale),
-                       jnp.result_type(q).name, bool(interpret))
-    out = call(vl.astype(jnp.int32), qp, kp, vp)
-    if out.shape == q.shape:
+    kernel = _build_call(bh, lq, lk, lanes, call.causal, call.scale,
+                         jnp.result_type(q).name, call.interpret, blocks, hb,
+                         first)
+    out = kernel(vl.astype(jnp.int32), qp, kp, vp)
+    width = call.heads * call.d
+    if out.shape == (bh, lq, width):
         return out
     with jax.named_scope("flash_attention_pad"):
-        return out[:, :lq, :d]
+        return out[:, :lq, :width]
+
+
+def _heads_first(x, heads: int, d: int, first: int):
+    """(B, L, ..) tokens-major lanes [first head, first + heads) as
+    (B * heads, L, d)."""
+    b, rows = x.shape[:2]
+    x = x[:, :, first * d:(first + heads) * d].reshape(b, rows, heads, d)
+    return x.transpose(0, 2, 1, 3).reshape(b * heads, rows, d)
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
-                    interpret=None, valid_len=None):
+                    interpret=None, valid_len=None, num_heads=None,
+                    head_dim=None, first_head=(0, 0, 0)):
     """Tiled attention: softmax(scale·QKᵀ + mask)V without materializing
     the score matrix.
 
-    Accepts (B, H, L, D) or (BH, L, D); Lq/Lk/D are padded internally to
-    tile multiples (K padding is masked exactly, never approximated).
-    ``valid_len`` enables per-sequence key-padding masks — shape (B,) or
-    (B*H,); keys at positions >= valid_len[i] are masked exactly like the
-    additive -1e9 padding mask of the XLA path.
+    Heads-first (``num_heads`` None): (B, H, L, D) or (BH, L, D) operands;
+    Lq/Lk/D are padded internally to tile multiples (K padding is masked
+    exactly, never approximated).  ``valid_len`` enables per-sequence
+    key-padding masks — shape (B,) or (B*H,); keys at positions >=
+    valid_len[i] are masked exactly like the additive -1e9 padding mask of
+    the XLA path.
+
+    Tokens-major (``num_heads`` = H, because the shape cannot say it): q
+    is (B, Lq, ..) and k, v are (B, Lk, ..) as the projections leave them,
+    a head's ``head_dim`` lanes (default: q's width / H) beside the next
+    head's; the result is (B, Lq, H·head_dim) and ``valid_len`` is (B,).
+    ``first_head`` says at which head of its array each of q, k and v
+    starts, so a fused projection is read in place: pass the one
+    (B, L, 3·H·d) array three times with ``first_head=(0, H, 2 * H)`` and
+    its gradient comes back whole.  The kernels then take each head as a
+    lane block of the array (two heads of 64 to a block of 128), and no
+    transposed copy of an operand, of the result or of a gradient is
+    made.  Heads of another width, an odd count of 64-lane heads and
+    lengths a head are served too: by the heads-first form, through the
+    transposes this form spares.
+
     DIFFERENTIABLE: the forward runs the Pallas kernel, the backward two
     more (``dq`` and ``dkv``), at the precision XLA's default gives a
     matmul on the chip (float32 operands rounded to bfloat16 once,
@@ -713,31 +975,66 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     """
     import jax.numpy as jnp
 
-    squeeze4 = q.ndim == 4
-    if squeeze4:
-        b, h, lq, dd = q.shape
-        q = q.reshape(b * h, lq, dd)
-        k = k.reshape(b * h, k.shape[2], dd)
-        v = v.reshape(b * h, v.shape[2], dd)
-    bh, lq, d = q.shape
-    lk = k.shape[1]
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = _interpret(q)
+    if num_heads is None:
+        squeeze4 = q.ndim == 4
+        if squeeze4:
+            b, h, lq, dd = q.shape
+            q = q.reshape(b * h, lq, dd)
+            k = k.reshape(b * h, k.shape[2], dd)
+            v = v.reshape(b * h, v.shape[2], dd)
+        out = _attend((q, k, v), 1, q.shape[2], (0, 0, 0), causal, scale,
+                      interpret, valid_len)
+        return out.reshape(b, h, lq, dd) if squeeze4 else out
+
+    heads, first = int(num_heads), tuple(int(f) for f in first_head)
+    d = int(head_dim) if head_dim else q.shape[2] // heads
+    for x, f in zip((q, k, v), first):
+        if (f + heads) * d > x.shape[2]:
+            raise ValueError(
+                f"{heads} heads of {d} lanes from head {f} on do not fit "
+                f"an operand of {x.shape[2]} lanes")
+    b = q.shape[0]
+    rows = None if valid_len is None else jnp.asarray(valid_len).size
+    if rows in (None, b) and _lane_blocks(
+            heads, d, first, [x.shape[2] for x in (q, k, v)]):
+        return _attend((q, k, v), heads, d, first, causal, scale, interpret,
+                       valid_len)
+    out = _attend(tuple(_heads_first(x, heads, d, f)
+                        for x, f in zip((q, k, v), first)),
+                  1, d, (0, 0, 0), causal, scale, interpret, valid_len)
+    return out.reshape(b, heads, -1, d).transpose(0, 2, 1, 3).reshape(
+        b, -1, heads * d)
+
+
+def _attend(operands, heads: int, d: int, first, causal, scale, interpret,
+            valid_len):
+    """The kernels over (q, k, v) that ``_lane_blocks`` serves: ``heads``
+    heads of ``d`` lanes from head ``first[i]`` of operand i on (heads-first
+    operands: one head, the whole width)."""
+    import jax.numpy as jnp
+
+    rows, lk = operands[0].shape[0], operands[1].shape[1]
     if valid_len is None:
-        vl = jnp.full((bh,), lk, jnp.float32)
+        vl = jnp.full((rows,), lk, jnp.float32)
     else:
         vl = jnp.asarray(valid_len).reshape(-1).astype(jnp.float32)
-        if vl.shape[0] != bh:
-            if bh % vl.shape[0]:
+        if vl.shape[0] != rows:
+            if rows % vl.shape[0]:
                 raise ValueError(
                     f"valid_len length {vl.shape[0]} does not divide "
-                    f"batch*heads {bh}")
-            vl = jnp.repeat(vl, bh // vl.shape[0])
-
-    out = _flash_core(q, k, v, vl, bool(causal), float(scale),
-                      bool(interpret))
-    if squeeze4:
-        out = out.reshape(b, h, lq, d)
-    return out
+                    f"batch*heads {rows}")
+            vl = jnp.repeat(vl, rows // vl.shape[0])
+    # one array read in several places (a fused projection) is one
+    # operand of the differentiated call: its gradient is made whole
+    arrays, src = [], []
+    for x in operands:
+        at = next((i for i, y in enumerate(arrays) if y is x), len(arrays))
+        if at == len(arrays):
+            arrays.append(x)
+        src.append(at)
+    return _flash_core(tuple(arrays), vl, _Call(
+        bool(causal), float(d ** -0.5 if scale is None else scale),
+        bool(interpret),
+        heads, d, tuple(src), tuple(first)))

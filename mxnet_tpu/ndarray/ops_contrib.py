@@ -967,16 +967,36 @@ def _register_round3b():
     # materializes the (Lq, Lk) score matrix.  Eager dispatch
     # (use_jit=False) keeps the Mosaic-vs-interpret choice keyed on the
     # data's actual device.
-    def flash_attention_maker(causal=False, scale=None):
+    #
+    # Layouts.  Without ``num_heads`` the operands are heads-first,
+    # (B*H, L, D) or (B, H, L, D), and ``valid_len`` is (B,) or (B*H,).
+    # With ``num_heads=H`` they are tokens-major, (B, L, ..) as a
+    # projection leaves them, a head's ``head_dim`` lanes (default: q's
+    # width / H) beside the next head's; the result is (B, Lq, H*head_dim)
+    # and ``valid_len`` (B,).  ``first_head=(fq, fk, fv)`` is the head of
+    # its array at which q, k and v start: a fused projection is read in
+    # place by passing the one array three times with (0, H, 2*H) (inside
+    # a traced program its gradient then comes back as one array; on the
+    # imperative tape each of the three inputs gets its own).  Which
+    # kernels' form engages follows from the shapes alone
+    # (``kernels.flash_attention.lane_heads`` / ``.tokens_major`` say
+    # which): heads of a multiple of 128 lanes are read as lane blocks of
+    # the array, an even count of 64-lane heads two to a block, and no
+    # transposed copy is made; any other width or count goes through the
+    # heads-first form and its transposes.
+    def flash_attention_maker(causal=False, scale=None, num_heads=None,
+                              head_dim=None, first_head=(0, 0, 0)):
         from ..kernels import flash_attention as _fa
 
         def fn(q, k, v, valid_len=None):
             # optional 4th input: per-sequence key-padding lengths
             return _fa(q, k, v, causal=causal, scale=scale,
-                       valid_len=valid_len)
+                       valid_len=valid_len, num_heads=num_heads,
+                       head_dim=head_dim, first_head=first_head)
         return fn
 
-    def flash_attention_vjp_maker(causal=False, scale=None):
+    def flash_attention_vjp_maker(causal=False, scale=None, num_heads=None,
+                                  head_dim=None, first_head=(0, 0, 0)):
         # recording path: jax.vjp traces the op, so the Mosaic-vs-
         # interpret choice must be made HERE on the concrete arrays,
         # before tracing (the multi_sgd static-kwarg rule)
@@ -984,16 +1004,14 @@ def _register_round3b():
         from ..kernels.flash_attention import _interpret as _interp
 
         def wrapper(q, k, v, valid_len=None):
-            interp = _interp(q)
+            def attend(a, b, c):
+                return _fa(a, b, c, causal=causal, scale=scale,
+                           interpret=_interp(q), valid_len=valid_len,
+                           num_heads=num_heads, head_dim=head_dim,
+                           first_head=first_head)
             if valid_len is None:
-                return jax.vjp(
-                    lambda a, b, c: _fa(a, b, c, causal=causal,
-                                        scale=scale, interpret=interp),
-                    q, k, v)
-            out, vjp3 = jax.vjp(
-                lambda a, b, c: _fa(a, b, c, causal=causal, scale=scale,
-                                    interpret=interp, valid_len=valid_len),
-                q, k, v)
+                return jax.vjp(attend, q, k, v)
+            out, vjp3 = jax.vjp(attend, q, k, v)
 
             def vjp4(g):
                 # the tape sees 4 parents; valid_len is a mask, zero grad

@@ -14,6 +14,7 @@ xdist worker could not.  The topology is described inside a fixture, never
 at import.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -59,23 +60,31 @@ def _compiled_text(fn, *args):
 # 16, seq 512: K and V resident, the head at its own 64 lanes); keys too
 # long to stay resident (the K-major grid axis); one query against a cache.
 # ``pads``: only a ragged Lq or Lk is padded, never a head of 64.
-@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal,pads", [
-    ((96, 256, 64), None, "float32", False, False, False),
-    ((96, 256, 64), None, "float32", True, False, False),
-    ((96, 256, 64), None, "bfloat16", False, False, False),
-    ((96, 256, 64), None, "bfloat16", True, False, False),
-    ((2, 256, 128), None, "float32", False, True, False),
-    ((192, 512, 64), None, "float32", True, False, False),
-    ((192, 512, 64), None, "bfloat16", True, False, False),
-    ((8, 4096, 128), None, "float32", True, False, False),
-    ((8, 1, 128), 300, "float32", True, True, True),
+# ``heads``: the operands are tokens-major, (B, L, heads * d), as the three
+# cells' models hand them over: a head is a block of the lanes (two heads of
+# 64 to a block of 128), and no transposed copy is made beside the kernel.
+@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal,pads,heads", [
+    ((96, 256, 64), None, "float32", False, False, False, None),
+    ((96, 256, 64), None, "float32", True, False, False, None),
+    ((96, 256, 64), None, "bfloat16", False, False, False, None),
+    ((96, 256, 64), None, "bfloat16", True, False, False, None),
+    ((2, 256, 128), None, "float32", False, True, False, None),
+    ((192, 512, 64), None, "float32", True, False, False, None),
+    ((192, 512, 64), None, "bfloat16", True, False, False, None),
+    ((8, 4096, 128), None, "float32", True, False, False, None),
+    ((8, 1, 128), 300, "float32", True, True, True, None),
     # the MLA cell: 20 heads of 256 over 8192 keys, causal, K-major
-    ((20, 8192, 256), None, "float32", False, True, False),
+    ((20, 8192, 256), None, "float32", False, True, False, None),
     # the hybrid cell's attention block: 30 heads of 128 over 2048, causal
-    ((30, 2048, 128), None, "float32", False, True, False),
+    ((30, 2048, 128), None, "float32", False, True, False, None),
+    # the three cells' shapes as their models hand them over
+    ((16, 512, 768), None, "float32", True, False, False, 12),
+    ((16, 512, 768), None, "bfloat16", True, False, False, 12),
+    ((1, 8192, 5120), None, "float32", False, True, False, 20),
+    ((1, 2048, 3840), None, "float32", False, True, False, 30),
 ])
 def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
-                                          valid_len, causal, pads):
+                                          valid_len, causal, pads, heads):
     import jax
     from mxnet_tpu.kernels import flash_attention
 
@@ -89,11 +98,13 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
 
     def fwd(q, k, v, vl=None):
         return flash_attention(q, k, v, causal=causal, valid_len=vl,
-                               interpret=False)
+                               interpret=False, num_heads=heads)
 
     text = _compiled_text(fwd, *args)
     assert "tpu_custom_call" in text
     assert (" pad(" in text) == pads
+    if heads:
+        assert " transpose(" not in text and " copy(" not in text
 
 
 def _kernel_calls(text, name):
@@ -110,22 +121,30 @@ def _kernel_calls(text, name):
 # bfloat16; the MLA cell (20 heads of 256 over 8192 keys, causal: K-major
 # and Q-major blocks, the diagonal through them); a ragged causal Lk > Lq
 # and a causal Lk < Lq, whose first rows see no key
-@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal", [
-    ((192, 512, 64), None, "float32", True, False),
-    ((192, 512, 64), None, "bfloat16", True, False),
-    ((20, 8192, 256), None, "float32", False, True),
-    ((4, 200, 64), 640, "float32", True, True),
-    ((4, 640, 128), 200, "float32", True, True),
-    ((30, 2048, 128), None, "float32", False, True),
+@pytest.mark.parametrize("shape,lk,dtype,valid_len,causal,heads", [
+    ((192, 512, 64), None, "float32", True, False, None),
+    ((192, 512, 64), None, "bfloat16", True, False, None),
+    ((20, 8192, 256), None, "float32", False, True, None),
+    ((4, 200, 64), 640, "float32", True, True, None),
+    ((4, 640, 128), 200, "float32", True, True, None),
+    ((30, 2048, 128), None, "float32", False, True, None),
+    ((16, 512, 768), None, "float32", True, False, 12),
+    ((16, 512, 768), None, "bfloat16", True, False, 12),
+    ((1, 8192, 5120), None, "float32", False, True, 20),
+    ((1, 2048, 3840), None, "float32", False, True, 30),
 ], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k", "causal_lk_gt_lq",
-        "causal_lk_lt_lq_dead_rows", "hybrid_cell_2k"])
-def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
-                                                   dtype, valid_len, causal):
+        "causal_lk_lt_lq_dead_rows", "hybrid_cell_2k",
+        "bert_cell_tokens_major", "bert_cell_tokens_major_bfloat16",
+        "mla_cell_8k_tokens_major", "hybrid_cell_2k_tokens_major"])
+def test_flash_attention_backward_compiles_for_v5e(
+        one_chip, shape, lk, dtype, valid_len, causal, heads):
     """Forward and the backward's kernels compile for the chip and fit it:
     one Mosaic call each for the forward, ``dq`` and ``dkv``, and at the
     MLA cell's shape temporaries far under 2 GiB (a scanned backward would
     stack 10.8 GB of carries there; a blocked one made eight block-major
-    float32 copies, 1.3 GB)."""
+    float32 copies, 1.3 GB).  ``heads``: tokens-major operands, as the
+    cells' models hand them over (heads as blocks of the lanes, two of 64
+    to a block): no transposed copy beside the kernels."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import flash_attention
@@ -140,7 +159,7 @@ def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
 
     def loss(q, k, v, vl=None):
         out = flash_attention(q, k, v, causal=causal, valid_len=vl,
-                              interpret=False)
+                              interpret=False, num_heads=heads)
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
@@ -151,6 +170,58 @@ def test_flash_attention_backward_compiles_for_v5e(one_chip, shape, lk,
     assert _kernel_calls(text, "flash_attention_fwd") <= 1
     if shape[1] == 8192:
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+    if heads:
+        assert " transpose(" not in text and " copy(" not in text
+
+
+def test_fused_projection_is_read_in_place_for_v5e(one_chip):
+    """Projection, attention, projection at the BERT cell's shape from one
+    fused (768, 2304) weight, forward and gradient: the flash kernels read
+    q, k and v where the projection left them, as lane blocks of the
+    (16, 512, 2304) array (two heads of 64 to a block), and write the
+    result and dq, dk, dv tokens-major.  The compiled gradient holds one
+    Mosaic call of each of the three names and, of an operand the size of
+    an activation ((16, 512, 768) float32) or larger, no transpose, no copy
+    and no concatenate as an instruction of its own.  What is left beside
+    the kernels is the concatenation of dq, dk, dv into the fused
+    projection's gradient (``flash_attention_bwd/concatenate``), which XLA
+    folds into the rounding to bfloat16 that the projection's backward
+    matmuls ask for anyway: three ``dynamic-update-slice`` fusions that
+    write one bfloat16 (16, 512, 2304) array in place."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+
+    b, seq, heads, d = 16, 512, 12, 64
+    units = heads * d
+
+    def operand(*shape):
+        return jax.ShapeDtypeStruct(shape, "float32", sharding=one_chip)
+
+    def loss(x, w_qkv, w_out, vl):
+        qkv = x @ w_qkv
+        out = flash_attention(qkv, qkv, qkv, valid_len=vl, interpret=False,
+                              num_heads=heads, head_dim=d,
+                              first_head=(0, heads, 2 * heads))
+        return jnp.sum((out @ w_out) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          operand(b, seq, units), operand(units, 3 * units),
+                          operand(units, units), operand(b))
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert _kernel_calls(text, name) == 1, name
+    activation = b * seq * units
+    for line in text.splitlines():
+        found = re.search(
+            r" = \w+\[([\d,]+)\]\S* (transpose|copy|concatenate)\(", line)
+        assert not found or np.prod(
+            [int(n) for n in found.group(1).split(",")]) < activation, line
+    joined = [line for line in text.splitlines()
+              if re.search(r"flash_attention_bwd\)*/concatenate", line)]
+    assert joined and all("dynamic-update-slice" in line and
+                          f"bf16[{b},{seq},{3 * units}]" in line
+                          for line in joined)
 
 
 def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
@@ -199,10 +270,11 @@ def test_checkpointed_attention_block_runs_the_kernel_once_for_v5e(
                                  sharding=one_chip)
 
     def block(x, w_in, w_out):
-        q, k, v = jnp.moveaxis(
-            (x @ w_in).reshape(seq, 3, heads, d), (1, 2), (0, 1))
-        out = flash_attention(q, k, v, causal=True, interpret=False)
-        return jnp.moveaxis(out, 0, 1).reshape(seq, heads * d) @ w_out
+        qkv = (x @ w_in)[None]
+        out = flash_attention(qkv, qkv, qkv, causal=True, interpret=False,
+                              num_heads=heads, head_dim=d,
+                              first_head=(0, heads, 2 * heads))
+        return out[0] @ w_out
 
     def loss(x, w_in, w_out):
         policy = _keep_named() if keeps else None

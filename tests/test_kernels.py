@@ -14,7 +14,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon, nd
-from tests._jaxpr import pallas_call_names
+from tests._jaxpr import pallas_call_names, primitives_outside_kernels
 
 
 @pytest.fixture(autouse=True)
@@ -450,6 +450,132 @@ def test_flash_attention_valid_len_matches_masked_softmax():
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(
         jnp.array(q), jnp.array(k), jnp.array(v))
     _assert_grads_close(gf, gr)
+
+
+# heads as blocks of a tokens-major array's lanes: the BERT cell's form (an
+# even count of 64-lane heads, two to a 128-lane block, with lengths, one of
+# them nought) from separate projections and read in place from one fused
+# array, bfloat16, causal pairs, an odd count of 64-lane heads (no pair:
+# the entry makes the heads-first form itself, through transposes), heads
+# of one and of two lane groups, ragged Lk > Lq, and Lk < Lq whose first
+# rows see no key.  ``lanes``: (heads a block holds, operands tokens-major)
+# as the gauges read after the call.
+@pytest.mark.parametrize(
+    "heads,d,lq,lk,causal,lens,dtype,fused,lanes", [
+        (12, 64, 128, 128, False, [128, 100], "float32", False, (2, 1)),
+        (4, 64, 200, 200, True, None, "float32", False, (2, 1)),
+        (3, 64, 128, 128, False, [77, 128], "float32", False, (1, 0)),
+        (2, 128, 130, 300, True, None, "float32", False, (1, 1)),
+        (2, 256, 256, 256, True, None, "float32", False, (1, 1)),
+        (2, 64, 300, 200, True, None, "float32", False, (2, 1)),
+        (4, 64, 128, 128, False, [0, 128], "float32", False, (2, 1)),
+        (4, 64, 128, 128, False, [128, 77], "bfloat16", False, (2, 1)),
+        (4, 64, 128, 128, False, [128, 77], "float32", True, (2, 1)),
+        (2, 128, 128, 128, True, None, "float32", True, (1, 1)),
+    ], ids=["pairs_of_64_lengths", "pairs_of_64_causal", "odd_heads_of_64",
+            "heads_of_128_causal_lk_gt_lq", "heads_of_256_causal",
+            "pairs_of_64_causal_lk_lt_lq_dead_rows", "a_row_of_length_0",
+            "pairs_of_64_bfloat16", "fused_qkv_read_in_place",
+            "fused_qkv_heads_of_128_causal"])
+def test_flash_attention_tokens_major_matches_heads_first(
+        heads, d, lq, lk, causal, lens, dtype, fused, lanes):
+    """Forward and gradients of the tokens-major call, (B, L, H*d) operands
+    with the head count given, against the heads-first call on the
+    transposed operands: the same kernels with another index map, so the
+    same numbers (to the tolerance the gradients are held to against the
+    full softmax).  A fused (B, L, 3*H*d) projection passed three times
+    with ``first_head=(0, H, 2*H)`` is read where it lies and its gradient
+    comes back as the one array."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
+
+    b = 2
+    rs = np.random.RandomState(13)
+    q, k, v, g = (jnp.asarray(rs.randn(b, n, heads * d).astype(np.float32))
+                  .astype(dtype) for n in (lq, lk, lk, lq))
+    vl = None if lens is None else jnp.asarray(lens, jnp.float32)
+
+    def heads_first(t):
+        return t.reshape(b, -1, heads, d).transpose(0, 2, 1, 3)
+
+    def reference(q, k, v):
+        out = flash_attention(heads_first(q), heads_first(k), heads_first(v),
+                              causal=causal, valid_len=vl)
+        return out.transpose(0, 2, 1, 3).reshape(b, lq, heads * d)
+
+    if fused:
+        operands = (jnp.concatenate([q, k, v], axis=-1),)
+
+        def lanes_call(x):
+            return flash_attention(
+                x, x, x, causal=causal, valid_len=vl, num_heads=heads,
+                head_dim=d, first_head=(0, heads, 2 * heads))
+        want_out, want_vjp = jax.vjp(
+            lambda x: reference(*jnp.split(x, 3, axis=-1)), *operands)
+    else:
+        operands = (q, k, v)
+
+        def lanes_call(q, k, v):
+            return flash_attention(q, k, v, causal=causal, valid_len=vl,
+                                   num_heads=heads)
+        want_out, want_vjp = jax.vjp(reference, q, k, v)
+    want = want_vjp(g)
+
+    got_out, got_vjp = jax.vjp(lanes_call, *operands)
+    got = got_vjp(g)
+    reg = registry()
+    assert (reg.get("kernels.flash_attention.lane_heads").read(),
+            reg.get("kernels.flash_attention.tokens_major").read(),
+            reg.get("kernels.flash_attention_bwd.lane_heads").read()) == (
+                lanes[0], lanes[1], lanes[0])
+    assert got_out.shape == (b, lq, heads * d) and got_out.dtype == q.dtype
+    assert [x.shape for x in got] == [x.shape for x in operands]
+    out_tol = 3e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got_out, np.float32),
+                               np.asarray(want_out, np.float32),
+                               atol=out_tol, rtol=0)
+    _assert_grads_close(got, want)
+    if lens is not None and 0 in lens:
+        row = lens.index(0)
+        assert not np.asarray(got_out)[row].any()   # no valid key: 0 / 1
+        assert not np.asarray(got[1])[row].any()    # ... and dk, dv 0
+        assert not np.asarray(got[2])[row].any()
+    if causal and lk < lq:
+        assert not np.asarray(got[0])[:, :lq - lk].any()   # dead rows: dq 0
+    # the three kernels once each, under their names, and for lane blocks
+    # no transposed copy of an operand beside them
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(lanes_call, *a)[1](g))(
+        *operands)
+    assert sorted(pallas_call_names(jaxpr.jaxpr)) == [
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+        "flash_attention_fwd"]
+    assert ("transpose" in primitives_outside_kernels(jaxpr.jaxpr)) == (
+        not lanes[1])
+
+
+def test_flash_attention_heads_first_call_reads_one_head_a_block():
+    """The heads-first entry is the case of one head that is the whole
+    array: the gauges read 1 / 0 / 1 after it, and heads that do not fit
+    their array are refused."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
+
+    fa = _flash_module()
+    fa._build_call.cache_clear()
+    fa._build_backward.cache_clear()
+    x = jnp.ones((4, 128, 64), jnp.float32)
+    jax.grad(lambda a: jnp.sum(flash_attention(a, a, a)))(x)
+    reg = registry()
+    assert (reg.get("kernels.flash_attention.lane_heads").read(),
+            reg.get("kernels.flash_attention.tokens_major").read(),
+            reg.get("kernels.flash_attention_bwd.lane_heads").read()) == (
+                1, 0, 1)
+    with pytest.raises(ValueError, match="do not fit"):
+        flash_attention(x, x, x, num_heads=2, head_dim=64)
 
 
 def _flash_module():

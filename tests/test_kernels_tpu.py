@@ -209,3 +209,34 @@ def test_flash_attention_backward_kernels_match_on_chip(tpu, shape, causal,
     assert np.sqrt((bias ** 2).mean()) <= 1e-2 * np.sqrt(
         (dk ** 2).sum(axis=1).mean())
 
+
+
+# the three cells' attention as their models hand it over: (B, L, H * d)
+# operands, a head a block of the lanes (two of BERT's 64-lane heads to a
+# block of 128), against the heads-first call on the transposed operands
+@pytest.mark.parametrize("b,seq,heads,d,dtype,causal", [
+    (16, 512, 12, 64, "float32", False),
+    (16, 512, 12, 64, "bfloat16", False),
+    (1, 8192, 20, 256, "float32", True),
+    (1, 2048, 30, 128, "float32", True),
+], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k", "hybrid_cell_2k"])
+def test_flash_attention_tokens_major_matches_heads_first_on_chip(
+        tpu, b, seq, heads, d, dtype, causal):
+    """Forward and gradients of the tokens-major call equal the heads-first
+    call's on the chip (the same kernels with another index map: float32
+    forward to 3e-5, gradients to the tolerance they are held to against
+    the full softmax), for separate q, k, v and for one fused
+    (B, L, 3 * H * d) array read in place; and the gauges say which form
+    engaged."""
+    from chip_smoke import tokens_major_against_heads_first
+
+    read = tokens_major_against_heads_first(
+        np.random.default_rng(4), b, seq, heads, d, causal, dtype)
+    lane_heads = max(128 // d, 1)
+    gauges = read["gauges"]
+    assert (gauges["lane_heads"], gauges["tokens_major"],
+            gauges["bwd_lane_heads"]) == (lane_heads, 1, lane_heads)
+    assert "tpu_custom_call" in read["text"]
+    assert read["out_err"] <= (3e-5 if dtype == "float32" else 2e-2)
+    assert read["grad_max_rel"] <= 2e-2
+    assert read["grad_vector_rel"] <= 1e-2
