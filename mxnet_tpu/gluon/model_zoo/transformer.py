@@ -34,7 +34,8 @@ __all__ = ["SlidingWindowSelfAttention", "LongformerEncoderCell",
            "CausalLMCell", "CausalLM", "causal_lm_small",
            "MLAttention", "MLADecoderCell", "MTPModule", "MLAMoELM",
            "GatedDeltaNet", "QKNormAttention", "HybridDecoderCell",
-           "OlmoHybridLM", "TP_RULES"]
+           "OlmoHybridLM", "GroupedQueryAttention", "WindowMoEDecoderCell",
+           "WindowMoELM", "TP_RULES"]
 
 #: megatron-style tensor-parallel PartitionSpecs for this family — pass to
 #: parallel.ShardingRules(TP_RULES)
@@ -919,37 +920,52 @@ def causal_lm_small(vocab_size=257, **kwargs):
 # pre-norm causal decoder of the DeepSeek-V3 / GLM-4.x line
 # ---------------------------------------------------------------------------
 
-def _causal_attention(F, q, k, v, scale, heads=None, kernel_serves=True):
+def _causal_attention(F, q, k, v, scale, heads=None, kernel_serves=True,
+                      kv_heads=None, window=None):
     """Causal softmax attention for training: the flash kernel where the
     selector takes it and its shapes serve, else the full softmax through
     XLA.  The operands are heads-first, (B*H, S, D), or with ``heads`` given
     tokens-major, (B, S, heads * D) as a projection leaves them: the kernel
     then reads a head as a block of the lanes, and only the XLA path
-    splits the heads off."""
+    splits the heads off.  ``kv_heads`` (tokens-major): k and v hold that
+    many heads, each read by ``heads / kv_heads`` query heads in a row;
+    ``window``: a query weighs its own key and the ``window - 1`` before
+    it.  Both paths take both, the XLA path by a mask and a reshape to
+    (B * kv_heads, group * S, D), so neither repeats a key head."""
     import jax
     if kernel_serves and _flash_eligible(F, None, None, None):
         return F.flash_attention(q, k, v, causal=True, scale=scale,
-                                 num_heads=heads)
+                                 num_heads=heads, num_kv_heads=kv_heads,
+                                 window=window)
     b, s = q.shape[0], q.shape[1]
+    n = heads if kv_heads is None else kv_heads
+    group = 1 if heads is None else heads // n
 
-    def heads_first(t):
+    def heads_first(t, per_key_head):
+        # (b, s, n * g * d) -> (b * n, g * s, d): the g query heads of a
+        # group one after the other down the rows of their key head
         if heads is None:
             return t
-        return F.reshape(F.transpose(F.reshape(t, shape=(b, s, heads, -1)),
-                                     axes=(0, 2, 1, 3)),
-                         shape=(b * heads, s, -1))
+        t = F.reshape(t, shape=(b, s, n, per_key_head, -1))
+        return F.reshape(F.transpose(t, axes=(0, 2, 3, 1, 4)),
+                         shape=(b * n, per_key_head * s, -1))
     with jax.named_scope("attention_xla"):
-        q, k, v = heads_first(q), heads_first(k), heads_first(v)
-        keep = F.reshape(
-            F.arange(s).reshape((1, s)) <= F.arange(s).reshape((s, 1)),
-            shape=(1, s, s))
+        q, k, v = heads_first(q, group), heads_first(k, 1), heads_first(v, 1)
+        at, key = F.arange(s).reshape((s, 1)), F.arange(s).reshape((1, s))
+        keep = key <= at
+        if window is not None:
+            keep = keep * (at - key < window)
+        if group > 1:
+            keep = F.tile(keep, reps=(group, 1))
+        keep = F.reshape(keep, shape=(1, group * s, s))
         scores = F.batch_dot(q, k, transpose_b=True) * scale
         out = F.batch_dot(_masked_softmax(
             F, scores, F.broadcast_to(keep, shape=scores.shape)), v)
     if heads is None:
         return out
-    return F.reshape(F.transpose(F.reshape(out, shape=(b, heads, s, -1)),
-                                 axes=(0, 2, 1, 3)), shape=(b, s, -1))
+    return F.reshape(F.transpose(
+        F.reshape(out, shape=(b, n, group, s, -1)), axes=(0, 3, 1, 2, 4)),
+        shape=(b, s, -1))
 
 
 class MLAttention(HybridBlock):
@@ -1330,6 +1346,144 @@ class OlmoHybridLM(HybridBlock):
                                       prefix="final_norm_")
             self.head = Dense(vocab_size, use_bias=False, flatten=False,
                               in_units=units, prefix="head_")
+
+    @property
+    def remat_blocks(self):
+        return list(self.cells)
+
+    def hybrid_forward(self, F, tokens):
+        x = self.embed(tokens)
+        for cell in self.cells:
+            x = cell(x)
+        return self.head(self.final_norm(x))
+
+
+# ---------------------------------------------------------------------------
+# Window and full attention over grouped-query heads, a router that stands
+# before attention: the pre-norm sparse-expert decoder of the SmallThinker
+# line (three window blocks with rotary positions to one full block with
+# none)
+# ---------------------------------------------------------------------------
+
+class GroupedQueryAttention(HybridBlock):
+    """Causal attention whose ``num_heads`` query heads of ``head_dim``
+    lanes read ``num_kv_heads`` key and value heads, head h the key head
+    ``h // (num_heads / num_kv_heads)``; no bias; for training (no cache).
+    ``window``: a query weighs its own key and the ``window - 1`` before
+    it (None: every key before it).  ``rope_theta``: rotate-half rotary
+    positions over a head's whole width, from 0 (None: no position).  q, k
+    and v are separate projections, so the flash kernel reads each where
+    it lies and a group's key head in place."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 window=None, rope_theta=None, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % num_kv_heads:
+            raise MXNetError(f"{num_heads} query heads do not divide into "
+                             f"groups over {num_kv_heads} key heads")
+        self._heads, self._kv, self._d = num_heads, num_kv_heads, head_dim
+        self._window, self._theta = window, rope_theta
+
+        def lin(out, inp, name):
+            return Dense(out, use_bias=False, flatten=False, in_units=inp,
+                         prefix=name)
+        with self.name_scope():
+            self.q = lin(num_heads * head_dim, units, "q_")
+            self.k = lin(num_kv_heads * head_dim, units, "k_")
+            self.v = lin(num_kv_heads * head_dim, units, "v_")
+            self.proj = lin(units, num_heads * head_dim, "proj_")
+
+    def _rope(self, F, t, heads):
+        b, s = t.shape[0], t.shape[1]
+        return F.reshape(F.rope(F.reshape(t, shape=(b, s, heads, self._d)),
+                                base=self._theta, seq_axis=1),
+                         shape=(b, s, heads * self._d))
+
+    def hybrid_forward(self, F, x):
+        import jax
+        q, k, v = self.q(x), self.k(x), self.v(x)
+        if self._theta is not None:
+            with jax.named_scope("rope"):
+                q, k = self._rope(F, q, self._heads), \
+                    self._rope(F, k, self._kv)
+        # the kernel reads a group's key head in place where a head is a
+        # whole lane group; another width goes through XLA
+        return self.proj(_causal_attention(
+            F, q, k, v, 1.0 / math.sqrt(self._d), heads=self._heads,
+            kernel_serves=self._heads == self._kv or self._d % 128 == 0,
+            kv_heads=self._kv, window=self._window))
+
+
+class WindowMoEDecoderCell(HybridBlock):
+    """Pre-norm block whose router stands before attention: the gates are
+    made from the block's input ``x`` itself (before any norm), then ``h =
+    x + Attention(RMSNorm(x))`` and ``h + Experts(RMSNorm(h))`` with those
+    gates; ``attention`` and ``moe`` build the two."""
+
+    def __init__(self, units, attention, moe, epsilon=1e-6, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.attn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                     prefix="attn_norm_")
+            self.attn = attention(prefix="attn_")
+            self.ffn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                    prefix="ffn_norm_")
+            self.moe = moe(prefix="moe_")
+
+    def hybrid_forward(self, F, x):
+        h = x + self.attn(self.attn_norm(x))
+        return h + self.moe(self.ffn_norm(h), x)
+
+
+class WindowMoELM(HybridBlock):
+    """Causal language model of ``WindowMoEDecoderCell`` blocks, one a
+    entry of the two lists: block l attends within ``window`` keys where
+    ``sliding_window_layout[l]`` is 1 (else over every key before it) and
+    carries rotary positions where ``rope_layout[l]`` is 1 (else none).
+    Every block's feed-forward is a ``parallel.moe.SparseMoE`` with a
+    softmax top-k router that reads the block's input, ReLU-gated experts
+    of ``moe_hidden_size``, ``experts_held`` of the ``num_experts`` (one
+    chip's share) and no shared expert; a final RMSNorm and an untied head.
+    ``net(tokens)`` returns the logits (B, S, vocab); ``remat_blocks`` lists
+    the blocks a trainer rematerialises."""
+
+    def __init__(self, vocab_size, units, num_heads, num_kv_heads, head_dim,
+                 moe_hidden_size, num_experts, top_k, experts_held,
+                 sliding_window_layout, rope_layout, window, rope_theta,
+                 epsilon=1e-6, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        import functools
+        from ...observability.registry import registry
+        from ...parallel.moe import SparseMoE
+        if len(sliding_window_layout) != len(rope_layout):
+            raise MXNetError(
+                f"sliding_window_layout names {len(sliding_window_layout)} "
+                f"blocks and rope_layout {len(rope_layout)}")
+        moe = functools.partial(
+            SparseMoE, units, moe_hidden_size, num_experts, top_k,
+            experts_held=experts_held, score="softmax", activation="relu")
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, units, prefix="embed_")
+            self.cells = HybridSequential(prefix="")
+            for i, (windowed, rotary) in enumerate(zip(sliding_window_layout,
+                                                       rope_layout)):
+                self.cells.add(WindowMoEDecoderCell(
+                    units, functools.partial(
+                        GroupedQueryAttention, units, num_heads, num_kv_heads,
+                        head_dim, window=window if windowed else None,
+                        rope_theta=rope_theta if rotary else None),
+                    moe, epsilon=epsilon, prefix=f"layer{i}_"))
+            self.final_norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                      prefix="final_norm_")
+            self.head = Dense(vocab_size, use_bias=False, flatten=False,
+                              in_units=units, prefix="head_")
+        windowed = sum(1 for w in sliding_window_layout if w)
+        for name, value in (("window_layers", windowed),
+                            ("full_layers",
+                             len(sliding_window_layout) - windowed)):
+            registry().gauge(f"lm.{name}", "blocks of the last window / "
+                             "full attention model built").set(value)
 
     @property
     def remat_blocks(self):

@@ -63,6 +63,37 @@ are neither visited nor fetched.
 Only a ragged Lq/Lk is padded, to the tile, and a lone head to a width
 the MXU contracts over.
 
+The sweep's two bounds.  ``window`` (keys; it goes with the causal mask and
+follows from the model's layer, never from a switch a user sets): key j
+weighs on the query at key position i where ``j <= i`` and ``i - j <
+window``.  The sweep of query tile ``qi`` then also has a bound from BELOW:
+it starts at the key tile that holds the first key its first row reaches
+(``key_start``) and ends at ``key_limit``; a K-major block wholly behind the
+window is neither computed nor fetched (its index map is clamped from below
+as ``last_block`` clamps it from above); the masks inside the boundary
+tiles are exact.  ``dq`` skips what the forward skips; in ``dkv`` a key
+tile's query sweep ends at the last query tile that holds a query less than
+``window`` beyond the tile's last key, and Q-major blocks beyond it are not
+fetched.  Kernels built with a window carry the names
+``flash_attention_fwd_window``, ``flash_attention_bwd_dq_window`` and
+``flash_attention_bwd_dkv_window`` (a device trace then tells a window
+layer's calls from a full layer's, and whoever reads ``flash_attention_fwd``
+/ ``_bwd`` counts both); one built without keeps today's names and code.
+
+The grouped index map.  With ``num_kv_heads`` < ``num_heads`` (tokens-major,
+heads of whole lane groups: another width is refused) the q, output and dq
+blocks are at head ``h`` and the k and v blocks at head ``first + h //
+group``: the forward and ``dq`` read a group's key head in place, once a
+query head, and ``dkv`` adds the group's query heads up inside the kernel
+(a sequential grid axis over the group before the Q-major one, accumulating
+in the one scratch), so dk and dv leave it a key head wide and no copy a
+query head wide of k, v, dk or dv exists.  Gauges ``kernels.flash_attention.window`` (keys; 0
+without) and ``.kv_group`` (query heads a key head serves) for the kernels
+last built, ``.key_tiles`` and ``.key_tiles_causal`` (the key tiles one
+head's query tiles visit, all rows full, and what the diagonal alone would
+leave them) for the last built WITH a window, and the same four under
+``kernels.flash_attention_bwd.``.
+
 The backward is two Pallas kernels (``_build_backward``; tiles from
 ``_bwd_tiling``, by the same rules and the same budget): ``dq``, which
 also makes the rows' statistics from the scores it computes itself, and
@@ -193,17 +224,37 @@ def _each(parts, width: int, axis: int = 1):
     return whole
 
 
+def _key_tiles(lq: int, lk: int, block_q: int, block_k: int,
+               diagonal: bool, window: int):
+    """(("key_tiles", n), ("key_tiles_causal", n)) of a build with a
+    window: the key tiles that the sweeps of one head's query tiles visit,
+    all rows full, and what the diagonal alone would leave them.  A build
+    without a window publishes neither, so the two gauges are the last
+    window build's whatever was built after it."""
+    if not window:
+        return ()
+    off, visited, causal_only = lk - lq, 0, 0
+    for q0 in range(0, _round_up(lq, block_q), block_q):
+        end = -(-min(lk, q0 + block_q + off if diagonal else lk) // block_k)
+        causal_only += end
+        visited += end - max(q0 + off - window + 1, 0) // block_k
+    return ("key_tiles", visited), ("key_tiles_causal", causal_only)
+
+
 @functools.lru_cache(maxsize=None)
 def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                 scale: float, dtype_name: str, interpret: bool,
-                blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0)):
+                blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0),
+                window: int = 0, group: int = 1):
     """The kernel for one call's (unpadded) shape; it takes the operands
     padded as ``_tiling`` says.  ``d`` is the width of a lane block: of
     every operand's last axis the grid's second axis owns ``blocks`` of
     them in turn, from block ``first[i]`` of operand i on, and a block
     holds ``lane_heads`` heads side by side (heads-first operands are one
-    block, the whole last axis).  Each build says which tiling engaged in
-    the ``kernels.flash_attention.*`` gauges."""
+    block, the whole last axis).  ``window`` keys (0: none) bound the sweep
+    from below; ``group`` query blocks in a row read one block of k and of
+    v.  Each build says which tiling engaged in the
+    ``kernels.flash_attention.*`` gauges."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -219,6 +270,12 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
     precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
     hb = lane_heads
 
+    # under a causal mask whose every row sees a key (Lk >= Lq) the keys
+    # beyond a query tile's last row weigh nothing for the whole tile, so
+    # they bound the sweep as the valid length does; with Lk < Lq some rows
+    # see no key and take the dead-row rule below, which reads every tile
+    diagonal = causal and lk >= lq
+
     reg = registry()
     reg.counter("kernels.flash_attention.builds",
                 "flash forward kernels built (one per shape)").inc()
@@ -226,21 +283,23 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                         ("kv_resident", int(nkv == 1)),
                         ("grid_steps", bh * blocks * nq * nkv),
                         ("lane_heads", hb),
-                        ("tokens_major", int(blocks * hb > 1))):
+                        ("tokens_major", int(blocks * hb > 1)),
+                        ("window", window), ("kv_group", group),
+                        *_key_tiles(lq, lk, block_q, block_k, diagonal,
+                                    window)):
         reg.gauge(f"kernels.flash_attention.{name}",
                   "tiling of the last flash forward kernel built").set(value)
-
-    # under a causal mask whose every row sees a key (Lk >= Lq) the keys
-    # beyond a query tile's last row weigh nothing for the whole tile, so
-    # they bound the sweep as the valid length does; with Lk < Lq some rows
-    # see no key and take the dead-row rule below, which reads every tile
-    diagonal = causal and lk >= lq
 
     def key_limit(vl, qi):
         """Keys that query tile ``qi`` of a row of length ``vl`` can weigh."""
         if not diagonal:
             return vl
         return jnp.minimum(vl, (qi + 1) * block_q + (lk - lq))
+
+    def key_start(qi):
+        """The first key that query tile ``qi`` can weigh under the window:
+        its first row's, which reaches furthest back."""
+        return jnp.maximum(qi * block_q + (lk - lq) - window + 1, 0)
 
     def sweep(vl, q, k_ref, v_ref, qi, kj, carry):
         """Online softmax of one query tile over the key tiles of K-major
@@ -272,6 +331,8 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                 q_idx = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 mask = mask & (k_idx <= q_idx + (lk - lq))
+                if window:
+                    mask = mask & (k_idx > q_idx + (lk - lq) - window)
             ms_new, ls_new, corrs, pvs = [], [], [], []
             for q, m, l in zip(qs, ms, ls):
                 # operands stay in the input dtype, the scale is applied to
@@ -294,8 +355,14 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                     # prefixes, so a row dead in this tile is dead in every
                     # tile).  Without ``causal`` every visited tile holds a
                     # valid key and no row is dead.
+                    # Under a window a row may see its first key only in
+                    # a later tile (what it holds till then is wiped by
+                    # ``corr`` = 0), and a row that never sees one (a
+                    # padded query a window beyond the length) weighs
+                    # nothing and gives 0.
                     dead = m_new <= (_NEG_INF * 0.5)
-                    p = jnp.where(dead, kmask.astype(jnp.float32), p)
+                    p = jnp.where(dead, 0.0 if window
+                                  else kmask.astype(jnp.float32), p)
                 corrs.append(jnp.exp(m - m_new))
                 ms_new.append(m_new)
                 ls_new.append(l * corrs[-1]
@@ -307,7 +374,9 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                     acc * _each(corrs, dp) + _each(pvs, dp))
 
         n = jnp.clip(pl.cdiv(vl - k0, block_k), 0, tiles)
-        return lax.fori_loop(0, n, tile, carry)
+        lo = jnp.clip((key_start(qi) - k0) // block_k, 0, tiles) \
+            if window else 0
+        return lax.fori_loop(lo, n, tile, carry)
 
     def start():
         return ((jnp.full((block_q, 1), _NEG_INF, jnp.float32),) * hb,
@@ -339,7 +408,9 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         def _():
             keep(*start())
 
-        @pl.when(kj * kv_block < vl)
+        @pl.when((kj * kv_block < vl)
+                 & ((kj + 1) * kv_block > key_start(qi)) if window
+                 else kj * kv_block < vl)
         def _():
             keep(*sweep(vl, q_ref[0], k_ref, v_ref, qi, kj,
                         (tuple(r[...] for r in m_refs),
@@ -357,6 +428,11 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
             pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
             0)
 
+    def kv_block_of(b, i, j, vl_ref):
+        # ... and one wholly behind the window to the first that is not
+        j = jnp.maximum(j, key_start(i) // kv_block) if window else j
+        return jnp.minimum(j, last_block(b, i, vl_ref))
+
     # Mosaic takes neither a rank-1 block of one element nor rank-1 loop
     # carries: the per-row length rides scalar memory, and the running
     # max/denominator are (block_q, 1) columns.  A head (or a pair) is the
@@ -368,10 +444,11 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                             lambda b, h, i, j, vl: (b, i, at + h))
 
     def kv_spec(at):
+        # ``group`` query blocks in a row read the one key block in place
         return pl.BlockSpec(
             (1, kv_block, dp),
-            lambda b, h, i, j, vl: (b, jnp.minimum(j, last_block(b, i, vl)),
-                                    at + h))
+            lambda b, h, i, j, vl: (b, kv_block_of(b, i, j, vl),
+                                    at + (h // group if group > 1 else h)))
     scratch = [] if nkv == 1 else (
         [pltpu.VMEM((block_q, 1), jnp.float32)] * (2 * hb)
         + [pltpu.VMEM((block_q, dp), jnp.float32)])
@@ -388,7 +465,7 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" + ("_window" if window else ""),
     )
 
 
@@ -415,7 +492,8 @@ def _bwd_tiling(lq: int, lk: int, d: int, itemsize: int):
 @functools.lru_cache(maxsize=None)
 def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                     scale: float, dtype_name: str, interpret: bool,
-                    blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0)):
+                    blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0),
+                    window: int = 0, group: int = 1):
     """The backward's two kernels for one call's (unpadded) shape; they
     take the operands padded as ``_bwd_tiling`` says, and lane blocks as
     the forward does (``_build_call``: ``d`` lanes a block, ``blocks`` of
@@ -460,7 +538,17 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     nor fetched, as in the forward; a key tile wholly beyond the length
     gets zeros.  Operands reach the MXU as XLA's default precision gives
     them to it (float32 rounded to bfloat16 once, bfloat16 as it is);
-    products, statistics and accumulators are float32."""
+    products, statistics and accumulators are float32.
+
+    Under a ``window`` (keys; 0: none) ``dq`` starts its sweep where the
+    forward does, and ``dkv`` ends a key tile's query sweep at the last
+    query tile that holds a query less than ``window`` beyond the tile's
+    last key; Q-major blocks beyond it are not fetched.  With ``group`` > 1
+    query blocks to a block of k and v, ``dq`` reads the key block in place
+    and ``dkv``'s grid is (batch, key blocks of the lanes, key blocks, the
+    group's query blocks, Q-major blocks): the last two are sequential and
+    add up in the one scratch, so dk and dv come out a key head wide and no
+    copy a query head wide of k, v, dk or dv is made."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -484,7 +572,10 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     for name, value in (("block_q", block_q), ("block_k", block_k),
                         ("grid_steps",
                          bh * blocks * (nq * nkv + nkb * nqb)),
-                        ("lane_heads", hb)):
+                        ("lane_heads", hb),
+                        ("window", window), ("kv_group", group),
+                        *_key_tiles(lq, lk, block_q, block_k, diagonal,
+                                    window)):
         reg.gauge(f"kernels.flash_attention_bwd.{name}",
                   "tiling of the last flash backward kernels built"
                   ).set(value)
@@ -510,7 +601,9 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     # with Lk < Lq makes one (with Lk >= Lq every row sees key 0, and a
     # row of length 0 visits nothing).  It weighs its valid keys evenly
     # and passes nothing to q and k, as in the forward.
-    dead_rows = causal and not diagonal
+    # Under a window a padded query a window beyond the length sees none
+    # either: it weighs nothing, as in the forward.
+    dead_rows = (causal and not diagonal) or bool(window)
 
     def positions():
         """Of a score block (keys down the sublanes, queries along the
@@ -525,6 +618,8 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         """Scores masked as the forward masks them, and the valid keys."""
         kmask = key < length - k0
         keep = kmask & (rel <= q0 + off - k0) if causal else kmask
+        if window:
+            keep = keep & (rel > q0 + off - k0 - window)
         return jnp.where(keep, s, _NEG_INF), kmask
 
     def weights(s, kmask, lse, linv, dp_, delta):
@@ -532,7 +627,8 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         p = jnp.exp(s - lse)
         if dead_rows:
             dead = lse <= _NEG_INF * 0.5
-            p = jnp.where(dead, kmask.astype(jnp.float32) * linv, p)
+            p = jnp.where(dead, 0.0 if window
+                          else kmask.astype(jnp.float32) * linv, p)
         ds = p * (dp_ - delta)
         if dead_rows:
             ds = jnp.where(dead, 0.0, ds)
@@ -542,6 +638,10 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         if not diagonal:
             return length
         return jnp.minimum(length, (qi + 1) * block_q + off)
+
+    def key_start(qi):
+        # the first key that query tile ``qi`` can weigh under the window
+        return jnp.maximum(qi * block_q + off - window + 1, 0)
 
     # -- dq, and the statistics -------------------------------------------
     def dq_kernel(vl_ref, q_ref, g_ref, k_ref, v_ref, dq_ref, st_ref,
@@ -553,6 +653,8 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         # can weigh
         tiles = jnp.clip(pl.cdiv(key_limit(length, qi) - k0, block_k), 0,
                          kv_block // block_k)
+        lo = jnp.clip((key_start(qi) - k0) // block_k, 0,
+                      kv_block // block_k) if window else 0
         qs = [scaled(_own(q_ref[0], h, hb)) for h in range(hb)]
         gs = [_own(g_ref[0], h, hb).astype(mxu) for h in range(hb)]
         key, rel = positions()
@@ -588,8 +690,8 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                 m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                 p = jnp.exp(s - m_new)
                 if dead_rows:
-                    p = jnp.where(m_new <= _NEG_INF * 0.5,
-                                  kmask.astype(jnp.float32), p)
+                    p = jnp.where(m_new <= _NEG_INF * 0.5, 0.0 if window
+                                  else kmask.astype(jnp.float32), p)
                 corr = jnp.exp(m - m_new)
                 pdp = p * (nt(v, g) - c)
                 ms_new.append(m_new)
@@ -604,7 +706,7 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                     b_ * corr + _each(kbs, dp, 0))
 
         ms, ls, ns, a, b_ = lax.fori_loop(
-            0, tiles, tile, (rows(m_ref), rows(l_ref), rows(n_ref),
+            lo, tiles, tile, (rows(m_ref), rows(l_ref), rows(n_ref),
                              a_ref[...], b_ref[...]))
         for ref, xs in ((m_ref, ms), (l_ref, ls), (n_ref, ns)):
             for h, x in enumerate(xs):
@@ -645,6 +747,11 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
             pl.cdiv(key_limit(jnp.minimum(vl_ref[b], lk), i), kv_block) - 1,
             0)
 
+    def kv_block_of(b, i, j, vl_ref):
+        # ... and it starts at the first that the window reaches
+        j = jnp.maximum(j, key_start(i) // kv_block) if window else j
+        return jnp.minimum(j, last_block(b, i, vl_ref))
+
     fq, fk, fv = first
 
     def q_spec(at):
@@ -654,8 +761,8 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     def kv_spec(at):
         return pl.BlockSpec(
             (1, kv_block, dp),
-            lambda b, h, i, j, vl: (b, jnp.minimum(j, last_block(b, i, vl)),
-                                    at + h))
+            lambda b, h, i, j, vl: (b, kv_block_of(b, i, j, vl),
+                                    at + (h // group if group > 1 else h)))
     stats = jax.ShapeDtypeStruct((bh, blocks, nq, 8, block_q), jnp.float32)
     dq_call = pl.pallas_call(
         dq_kernel,
@@ -674,20 +781,35 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name="flash_attention_bwd_dq",
+        name="flash_attention_bwd_dq" + ("_window" if window else ""),
     )
 
     # -- dk and dv --------------------------------------------------------
     q_tiles = q_block // block_q
+    # with ``group`` query blocks to a key block the grid's second axis
+    # counts key blocks of the lanes and a sequential axis before the
+    # Q-major one counts the group's query blocks
+    key_heads = blocks // group
 
     def dkv_kernel(vl_ref, k_ref, v_ref, q_ref, g_ref, st_ref, dk_ref,
                    dv_ref, dk_acc, dv_acc):
-        b, kb, qb = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        b, kb = pl.program_id(0), pl.program_id(2)
+        if group > 1:
+            gi, qb = pl.program_id(3), pl.program_id(4)
+        else:
+            gi, qb = None, pl.program_id(3)
+
+        def at_step(group_step, q_step):
+            # the sequential axes stand at this step of the group's query
+            # blocks (where there are any) and of the Q-major blocks
+            if gi is None:
+                return qb == q_step
+            return (gi == group_step) & (qb == q_step)
         length = jnp.minimum(vl_ref[b], lk)
         k0, q0 = kb * key_block, qb * q_block
         key, rel = positions()
 
-        @pl.when(qb == 0)
+        @pl.when(at_step(0, 0))
         def _():
             dk_acc[...] = jnp.zeros((key_block, dp), jnp.float32)
             dv_acc[...] = jnp.zeros((key_block, dp), jnp.float32)
@@ -718,11 +840,16 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                 return dk + _each(dks, dp), dv + _each(dvs, dp)
 
             # under the diagonal, the first query tile of this Q-major
-            # block whose last row sees the tile's first key
+            # block whose last row sees the tile's first key; under the
+            # window, the sweep ends with the last query tile whose first
+            # row still reaches the tile's last key
             first = (jnp.clip(kt - off - q0, 0, q_block) // block_q
                      if diagonal else 0)
+            last = jnp.clip(pl.cdiv(kt + block_k - 1 + window - off - q0,
+                                    block_q), 0, q_tiles) \
+                if window else q_tiles
             dk, dv = lax.fori_loop(
-                first, q_tiles, query_tile,
+                first, last, query_tile,
                 (jnp.zeros((block_k, dp), jnp.float32),) * 2)
             dk_acc[rows, :] += dk
             dv_acc[rows, :] += dv
@@ -734,47 +861,62 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                         key_block // block_k),
             key_tile, 0)
 
-        @pl.when(qb == nqb - 1)
+        @pl.when(at_step(group - 1, nqb - 1))
         def _():
             dk_ref[0] = dk_acc[...].astype(dtype)
             dv_ref[0] = dv_acc[...].astype(dtype)
 
     def q_index(b, kb, qb, vl_ref):
         # Q-major blocks wholly above the diagonal map to the first that
-        # is not, and every block of a key block beyond the length to the
-        # last: the pipeline sees the block it holds and issues no DMA
+        # is not, those wholly beyond the window to the last that is not,
+        # and every block of a key block beyond the length to the last:
+        # the pipeline sees the block it holds and issues no DMA
         first = (jnp.clip(kb * key_block - off, 0, lqp - 1) // q_block
                  if diagonal else 0)
         beyond = kb * key_block >= jnp.minimum(vl_ref[b], lk)
-        return jnp.where(beyond, nqb - 1, jnp.maximum(qb, first))
+        at = jnp.maximum(qb, first)
+        if window:
+            at = jnp.minimum(at, jnp.clip(
+                (kb + 1) * key_block - 1 + window - 1 - off, 0, lqp - 1)
+                // q_block)
+        return jnp.where(beyond, nqb - 1, at)
+
+    def on_grid(index):
+        """``index(b, key head, query head, kb, qb, vl)`` as the grid's
+        index map."""
+        if group > 1:
+            return lambda b, h, kb, gi, qb, vl: index(
+                b, h, h * group + gi, kb, qb, vl)
+        return lambda b, h, kb, qb, vl: index(b, h, h, kb, qb, vl)
 
     def kb_spec(at):
-        return pl.BlockSpec((1, key_block, dp),
-                            lambda b, h, kb, qb, vl: (b, kb, at + h))
+        return pl.BlockSpec((1, key_block, dp), on_grid(
+            lambda b, hk, hq, kb, qb, vl: (b, kb, at + hk)))
 
     def qb_spec(at):
-        return pl.BlockSpec(
-            (1, q_block, dp),
-            lambda b, h, kb, qb, vl: (b, q_index(b, kb, qb, vl), at + h))
+        return pl.BlockSpec((1, q_block, dp), on_grid(
+            lambda b, hk, hq, kb, qb, vl: (b, q_index(b, kb, qb, vl),
+                                           at + hq)))
     dkv_call = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, blocks, nkb, nqb),
+            grid=(bh, key_heads, nkb) + ((group,) if group > 1 else ())
+            + (nqb,),
             in_specs=[kb_spec(fk), kb_spec(fv), qb_spec(fq), qb_spec(0),
                       pl.BlockSpec(
-                          (1, 1, q_tiles, 8, block_q),
-                          lambda b, h, kb, qb, vl: (
-                              b, h, q_index(b, kb, qb, vl), 0, 0))],
+                          (1, 1, q_tiles, 8, block_q), on_grid(
+                              lambda b, hk, hq, kb, qb, vl: (
+                                  b, hq, q_index(b, kb, qb, vl), 0, 0)))],
             out_specs=[kb_spec(0), kb_spec(0)],
             scratch_shapes=[pltpu.VMEM((key_block, dp), jnp.float32)] * 2),
-        out_shape=[jax.ShapeDtypeStruct((bh, lkp, blocks * dp),
+        out_shape=[jax.ShapeDtypeStruct((bh, lkp, key_heads * dp),
                                         dtype)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel")
+            + ("arbitrary",) * (2 if group > 1 else 1)),
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name="flash_attention_bwd_dkv" + ("_window" if window else ""),
     )
     return dq_call, dkv_call
 
@@ -789,6 +931,8 @@ class _Call(typing.NamedTuple):
     d: int              # lanes of a head
     src: tuple          # which of the call's arrays q, k and v are read from
     first: tuple        # ... and the head of it that each starts at
+    window: int = 0     # keys a query reaches back over, itself among them
+    group: int = 1      # query heads in a row that read one head of k and v
 
 
 def _blocks(call: _Call, arrays):
@@ -813,21 +957,22 @@ def _run_backward(arrays, vl, g, call: _Call):
     blocks, hb, lanes, first = _blocks(call, arrays)
     _, _, lqp, _, _, _, lkp, dp = _bwd_tiling(
         lq, lk, lanes, jnp.result_type(q).itemsize)
+    key_width = call.heads // call.group * call.d
 
     with jax.named_scope("flash_attention_pad"):
         qp, gp, kp, vp = (_pad_to(x, rows, dp - lanes) for x, rows in (
             (q, lqp), (g.astype(q.dtype), lqp), (k, lkp), (v, lkp)))
     dq_call, dkv_call = _build_backward(
         bh, lq, lk, lanes, call.causal, call.scale, jnp.result_type(q).name,
-        call.interpret, blocks, hb, first)
+        call.interpret, blocks, hb, first, call.window, call.group)
     lens = vl.astype(jnp.int32)
     dq, stats = dq_call(lens, qp, gp, kp, vp)
     dk, dv = dkv_call(lens, kp, vp, qp, gp, stats)
-    if dq.shape == g.shape and dk.shape[1] == lk:
+    if dq.shape == g.shape and dk.shape[1:] == (lk, key_width):
         return dq, dk, dv
     with jax.named_scope("flash_attention_pad"):
-        width = g.shape[2]
-        return dq[:, :lq, :width], dk[:, :lk, :width], dv[:, :lk, :width]
+        return (dq[:, :lq, :g.shape[2]], dk[:, :lk, :key_width],
+                dv[:, :lk, :key_width])
 
 
 def _cotangents(arrays, call: _Call, grads):
@@ -922,7 +1067,7 @@ def _run_kernel(arrays, vl, call: _Call):
                       for x, rows in ((q, lqp), (k, lkp), (v, lkp)))
     kernel = _build_call(bh, lq, lk, lanes, call.causal, call.scale,
                          jnp.result_type(q).name, call.interpret, blocks, hb,
-                         first)
+                         first, call.window, call.group)
     out = kernel(vl.astype(jnp.int32), qp, kp, vp)
     width = call.heads * call.d
     if out.shape == (bh, lq, width):
@@ -941,7 +1086,8 @@ def _heads_first(x, heads: int, d: int, first: int):
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     interpret=None, valid_len=None, num_heads=None,
-                    head_dim=None, first_head=(0, 0, 0)):
+                    head_dim=None, first_head=(0, 0, 0), window=None,
+                    num_kv_heads=None):
     """Tiled attention: softmax(scale·QKᵀ + mask)V without materializing
     the score matrix.
 
@@ -966,6 +1112,23 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     lengths a head are served too: by the heads-first form, through the
     transposes this form spares.
 
+    ``window`` (with ``causal`` and Lk >= Lq; what the model's layer has,
+    not a switch): key j weighs on the query at key position i where
+    ``j <= i`` and ``i - j < window``, the query's own key among the
+    ``window``.  Tiles and blocks wholly behind the window are neither
+    visited nor fetched, in the forward and in both backward kernels, which
+    then carry the names ``flash_attention_{fwd,bwd_dq,bwd_dkv}_window``.  A
+    window that reaches every key (``>= Lk``) is no window.  A padded query
+    whose window holds no key below ``valid_len`` gives 0 and passes no
+    gradient.
+
+    ``num_kv_heads`` (tokens-major; grouped-query attention): k and v hold
+    that many heads and query head h reads key head
+    ``h // (num_heads / num_kv_heads)``; dk and dv come back that many
+    heads wide.  The kernels read a group's key head in place by the index
+    map and add the group's dk, dv up in VMEM, so the heads are whole lane
+    groups (``head_dim`` a multiple of 128); another width is refused.
+
     DIFFERENTIABLE: the forward runs the Pallas kernel, the backward two
     more (``dq`` and ``dkv``), at the precision XLA's default gives a
     matmul on the chip (float32 operands rounded to bfloat16 once,
@@ -977,7 +1140,18 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 
     if interpret is None:
         interpret = _interpret(q)
+    if window is not None:
+        lq, lk = q.shape[-2 if num_heads is None else 1], \
+            k.shape[-2 if num_heads is None else 1]
+        if not causal or lk < lq or int(window) < 1:
+            raise ValueError("a window of at least one key goes with a "
+                             "causal mask and Lk >= Lq")
+    window = 0 if window is None or int(window) >= lk else int(window)
     if num_heads is None:
+        if num_kv_heads is not None:
+            raise ValueError("key heads shared by a group of query heads "
+                             "are read from tokens-major operands "
+                             "(num_heads given)")
         squeeze4 = q.ndim == 4
         if squeeze4:
             b, h, lq, dd = q.shape
@@ -985,34 +1159,45 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
             k = k.reshape(b * h, k.shape[2], dd)
             v = v.reshape(b * h, v.shape[2], dd)
         out = _attend((q, k, v), 1, q.shape[2], (0, 0, 0), causal, scale,
-                      interpret, valid_len)
+                      interpret, valid_len, window)
         return out.reshape(b, h, lq, dd) if squeeze4 else out
 
     heads, first = int(num_heads), tuple(int(f) for f in first_head)
+    key_heads = heads if num_kv_heads is None else int(num_kv_heads)
+    if heads % key_heads:
+        raise ValueError(f"{heads} query heads do not divide into groups "
+                         f"over {key_heads} key heads")
+    group = heads // key_heads
     d = int(head_dim) if head_dim else q.shape[2] // heads
-    for x, f in zip((q, k, v), first):
-        if (f + heads) * d > x.shape[2]:
+    for x, f, n in zip((q, k, v), first, (heads, key_heads, key_heads)):
+        if (f + n) * d > x.shape[2]:
             raise ValueError(
-                f"{heads} heads of {d} lanes from head {f} on do not fit "
+                f"{n} heads of {d} lanes from head {f} on do not fit "
                 f"an operand of {x.shape[2]} lanes")
     b = q.shape[0]
     rows = None if valid_len is None else jnp.asarray(valid_len).size
-    if rows in (None, b) and _lane_blocks(
-            heads, d, first, [x.shape[2] for x in (q, k, v)]):
+    lanes = rows in (None, b) and _lane_blocks(
+        heads, d, first, [x.shape[2] for x in (q, k, v)])
+    if group > 1 and not (lanes and d % 128 == 0):
+        raise ValueError("a group's key head is read in place: heads of "
+                         "whole lane groups (head_dim a multiple of 128) "
+                         "and lengths a batch row")
+    if lanes:
         return _attend((q, k, v), heads, d, first, causal, scale, interpret,
-                       valid_len)
+                       valid_len, window, group)
     out = _attend(tuple(_heads_first(x, heads, d, f)
                         for x, f in zip((q, k, v), first)),
-                  1, d, (0, 0, 0), causal, scale, interpret, valid_len)
+                  1, d, (0, 0, 0), causal, scale, interpret, valid_len,
+                  window)
     return out.reshape(b, heads, -1, d).transpose(0, 2, 1, 3).reshape(
         b, -1, heads * d)
 
 
 def _attend(operands, heads: int, d: int, first, causal, scale, interpret,
-            valid_len):
+            valid_len, window: int = 0, group: int = 1):
     """The kernels over (q, k, v) that ``_lane_blocks`` serves: ``heads``
     heads of ``d`` lanes from head ``first[i]`` of operand i on (heads-first
-    operands: one head, the whole width)."""
+    operands: one head, the whole width); of k and v ``heads / group``."""
     import jax.numpy as jnp
 
     rows, lk = operands[0].shape[0], operands[1].shape[1]
@@ -1037,4 +1222,4 @@ def _attend(operands, heads: int, d: int, first, causal, scale, interpret,
     return _flash_core(tuple(arrays), vl, _Call(
         bool(causal), float(d ** -0.5 if scale is None else scale),
         bool(interpret),
-        heads, d, tuple(src), tuple(first)))
+        heads, d, tuple(src), tuple(first), window, group))

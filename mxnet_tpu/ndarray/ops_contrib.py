@@ -983,20 +983,27 @@ def _register_round3b():
     # which): heads of a multiple of 128 lanes are read as lane blocks of
     # the array, an even count of 64-lane heads two to a block, and no
     # transposed copy is made; any other width or count goes through the
-    # heads-first form and its transposes.
+    # heads-first form and its transposes.  ``window`` (with ``causal``):
+    # a query weighs its own key and the ``window - 1`` before it.
+    # ``num_kv_heads`` (tokens-major, heads of whole lane groups): k and v
+    # hold that many heads, each read in place by a group of ``num_heads /
+    # num_kv_heads`` query heads in a row.
     def flash_attention_maker(causal=False, scale=None, num_heads=None,
-                              head_dim=None, first_head=(0, 0, 0)):
+                              head_dim=None, first_head=(0, 0, 0),
+                              window=None, num_kv_heads=None):
         from ..kernels import flash_attention as _fa
 
         def fn(q, k, v, valid_len=None):
             # optional 4th input: per-sequence key-padding lengths
             return _fa(q, k, v, causal=causal, scale=scale,
                        valid_len=valid_len, num_heads=num_heads,
-                       head_dim=head_dim, first_head=first_head)
+                       head_dim=head_dim, first_head=first_head,
+                       window=window, num_kv_heads=num_kv_heads)
         return fn
 
     def flash_attention_vjp_maker(causal=False, scale=None, num_heads=None,
-                                  head_dim=None, first_head=(0, 0, 0)):
+                                  head_dim=None, first_head=(0, 0, 0),
+                                  window=None, num_kv_heads=None):
         # recording path: jax.vjp traces the op, so the Mosaic-vs-
         # interpret choice must be made HERE on the concrete arrays,
         # before tracing (the multi_sgd static-kwarg rule)
@@ -1008,7 +1015,8 @@ def _register_round3b():
                 return _fa(a, b, c, causal=causal, scale=scale,
                            interpret=_interp(q), valid_len=valid_len,
                            num_heads=num_heads, head_dim=head_dim,
-                           first_head=first_head)
+                           first_head=first_head, window=window,
+                           num_kv_heads=num_kv_heads)
             if valid_len is None:
                 return jax.vjp(attend, q, k, v)
             out, vjp3 = jax.vjp(attend, q, k, v)
@@ -1024,13 +1032,17 @@ def _register_round3b():
                 vjp_maker=flash_attention_vjp_maker)
 
     # ---- routed experts (parallel/moe.py, one chip's share) ---------------
-    def routed_experts_maker(top_k=1, first=0, scale=1.0, norm_topk=True):
+    def routed_experts_maker(top_k=1, first=0, scale=1.0, norm_topk=True,
+                             score="sigmoid", activation="silu"):
         from ..parallel.moe import routed_experts as _re
 
-        def fn(x, router_w, router_b, w_gate, w_up, w_down):
+        def fn(x, router_w, router_b, w_gate, w_up, w_down, router_x=None):
+            # optional 7th input: what the router reads, where that is not
+            # the experts' input
             return _re(x, router_w, router_b, w_gate, w_up, w_down,
                        top_k=top_k, first=first, scale=scale,
-                       norm_topk=norm_topk)
+                       norm_topk=norm_topk, score=score,
+                       activation=activation, router_x=router_x)
         return fn
     # use_jit=False: inside a compiled step its scopes (router, dispatch,
     # experts, combine) stay the program's own, not a nested jit's

@@ -7,15 +7,22 @@ first-class, so the framework ships TPU-native expert layers.
 ``SparseMoE`` is the design: an expert layer that is TOLD WHICH EXPERTS IT
 HOLDS (``experts_held=(first, count)``, one chip's share of an
 expert-parallel deployment), routes every token over all ``num_experts``
-(sigmoid scores, selection by score plus a non-gradient bias, top-k,
-renormalised, scaled), and computes its own experts' part of the result
-for the tokens routed to them, dropping none: the token-assignments are
-sorted by expert, the rows of the held experts gathered into one buffer
-and multiplied group by group (``jax.lax.ragged_dot`` over the stacked
-expert weights), weighted and scatter-added back.  A shared expert runs
-beside them on every token.  What the experts held elsewhere would add is
-left out; on one chip the layer runs without its exchange.  The buffer has
-``top_k`` x tokens rows, one for every assignment, so none can fail to fit.
+by one of the two routers the architectures have (``score="sigmoid"``:
+sigmoid scores, selection by score plus a non-gradient bias, top-k,
+renormalised, scaled; ``score="softmax"``: the top-k logits and the
+softmax over them, no bias, no scale), and computes its own experts' part
+of the result for the tokens routed to them, dropping none: the
+token-assignments are sorted by expert, the rows of the held experts
+gathered into one buffer and multiplied group by group
+(``jax.lax.ragged_dot`` over the stacked expert weights; gated by SiLU or
+by ReLU, as the architecture says), weighted and scatter-added back.  The
+router reads the experts' input, or another array of the same rows where
+the block hands it one (a router that stands before attention reads the
+block's input while the experts read the normed state after it).  A shared
+expert runs beside them on every token where the architecture has one.
+What the experts held elsewhere would add is left out; on one chip the
+layer runs without its exchange.  The buffer has ``top_k`` x tokens rows,
+one for every assignment, so none can fail to fit.
 
 ``MoEFFN`` is the older Switch/GShard dense-dispatch form (top-1, a
 capacity limit, one-hot matmuls), kept for the ``ep`` mesh axis: under
@@ -111,7 +118,8 @@ class MoEFFN(HybridBlock):
 
 
 def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
-                   first=0, scale=1.0, norm_topk=True):
+                   first=0, scale=1.0, norm_topk=True, score="sigmoid",
+                   activation="silu", router_x=None):
     """The routed part of a sparse-expert layer on one chip's share.
 
     x (T, D); router_w (E, D) and router_b (E,) over ALL experts; w_gate,
@@ -120,22 +128,45 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
     (G,) token-assignments per held expert)``.  The dispatch buffer has
     ``top_k`` x T rows, which every assignment fits.  The router runs in
     float32 at the highest precision whatever x is kept in: a rounded
-    score flips selections."""
+    score flips selections.  It reads ``router_x`` (T, D) where that is
+    given, else ``x``.
+
+    ``score="sigmoid"``: the gates are the sigmoids of the logits, the
+    selection is by gate plus ``router_b`` (which no gradient reaches),
+    renormalised over the selected where ``norm_topk``, times ``scale``.
+    ``score="softmax"``: the ``top_k`` largest logits are selected and the
+    gates are the softmax over those (the softmax over all experts,
+    renormalised over the selected); ``router_b``, ``norm_topk`` and
+    ``scale`` take no part.  ``activation``: the experts' gating function,
+    ``"silu"`` or ``"relu"``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
+    if activation not in ("silu", "relu"):
+        raise ValueError(f"activation {activation!r} is neither 'silu' nor "
+                         "'relu'")
+    act = getattr(jax.nn, activation)
     g = w_gate.shape[0]
     with jax.named_scope("router"):
-        s = jax.nn.sigmoid(jnp.einsum(
-            "td,ed->te", x.astype(jnp.float32),
-            router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST))
-        _, sel = lax.top_k(
-            s + lax.stop_gradient(router_b.astype(jnp.float32)), top_k)
-        gate = jnp.take_along_axis(s, sel, axis=-1)
-        if norm_topk:
-            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
-        gate = gate * scale
+        logits = jnp.einsum(
+            "td,ed->te", (x if router_x is None else router_x)
+            .astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST)
+        if score == "softmax":
+            top, sel = lax.top_k(logits, top_k)
+            gate = jax.nn.softmax(top, axis=-1)
+        elif score == "sigmoid":
+            s = jax.nn.sigmoid(logits)
+            _, sel = lax.top_k(
+                s + lax.stop_gradient(router_b.astype(jnp.float32)), top_k)
+            gate = jnp.take_along_axis(s, sel, axis=-1)
+            if norm_topk:
+                gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+            gate = gate * scale
+        else:
+            raise ValueError(f"score {score!r} is neither 'sigmoid' nor "
+                             "'softmax'")
     with jax.named_scope("dispatch"):
         # a token-assignment's key is its expert's index among the held
         # ones; those of experts held elsewhere sort behind them all
@@ -153,8 +184,7 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
     with jax.named_scope("experts"):
         h = lax.ragged_dot(rows, w_gate.astype(x.dtype), load)
         u = lax.ragged_dot(rows, w_up.astype(x.dtype), load)
-        out = lax.ragged_dot(jax.nn.silu(h) * u, w_down.astype(x.dtype),
-                             load)
+        out = lax.ragged_dot(act(h) * u, w_down.astype(x.dtype), load)
     with jax.named_scope("combine"):
         wgt = jnp.where(live, jnp.take(gate.reshape(-1), order), 0.0)
         out = jnp.where(live[:, None], out, 0) * wgt[:, None].astype(x.dtype)
@@ -164,8 +194,10 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
 
 class SparseMoE(HybridBlock):
     """One chip's share of a sparse-expert feed-forward (module docstring):
-    sigmoid top-k router over ``num_experts``, the ``experts_held`` routed
-    experts as stacked SwiGLU weights, a shared SwiGLU expert beside them.
+    a top-k router over ``num_experts``, the ``experts_held`` routed
+    experts as stacked gated-linear-unit weights, a shared SwiGLU expert
+    beside them.  ``net(x)`` routes on ``x``; ``net(x, router_x)`` routes on
+    ``router_x`` (the same leading shape) and feeds the experts ``x``.
 
     Parameters
     ----------
@@ -174,13 +206,18 @@ class SparseMoE(HybridBlock):
     experts_held : ``(first, count)``, the routed experts computed here;
         all of them by default.
     shared_hidden : the shared expert's width; 0 for none.
-    routed_scale, norm_topk : the gates are renormalised over the selected
-        experts (held here or not), then scaled.
+    routed_scale, norm_topk : the sigmoid router's gates are renormalised
+        over the selected experts (held here or not), then scaled.
+    score, activation : what the architecture has (``routed_experts``):
+        ``"sigmoid"`` gates selected with a bias, or the ``"softmax"`` over
+        the selected logits; ``"silu"`` or ``"relu"`` gating in the routed
+        experts.
     """
 
     def __init__(self, units, hidden_size, num_experts, top_k,
                  experts_held=None, shared_hidden=0, routed_scale=1.0,
-                 norm_topk=True, prefix=None, params=None):
+                 norm_topk=True, score="sigmoid", activation="silu",
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = experts_held if experts_held is not None \
             else (0, num_experts)
@@ -189,6 +226,7 @@ class SparseMoE(HybridBlock):
                              f"of the {num_experts} experts")
         self._k, self._first, self._count = top_k, first, count
         self._scale, self._norm = routed_scale, norm_topk
+        self._score, self._act = score, activation
         from ..observability.registry import registry
         for name, value, doc in (
                 ("experts_routed", num_experts, "experts the router scores"),
@@ -216,15 +254,19 @@ class SparseMoE(HybridBlock):
             self.shared = SwiGLU(units, shared_hidden, prefix="shared_") \
                 if shared_hidden else None
 
-    def hybrid_forward(self, F, x, router_weight, router_bias, experts_gate,
-                       experts_up, experts_down, expert_load):
+    def hybrid_forward(self, F, x, router_x=None, *, router_weight,
+                       router_bias, experts_gate, experts_up, experts_down,
+                       expert_load):
         from .. import autograd
         shape = x.shape
         tok = F.reshape(x, shape=(-1, shape[-1]))
+        reads = () if router_x is None else (
+            F.reshape(router_x, shape=(-1, shape[-1])),)
         y, load = F.routed_experts(
             tok, router_weight, router_bias, experts_gate, experts_up,
-            experts_down, top_k=self._k, first=self._first,
-            scale=self._scale, norm_topk=self._norm)
+            experts_down, *reads, top_k=self._k, first=self._first,
+            scale=self._scale, norm_topk=self._norm, score=self._score,
+            activation=self._act)
         if autograd.is_training():
             expert_load._set_data(load._read())
         y = F.reshape(y, shape=shape)

@@ -174,6 +174,53 @@ def test_flash_attention_backward_compiles_for_v5e(
         assert " transpose(" not in text and " copy(" not in text
 
 
+# the window / full attention cell's kernels as its model hands them over:
+# one row of 16,384 tokens, 28 query heads of 128 lanes that read 4 key
+# heads, causal, a window of 4096 in three blocks of four and none in one
+@pytest.mark.parametrize("window,tail", [(4096, "_window"), (None, "")],
+                         ids=["window_block", "full_block"])
+def test_grouped_window_kernels_compile_for_v5e(one_chip, window, tail):
+    """Forward, ``dq`` and ``dkv`` at (28 / 4 heads, 128, 16,384, window
+    4096) compile for the chip, one Mosaic call each, the window build's
+    under its own names; k and v are read, and dk and dv written, a key head
+    wide: the compiled gradient holds no array of a key operand's rows that
+    is a query head wide but q's own, the result's and their gradients', no
+    transpose and no copy."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+
+    seq, heads, kv_heads, d = 16384, 28, 4, 128
+    q = jax.ShapeDtypeStruct((1, seq, heads * d), "float32",
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads * d), "float32",
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False,
+                              num_heads=heads, num_kv_heads=kv_heads,
+                              head_dim=d, window=window)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert _kernel_calls(text, name + tail) == 1, name
+        assert _kernel_calls(text, name) == 1, name
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " transpose(" not in text and " copy(" not in text
+    # dk and dv leave the kernel 4 heads wide
+    dkv = [line for line in text.splitlines()
+           if "flash_attention_bwd_dkv" in line.split(" = ")[0]
+           and "tpu_custom_call" in line]
+    assert f"f32[1,{seq},{kv_heads * d}]" in dkv[0].split(" custom-call(")[0]
+    # q, the result, the cotangent and dq: the only arrays 28 heads wide
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        3 * seq * heads * d * 4
+
+
 def test_fused_projection_is_read_in_place_for_v5e(one_chip):
     """Projection, attention, projection at the BERT cell's shape from one
     fused (768, 2304) weight, forward and gradient: the flash kernels read

@@ -659,11 +659,14 @@ def test_flash_attention_tile_choice_and_skipped_tiles(
     assert not out[0].any()          # no valid key: the row divides by 1
 
 
-def _attention_and_grads(q, k, v, g, lens, causal, scale, rounded=False):
+def _attention_and_grads(q, k, v, g, lens, causal, scale, rounded=False,
+                         window=None):
     """(out, dq, dk, dv) of the masked softmax attention with the kernel's
     dead-row rule, written out by hand in float32.  ``rounded``: operands
     reach each product rounded to bfloat16 once, as the backward's kernels
-    (and XLA's default precision on the chip) give them to the MXU."""
+    (and XLA's default precision on the chip) give them to the MXU.
+    ``window``: a query weighs its own key and the ``window - 1`` before
+    it; a row that then sees none weighs nothing."""
     import jax
     import jax.numpy as jnp
     r = (lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)) if rounded \
@@ -680,12 +683,15 @@ def _attention_and_grads(q, k, v, g, lens, causal, scale, rounded=False):
         if causal:
             live = keep & (jnp.arange(lk)[None, None, :] <=
                            jnp.arange(lq)[None, :, None] + (lk - lq))
+        if window is not None:
+            live = live & (jnp.arange(lk)[None, None, :] >
+                           jnp.arange(lq)[None, :, None] + (lk - lq) - window)
         # a row with no live key weighs its valid keys evenly (none: 0)
         # and passes no gradient to q and k
         dead = ~live.any(-1, keepdims=True)
         p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
-        p = jnp.where(dead, keep / jnp.maximum(keep.sum(-1, keepdims=True),
-                                               1), p)
+        p = jnp.where(dead, 0.0 if window is not None else
+                      keep / jnp.maximum(keep.sum(-1, keepdims=True), 1), p)
         dp = jnp.einsum("bqd,bkd->bqk", g, v)
         ds = jnp.where(dead, 0.0,
                        p * (dp - jnp.sum(p * dp, -1, keepdims=True)))
@@ -796,6 +802,200 @@ def test_flash_backward_kernels_match_full_softmax(
         text = str(jax.make_jaxpr(lambda *a: jax.vjp(flashed, *a)[1](g))(
             q, k, v))
         assert "reduce_precision[" not in text and "scan[" not in text
+
+
+# the sweep's lower bound: windows that are no multiple of a key tile (256)
+# or of a query tile, that end inside the first tile, that cross K-major
+# blocks of 2048 keys, with rows' valid lengths on both sides of a tile's
+# edge and nought (a padded query a window beyond its row's length sees no
+# key: it gives 0 and passes no gradient), with Lk > Lq, and one that
+# reaches every key, which is no window: today's kernels under today's names
+@pytest.mark.parametrize("lq,lk,d,window,ragged", [
+    (600, 600, 16, 100, False),
+    (600, 600, 16, 300, True),
+    (700, 700, 16, 513, True),
+    (2304, 2304, 128, 700, False),
+    (200, 640, 16, 256, True),
+    (600, 600, 16, 600, False),
+    (600, 600, 16, 5000, True)],
+    ids=["inside_a_tile", "ragged", "two_tiles_and_a_key_ragged",
+         "across_k_major_blocks", "lk_gt_lq_ragged", "every_key",
+         "beyond_every_key_ragged"])
+def test_flash_window_kernels_match_masked_softmax(lq, lk, d, window,
+                                                   ragged):
+    """Forward, dq, dk and dv of the kernels built with a window against
+    the masked softmax written out by hand (at the kernels' own roundings
+    to a few flipped ones, against the exact ones to what one bfloat16
+    rounding costs), the three kernels under the window build's names; and
+    the tiles the gauges say the forward visits are those its loop bounds
+    give."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.observability.registry import registry
+    fa = _flash_module()
+
+    scale = 0.3 if d == 16 else d ** -0.5
+    lens = [lk, 0, 1, 255, 257, lk - 1] if ragged else [lk, lk]
+    lens = np.array([min(n, lk) for n in lens], np.float32)
+    rs = np.random.RandomState(17)
+    q, k, v, g = (jnp.asarray(rs.randn(len(lens), n, d).astype(np.float32))
+                  for n in (lq, lk, lk, lq))
+
+    def flashed(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, scale=scale,
+                                  valid_len=jnp.asarray(lens), window=window)
+
+    fa._build_call.cache_clear()
+    fa._build_backward.cache_clear()
+    reg = registry()
+    for n in ("key_tiles", "key_tiles_causal"):     # a window build's alone
+        reg.gauge(f"kernels.flash_attention.{n}").set(-1)
+    got_out, vjp = jax.vjp(flashed, q, k, v)
+    got = vjp(g)
+    names = pallas_call_names(
+        jax.make_jaxpr(lambda *a: jax.vjp(flashed, *a)[1](g))(q, k, v).jaxpr)
+    tail = "_window" if window < lk else ""
+    assert sorted(names) == [f"flash_attention_bwd_dkv{tail}",
+                             f"flash_attention_bwd_dq{tail}",
+                             f"flash_attention_fwd{tail}"]
+    read = {n: reg.get(f"kernels.flash_attention.{n}").read()
+            for n in ("window", "kv_group", "key_tiles", "key_tiles_causal",
+                      "block_q", "block_k")}
+    assert read["window"] == (window if window < lk else 0)
+    assert read["kv_group"] == 1
+    bq, bk = int(read["block_q"]), int(read["block_k"])
+    off = lk - lq
+    causal_tiles = sum(-(-min(lk, q0 + bq + off) // bk)
+                       for q0 in range(0, lq, bq))
+    behind = sum(max(q0 + off - window + 1, 0) // bk
+                 for q0 in range(0, lq, bq))
+    if window < lk:
+        assert read["key_tiles_causal"] == causal_tiles
+        assert read["key_tiles"] == causal_tiles - behind
+    else:       # no window, no word on the tiles a window would leave
+        assert read["key_tiles_causal"] == read["key_tiles"] == -1
+    if (lq, window) == (2304, 700):
+        assert behind > 0 and bk == 256
+
+    exact = _attention_and_grads(q, k, v, g, lens, True, scale,
+                                 window=window)
+    same = _attention_and_grads(q, k, v, g, lens, True, scale, rounded=True,
+                                window=window)
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(exact[0]),
+                               atol=3e-5, rtol=0)
+    for a, b, c, name in zip(got, same[1:], exact[1:], ("dq", "dk", "dv")):
+        a, c = np.asarray(a), np.asarray(c)
+        top = np.abs(c).max()
+        if name == "dq":
+            assert np.linalg.norm(a - c) <= 1.5 * np.linalg.norm(
+                np.asarray(b) - c)
+        else:
+            assert np.abs(a - np.asarray(b)).max() <= 1e-3 * top, name
+        assert np.abs(a - c).max() <= 2.5e-2 * top, name
+    if ragged:
+        assert not np.asarray(got_out)[1].any()    # no valid key at all
+        assert not np.asarray(got[1])[1].any()
+        assert not np.asarray(got[2])[1].any()
+        if window < lk - 1:
+            # a query a window beyond a row of length 1 sees no key
+            assert not np.asarray(got_out)[2, lq - 1].any()
+            assert not np.asarray(got[0])[2, lq - 1].any()
+
+
+# key heads shared by a group of query heads, read in place: groups of 1,
+# 2 and 7 query heads of a whole lane group (128 lanes) to a key head, with
+# a window and without, with rows' lengths
+@pytest.mark.parametrize("heads,kv_heads,d,seq,window,lens", [
+    (2, 2, 128, 300, 100, None),
+    (4, 2, 128, 300, None, [300, 77]),
+    (4, 2, 128, 520, 257, [520, 130]),
+    (7, 1, 128, 300, 129, None),
+    (7, 1, 128, 300, None, [300, 77])],
+    ids=["group_of_1_window", "group_of_2_lengths", "group_of_2_window",
+         "group_of_7_window", "group_of_7_lengths"])
+def test_flash_grouped_heads_match_masked_softmax(heads, kv_heads, d, seq,
+                                                  window, lens):
+    """Query head h reads key head h // group: forward and gradients of the
+    tokens-major call with ``num_kv_heads`` against the masked softmax over
+    (B, key head, group, S, d) written out by hand; dk and dv come back a
+    key head wide, the sum over the group's query heads, and no copy of k
+    or v a query head wide is made."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
+
+    b, group = 2, heads // kv_heads
+    rs = np.random.RandomState(23)
+    q, g = (jnp.asarray(rs.randn(b, seq, heads * d).astype(np.float32))
+            for _ in range(2))
+    k, v = (jnp.asarray(rs.randn(b, seq, kv_heads * d).astype(np.float32))
+            for _ in range(2))
+    vl = jnp.asarray(lens if lens else [seq] * b, jnp.float32)
+
+    def flashed(q, k, v):
+        return flash_attention(q, k, v, causal=True, num_heads=heads,
+                               num_kv_heads=kv_heads, head_dim=d,
+                               window=window,
+                               valid_len=vl if lens else None)
+
+    def by_hand(q, k, v):
+        q5 = q.reshape(b, seq, kv_heads, group, d)
+        k4, v4 = (t.reshape(b, seq, kv_heads, d) for t in (k, v))
+        s = jnp.einsum("bqngd,bknd->bngqk", q5, k4) * d ** -0.5
+        at, key = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+        keep = key <= at
+        if window is not None:
+            keep = keep & (at - key < window)
+        keep = keep[None, None, None] & (
+            key[None, None, None] < vl[:, None, None, None, None])
+        p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+        p = jnp.where(keep.any(-1, keepdims=True), p, 0.0)
+        return jnp.einsum("bngqk,bknd->bqngd", p, v4).reshape(
+            b, seq, heads * d)
+
+    got_out, vjp = jax.vjp(flashed, q, k, v)
+    got = vjp(g)
+    with jax.default_matmul_precision("highest"):
+        want_out, want_vjp = jax.vjp(by_hand, q, k, v)
+        want = want_vjp(g)
+    assert registry().get("kernels.flash_attention.kv_group").read() == \
+        group
+    assert registry().get("kernels.flash_attention_bwd.kv_group").read() \
+        == group
+    assert [x.shape for x in got] == [q.shape, k.shape, v.shape]
+    live = np.asarray(jnp.arange(seq)[None, :] < vl[:, None])
+    np.testing.assert_allclose(np.asarray(got_out)[live],
+                               np.asarray(want_out)[live], atol=3e-5, rtol=0)
+    # a padded query with no window weighs its row's valid keys (the
+    # kernel's rule); the comparison is of what a loss would read
+    mask = jnp.asarray(live[..., None], jnp.float32)
+    got = jax.vjp(flashed, q, k, v)[1](g * mask)
+    want = want_vjp(g * mask)
+    for a, c, name in zip(got, want, ("dq", "dk", "dv")):
+        a, c = np.asarray(a), np.asarray(c)
+        assert np.abs(a - c).max() <= 2.5e-2 * np.abs(c).max(), name
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(flashed, *a)[1](g))(q, k, v)
+    assert "transpose" not in primitives_outside_kernels(jaxpr.jaxpr)
+
+
+def test_flash_window_and_groups_are_refused_where_they_mean_nothing():
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import flash_attention
+    x = jnp.ones((2, 128, 256), jnp.float32)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(x, x, x, window=16)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(x, x[:, :64], x[:, :64], causal=True, window=16)
+    with pytest.raises(ValueError, match="tokens-major"):
+        flash_attention(x, x, x, causal=True, num_kv_heads=1)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(x, x[:, :, :128], x[:, :, :128], causal=True,
+                        num_heads=4, num_kv_heads=3, head_dim=64)
+    # a head of 64 lanes is no lane group: its key head is not read in place
+    with pytest.raises(ValueError, match="whole lane groups"):
+        flash_attention(x, x[:, :, :128], x[:, :, :128], causal=True,
+                        num_heads=4, num_kv_heads=2, head_dim=64)
 
 
 # the backward's tiles at the shapes the program meets: the BERT cell's,

@@ -101,3 +101,164 @@ def test_moe_expert_parallel_sharded_step():
                     .index(net[0].expert_w1.name)]
     spec = ew1.sharding.spec
     assert spec[0] == "ep", spec
+
+
+# -- routed_experts: the two routers, the gating, the router's input -------------
+
+def _routed_setup(seed=0, tokens=24, units=16, hidden=12, experts=8, held=3):
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, scale=1.0):
+        return (scale * rng.standard_normal(shape)).astype(np.float32)
+    return dict(x=draw(tokens, units), router_x=draw(tokens, units),
+                router_w=draw(experts, units), router_b=draw(experts,
+                                                             scale=0.3),
+                w_gate=draw(held, units, hidden, scale=0.3),
+                w_up=draw(held, units, hidden, scale=0.3),
+                w_down=draw(held, hidden, units, scale=0.3))
+
+
+def _routed_by_hand(a, top_k, first, score, activation, router_x=None,
+                    scale=1.0):
+    """The layer written out token by token in float64."""
+    x = a["x"].astype(np.float64)
+    rx = x if router_x is None else router_x.astype(np.float64)
+    logits = rx @ a["router_w"].astype(np.float64).T
+    held = a["w_gate"].shape[0]
+    y, load = np.zeros_like(x), np.zeros(held)
+    act = {"silu": lambda h: h / (1 + np.exp(-h)),
+           "relu": lambda h: np.maximum(h, 0)}[activation]
+    for t in range(x.shape[0]):
+        if score == "softmax":
+            sel = np.argsort(-logits[t])[:top_k]
+            e = np.exp(logits[t][sel] - logits[t][sel].max())
+            gates = e / e.sum()
+        else:
+            s = 1 / (1 + np.exp(-logits[t]))
+            sel = np.argsort(-(s + a["router_b"]))[:top_k]
+            gates = s[sel] / s[sel].sum() * scale
+        for e_, g in zip(sel, gates):
+            j = e_ - first
+            if 0 <= j < held:
+                load[j] += 1
+                h = act(x[t] @ a["w_gate"][j]) * (x[t] @ a["w_up"][j])
+                y[t] += g * (h @ a["w_down"][j])
+    return y, load
+
+
+@pytest.mark.parametrize("score,activation,own_input", [
+    ("sigmoid", "silu", True), ("softmax", "relu", False),
+    ("softmax", "silu", True), ("sigmoid", "relu", False)])
+def test_routed_experts_routers_gatings_and_router_input(score, activation,
+                                                         own_input):
+    """Each router (sigmoid gates selected with the bias, renormalised and
+    scaled; the softmax over the selected logits, no bias, no scale), each
+    gating, and a router that reads another array than the experts do,
+    against the layer written out token by token."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.moe import routed_experts
+    a = _routed_setup()
+    rx = None if own_input else a["router_x"]
+    with jax.default_matmul_precision("highest"):
+        y, load = routed_experts(
+            *(jnp.asarray(a[k]) for k in ("x", "router_w", "router_b",
+                                          "w_gate", "w_up", "w_down")),
+            top_k=3, first=2, scale=1.7, score=score, activation=activation,
+            router_x=None if rx is None else jnp.asarray(rx))
+    want, want_load = _routed_by_hand(a, 3, 2, score, activation, rx, 1.7)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5, rtol=1e-4)
+    assert np.asarray(load).tolist() == want_load.tolist()
+    assert want_load.sum() > 0
+
+
+def test_routed_experts_softmax_gates_sum_to_one_and_take_no_bias():
+    """Under ``score="softmax"`` the bias and the scale take no part and the
+    gradient reaches the router through the gates alone."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.moe import routed_experts
+    a = {k: jnp.asarray(v) for k, v in _routed_setup(held=8).items()}
+
+    def layer(router_w, router_b, scale):
+        return routed_experts(a["x"], router_w, router_b, a["w_gate"],
+                              a["w_up"], a["w_down"], top_k=2, scale=scale,
+                              score="softmax", activation="relu",
+                              router_x=a["router_x"])[0]
+    base = layer(a["router_w"], a["router_b"], 1.0)
+    assert np.array_equal(np.asarray(base), np.asarray(
+        layer(a["router_w"], a["router_b"] + 5.0, 3.0)))
+    grad = jax.grad(lambda w: jnp.sum(layer(w, a["router_b"], 1.0) ** 2))(
+        a["router_w"])
+    assert float(jnp.abs(grad).max()) > 0
+    with pytest.raises(ValueError, match="score"):
+        routed_experts(a["x"], a["router_w"], a["router_b"], a["w_gate"],
+                       a["w_up"], a["w_down"], top_k=2, score="tanh")
+    with pytest.raises(ValueError, match="activation"):
+        routed_experts(a["x"], a["router_w"], a["router_b"], a["w_gate"],
+                       a["w_up"], a["w_down"], top_k=2, activation="gelu")
+
+
+def _routed_experts_before(x, router_w, router_b, w_gate, w_up, w_down, *,
+                           top_k, first=0, scale=1.0, norm_topk=True):
+    """The function as it was before it took a score, an activation and a
+    router input (a copy, to hold the defaults to)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    g = w_gate.shape[0]
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(jnp.float32),
+        router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST))
+    _, sel = lax.top_k(
+        s + lax.stop_gradient(router_b.astype(jnp.float32)), top_k)
+    gate = jnp.take_along_axis(s, sel, axis=-1)
+    if norm_topk:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    gate = gate * scale
+    local = sel.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < g), local, g)
+    load = jnp.zeros((g + 1,), jnp.int32).at[local].add(1)[:g]
+    order = jnp.argsort(local, stable=True)
+    live = jnp.arange(order.shape[0]) < jnp.sum(load)
+    token = order // top_k
+    rows = jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+    h = lax.ragged_dot(rows, w_gate.astype(x.dtype), load)
+    u = lax.ragged_dot(rows, w_up.astype(x.dtype), load)
+    out = lax.ragged_dot(jax.nn.silu(h) * u, w_down.astype(x.dtype), load)
+    wgt = jnp.where(live, jnp.take(gate.reshape(-1), order), 0.0)
+    out = jnp.where(live[:, None], out, 0) * wgt[:, None].astype(x.dtype)
+    y = jnp.zeros_like(x).at[token].add(out)
+    return y, load.astype(jnp.float32)
+
+
+def test_routed_experts_defaults_are_what_they_were():
+    """With no score, activation or router input given the function traces
+    to the program it traced to before it took them, equation for
+    equation, and gives the same bits, forward and gradient."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.moe import routed_experts
+    a = _routed_setup(seed=4)
+    args = tuple(jnp.asarray(a[k]) for k in (
+        "x", "router_w", "router_b", "w_gate", "w_up", "w_down"))
+    kw = dict(top_k=3, first=2, scale=1.8)
+
+    def strip(fn):
+        # the equations alone: scope names are metadata, not program
+        return str(jax.make_jaxpr(lambda *xs: fn(*xs, **kw))(*args))
+    assert strip(routed_experts) == strip(_routed_experts_before)
+    assert strip(lambda *xs, **k: routed_experts(
+        *xs, score="sigmoid", activation="silu", router_x=None, **k)) == \
+        strip(_routed_experts_before)
+
+    def loss(fn):
+        return jax.value_and_grad(
+            lambda *xs: jnp.sum(fn(*xs, **kw)[0] ** 2),
+            argnums=(0, 1, 3, 4, 5))(*args)
+    (got, got_grads), (want, want_grads) = loss(routed_experts), \
+        loss(_routed_experts_before)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    for g, w in zip(got_grads, want_grads):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
