@@ -15,8 +15,12 @@ of the result for the tokens routed to them, dropping none: the
 token-assignments are sorted by expert, the rows of the held experts
 gathered into one buffer and multiplied group by group
 (``jax.lax.ragged_dot`` over the stacked expert weights; gated by SiLU or
-by ReLU, as the architecture says), weighted and scatter-added back.  The
-router reads the experts' input, or another array of the same rows where
+by ReLU, as the architecture says), weighted and added back into their
+tokens' rows.  The buffer is the worst case and the work is the live rows':
+the gather in, the weighted scatter-add back and the backward of each are
+loops over row tiles that end at the held experts' load, which the device
+knows (``_row_movers``); the rows behind it are neither read nor written.
+The router reads the experts' input, or another array of the same rows where
 the block hands it one (a router that stands before attention reads the
 block's input while the experts read the normed state after it).  A shared
 expert runs beside them on every token where the architecture has one.
@@ -33,6 +37,7 @@ inserts the dispatch/combine all-to-alls over ICI itself.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 from ..gluon.block import HybridBlock
@@ -117,6 +122,118 @@ class MoEFFN(HybridBlock):
         return F.reshape(out, shape=(B, S, D))
 
 
+#: bytes of one row tile of the movers below (``_row_tile``)
+_ROW_TILE_BYTES = 4 << 20
+
+
+def _row_tile(rows, width, itemsize):
+    """Rows of the dispatch buffer that one iteration of a row mover takes:
+    the whole sublanes (eights of rows) whose bytes fit ``_ROW_TILE_BYTES``,
+    and no more than the buffer has."""
+    return min(rows, max(8, _ROW_TILE_BYTES // (width * itemsize) // 8 * 8))
+
+
+@functools.lru_cache(maxsize=None)
+def _row_movers(tokens, tile):
+    """``(dispatch, combine)``: the two passes between a (``tokens``, D)
+    token array and the (R, D) dispatch buffer, each bounded by the live
+    count and not by R (built lazily so that importing this module never
+    imports jax).
+
+    ``dispatch(x, token, n_live)``: ``rows[i] = x[token[i]]`` for ``i <
+    n_live``, nought beyond, handed out twice, once for each grouped matmul
+    that reads it: the two gradients then come back apart and are added
+    tile by tile, where jax would add them over the whole buffer first.
+    ``combine(out, wgt, token, n_live)``:
+    ``y[token[i]] += wgt[i] * out[i]`` over ``i < n_live``; what ``out``
+    holds beyond may be anything.  Each is a ``lax.while_loop`` over tiles
+    of ``tile`` rows that ends with the tile that holds row ``n_live - 1``;
+    a trip count the device decides has no transpose, so each has a
+    backward of its own, which is the other's loop: dispatch's adds both
+    ``d_rows[i]`` into ``dx[token[i]]``, combine's gathers ``dy[token[i]]``
+    for ``d_out`` and ``d_wgt``.  In the tile that straddles ``n_live`` the
+    dead rows are cut off with ``where``, never multiplied by nought."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def sweep(rows, n_live, carry, body):
+        # the last tile of a buffer that is no whole number of them is
+        # moved back to end with the buffer: ``fresh`` are its rows that
+        # the tile before has not had
+        def step(state):
+            i, carry = state
+            start = jnp.minimum(i * tile, rows - tile)
+            at = start + jnp.arange(tile)
+            return i + 1, body(carry, start, at < n_live, at >= i * tile)
+        return lax.while_loop(lambda state: state[0] * tile < n_live, step,
+                              (jnp.int32(0), carry))[1]
+
+    def tile_of(a, start):
+        return lax.dynamic_slice_in_dim(a, start, tile)
+
+    def gather(src, token, n_live, wgt=None, dot=None):
+        """``wgt[i] * src[token[i]]`` for the live ``i`` into a zeroed
+        buffer and, where ``dot`` (R, D) is given, ``<dot[i],
+        src[token[i]]>`` beside it."""
+        def body(carry, start, live, fresh):
+            blk = src.at[tile_of(token, start)].get(
+                mode="promise_in_bounds")
+            out = blk if wgt is None else \
+                blk * tile_of(wgt, start)[:, None].astype(blk.dtype)
+            moved = (lax.dynamic_update_slice_in_dim(
+                carry[0], jnp.where(live[:, None], out, 0), start, 0),)
+            if dot is not None:
+                along = jnp.sum((tile_of(dot, start) * blk)
+                                .astype(wgt.dtype), axis=-1)
+                moved += (lax.dynamic_update_slice_in_dim(
+                    carry[1], jnp.where(live, along, 0), start, 0),)
+            return moved
+        rows = token.shape[0]
+        carry = (jnp.zeros((rows,) + src.shape[1:], src.dtype),)
+        if dot is not None:
+            carry += (jnp.zeros((rows,), wgt.dtype),)
+        return sweep(rows, n_live, carry, body)
+
+    def scatter(srcs, token, n_live, wgt=None):
+        """``out[token[i]] += wgt[i] * sum(src[i] for src in srcs)`` over
+        the live ``i``."""
+        def body(out, start, live, fresh):
+            blk = sum(tile_of(src, start) for src in srcs)
+            if wgt is not None:
+                blk = blk * tile_of(wgt, start)[:, None].astype(blk.dtype)
+            return out.at[tile_of(token, start)].add(
+                jnp.where((live & fresh)[:, None], blk, 0),
+                mode="promise_in_bounds")
+        return sweep(token.shape[0], n_live, jnp.zeros(
+            (tokens,) + srcs[0].shape[1:], srcs[0].dtype), body)
+
+    def dispatch_fwd(x, token, n_live):
+        rows = gather(x, token, n_live)[0]
+        return (rows, rows), (token, n_live)
+
+    def dispatch_bwd(res, d_rows):
+        return scatter(d_rows, *res), None, None
+
+    def combine_fwd(out, wgt, token, n_live):
+        return scatter((out,), token, n_live, wgt), (out, wgt, token, n_live)
+
+    def combine_bwd(res, dy):
+        out, wgt, token, n_live = res
+        return gather(dy, token, n_live, wgt, out) + (None, None)
+
+    @jax.custom_vjp
+    def dispatch(x, token, n_live):
+        return dispatch_fwd(x, token, n_live)[0]
+
+    @jax.custom_vjp
+    def combine(out, wgt, token, n_live):
+        return combine_fwd(out, wgt, token, n_live)[0]
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
 def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
                    first=0, scale=1.0, norm_topk=True, score="sigmoid",
                    activation="silu", router_x=None):
@@ -126,7 +243,11 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
     w_up (G, D, H) and w_down (G, H, D) the ``G`` experts held here, which
     are experts ``first .. first + G - 1``.  Returns ``(y (T, D), load
     (G,) token-assignments per held expert)``.  The dispatch buffer has
-    ``top_k`` x T rows, which every assignment fits.  The router runs in
+    ``top_k`` x T rows, which every assignment fits; that is the worst
+    case, and the passes that move rows between ``x``, the buffer and ``y``
+    (forward and backward) sweep row tiles up to ``sum(load)`` only
+    (``_row_movers``; the gauges ``moe.buffer_rows`` and ``moe.row_tile``
+    are set as this is traced).  The router runs in
     float32 at the highest precision whatever x is kept in: a rounded
     score flips selections.  It reads ``router_x`` (T, D) where that is
     given, else ``x``.
@@ -142,6 +263,7 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from ..observability.registry import registry
 
     if activation not in ("silu", "relu"):
         raise ValueError(f"activation {activation!r} is neither 'silu' nor "
@@ -172,23 +294,34 @@ def routed_experts(x, router_w, router_b, w_gate, w_up, w_down, *, top_k,
         # ones; those of experts held elsewhere sort behind them all
         local = sel.reshape(-1) - first
         local = jnp.where((local >= 0) & (local < g), local, g)
-        load = jnp.zeros((g + 1,), jnp.int32).at[local].add(1)[:g]
+        # counted by comparison: the scatter of top_k x T ones into g + 1
+        # bins reads 0.94 ms on the chip at 98,304 assignments, this 0.21
+        load = jnp.sum(local[:, None] == jnp.arange(g), axis=0,
+                       dtype=jnp.int32)
         order = jnp.argsort(local, stable=True)
-        live = jnp.arange(order.shape[0]) < jnp.sum(load)
+        n_live = jnp.sum(load)
         token = order // top_k
-        # rows beyond the held experts' belong to no group: the grouped
-        # matmul leaves them (and, in the backward, their gradient)
-        # unwritten, so they are cut off here on the way in and, with
-        # that, on the gradient's way out
-        rows = jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+        tile = _row_tile(*token.shape, x.shape[1], x.dtype.itemsize)
+        for name, value, doc in (
+                ("buffer_rows", token.shape[0], "rows of the dispatch "
+                 "buffer, one for every assignment"),
+                ("row_tile", tile, "rows one iteration of a row mover "
+                 "takes")):
+            registry().gauge(f"moe.{name}", doc + ", last layer traced") \
+                .set(value)
+        dispatch, combine = _row_movers(x.shape[0], tile)
+        # the buffer is the worst case, the work the live rows': rows
+        # beyond the held experts' belong to no group, the grouped matmul
+        # leaves them (and, in the backward, their gradient) unwritten, and
+        # no pass here reads or writes a row tile that holds none but them
+        rows, rows_up = dispatch(x, token, n_live)
     with jax.named_scope("experts"):
         h = lax.ragged_dot(rows, w_gate.astype(x.dtype), load)
-        u = lax.ragged_dot(rows, w_up.astype(x.dtype), load)
+        u = lax.ragged_dot(rows_up, w_up.astype(x.dtype), load)
         out = lax.ragged_dot(act(h) * u, w_down.astype(x.dtype), load)
     with jax.named_scope("combine"):
-        wgt = jnp.where(live, jnp.take(gate.reshape(-1), order), 0.0)
-        out = jnp.where(live[:, None], out, 0) * wgt[:, None].astype(x.dtype)
-        y = jnp.zeros_like(x).at[token].add(out)
+        wgt = jnp.take(gate.reshape(-1), order)
+        y = combine(out, wgt, token, n_live)
     return y, load.astype(jnp.float32)
 
 
@@ -277,17 +410,28 @@ def publish_routing(trainer) -> dict:
     """What the last step of ``trainer`` (a ``ShardedTrainer``) wrote into
     its ``SparseMoE`` layers' aux buffers, as gauges: ``moe.expert_load_max``
     and ``moe.expert_load_mean`` (token-assignments per held expert, in the
-    layer where max / mean is worst).  Waits for the step, so call it where
-    the loss is read anyway.  Returns the two."""
+    layer where max / mean is worst); ``moe.live_rows`` (rows of the
+    dispatch buffer that held an assignment, in the layer with the most)
+    and ``moe.rows_moved`` (what one pass of a row mover touched there:
+    whole tiles of ``moe.row_tile`` rows up to the live count; the buffer
+    itself has ``moe.buffer_rows``).  Waits for the step, so call it where
+    the loss is read anyway.  Returns the four."""
     from ..observability.registry import registry
     aux = trainer.aux_values()
     loads = [v for k, v in aux.items() if k.endswith("expert_load")]
     worst = max(loads, key=lambda v: float(v.max()) / max(float(v.mean()),
                                                           1e-9))
-    out = {"expert_load_max": float(worst.max()),
-           "expert_load_mean": float(worst.mean())}
-    for name, value in out.items():
-        registry().gauge(f"moe.{name}", "of the last step read: token-"
-                         "assignments per held expert in the worst layer"
-                         ).set(value)
+    live = max(int(v.sum()) for v in loads)
+    tile = int(registry().gauge("moe.row_tile").value) or 1
+    load_doc = "token-assignments per held expert in the worst layer"
+    rows_doc = "dispatch-buffer rows in the layer with the most live"
+    out = {}
+    for name, value, doc in (
+            ("expert_load_max", float(worst.max()), load_doc),
+            ("expert_load_mean", float(worst.mean()), load_doc),
+            ("live_rows", live, rows_doc),
+            ("rows_moved", tile * -(-live // tile), rows_doc)):
+        registry().gauge(f"moe.{name}", "of the last step read: " + doc) \
+            .set(value)
+        out[name] = value
     return out

@@ -459,6 +459,15 @@ def test_gauges_and_scopes_are_there():
     assert routing["expert_load_max"] >= routing["expert_load_mean"] > 0
     assert registry().get("moe.expert_load_max").value == \
         routing["expert_load_max"]
+    # the row movers' gauges: the buffer is the worst case (top_k x tokens),
+    # a pass stops with the tile that holds the last live row
+    gauges = registry().snapshot()
+    assert gauges["moe.buffer_rows"] == 2 * tokens.size
+    assert 1 <= gauges["moe.row_tile"] <= gauges["moe.buffer_rows"]
+    assert 0 < gauges["moe.live_rows"] == routing["live_rows"] <= \
+        gauges["moe.rows_moved"] == routing["rows_moved"] <= \
+        gauges["moe.live_rows"] + gauges["moe.row_tile"] - 1
+    assert gauges["moe.rows_moved"] % gauges["moe.row_tile"] == 0
 
 
 @pytest.mark.parametrize("op_name,scope,way", [

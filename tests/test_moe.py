@@ -233,32 +233,153 @@ def _routed_experts_before(x, router_w, router_b, w_gate, w_up, w_down, *,
     return y, load.astype(jnp.float32)
 
 
-def test_routed_experts_defaults_are_what_they_were():
-    """With no score, activation or router input given the function traces
-    to the program it traced to before it took them, equation for
-    equation, and gives the same bits, forward and gradient."""
+@pytest.mark.parametrize("held,first", [(3, 2), (8, 0)])
+def test_routed_experts_defaults_are_what_they_were(held, first):
+    """With no score, activation or router input given the function gives
+    what the plain form gave before its row movers followed the live count
+    (every row of the buffer gathered, masked, weighted and scatter-added),
+    forward and the five gradients, within float32 rounding: a token's
+    contributions may be added in another order.  ``held=8``: every
+    assignment goes to a held expert, so every row of the buffer is live."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.parallel.moe import routed_experts
-    a = _routed_setup(seed=4)
+    a = _routed_setup(seed=4, held=held)
     args = tuple(jnp.asarray(a[k]) for k in (
         "x", "router_w", "router_b", "w_gate", "w_up", "w_down"))
-    kw = dict(top_k=3, first=2, scale=1.8)
-
-    def strip(fn):
-        # the equations alone: scope names are metadata, not program
-        return str(jax.make_jaxpr(lambda *xs: fn(*xs, **kw))(*args))
-    assert strip(routed_experts) == strip(_routed_experts_before)
-    assert strip(lambda *xs, **k: routed_experts(
-        *xs, score="sigmoid", activation="silu", router_x=None, **k)) == \
-        strip(_routed_experts_before)
+    kw = dict(top_k=3, first=first, scale=1.8)
 
     def loss(fn):
-        return jax.value_and_grad(
-            lambda *xs: jnp.sum(fn(*xs, **kw)[0] ** 2),
-            argnums=(0, 1, 3, 4, 5))(*args)
-    (got, got_grads), (want, want_grads) = loss(routed_experts), \
-        loss(_routed_experts_before)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-    for g, w in zip(got_grads, want_grads):
+        def f(*xs):
+            y, load = fn(*xs, **kw)
+            return jnp.sum(y ** 2), (y, load)
+        return jax.value_and_grad(f, argnums=(0, 1, 3, 4, 5),
+                                  has_aux=True)(*args)
+    for fn in (routed_experts, lambda *xs, **k: routed_experts(
+            *xs, score="sigmoid", activation="silu", router_x=None, **k)):
+        ((_, (y, load)), grads), ((_, (want, want_load)), want_grads) = \
+            loss(fn), loss(_routed_experts_before)
+        assert np.array_equal(np.asarray(load), np.asarray(want_load))
+        if held == 8:
+            assert float(load.sum()) == 3 * a["x"].shape[0]
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        for g, w in zip(grads, want_grads):
+            scale = float(np.abs(np.asarray(w)).max())
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=2e-6 * scale)
+
+
+# -- the row movers: work bounded by the live count, not the buffer -------------
+
+def _plain_dispatch(x, token, n_live):
+    import jax.numpy as jnp
+    live = jnp.arange(token.shape[0]) < n_live
+    rows = jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+    return rows, rows
+
+
+def _plain_combine(out, wgt, token, n_live, tokens):
+    import jax.numpy as jnp
+    live = jnp.arange(token.shape[0]) < n_live
+    out = jnp.where(live[:, None], out, 0) * \
+        jnp.where(live, wgt, 0.0)[:, None].astype(out.dtype)
+    return jnp.zeros((tokens,) + out.shape[1:], out.dtype).at[token].add(out)
+
+
+@pytest.mark.parametrize("way", ["eager", "jit", "checkpoint"])
+@pytest.mark.parametrize("rows,tile,live", [
+    (rows, tile, live)
+    for rows, tile, lives in (
+        (24, 8, (0, 1, 11, 16, 24)),     # a whole number of tiles
+        (21, 8, (0, 1, 11, 16, 18, 21)),  # the last tile is moved back
+        (6, 6, (0, 1, 3, 6)))             # shorter than the rule's tile
+    for live in lives])
+def test_row_movers_are_the_plain_forms(rows, tile, live, way):
+    """``dispatch`` (which hands its rows out twice and takes a gradient
+    for each) and ``combine`` against ``take`` / ``where`` / ``.at[].add``
+    over the whole buffer: values and the gradients by ``x``, ``out`` and
+    ``wgt``, with NaN in every row (and every cotangent row) at or beyond
+    the live count."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.gluon.block import _keep_named
+    from mxnet_tpu.parallel.moe import _row_movers, _row_tile
+    assert _row_tile(6, 16, 4) == 6 and _row_tile(10 ** 6, 16, 4) % 8 == 0
+    tokens, width = 10, 16
+    rng = np.random.default_rng(rows * 100 + live)
+    x = jnp.asarray(rng.standard_normal((tokens, width)), jnp.float32)
+    token = jnp.asarray(rng.integers(0, tokens, rows), jnp.int32)
+    dead = (np.arange(rows) >= live)
+    out = jnp.asarray(np.where(dead[:, None], np.nan, rng.standard_normal(
+        (rows, width))), jnp.float32)
+    d_rows = tuple(jnp.asarray(np.where(
+        dead[:, None], np.nan, rng.standard_normal((rows, width))),
+        jnp.float32) for _ in range(2))
+    wgt = jnp.asarray(rng.uniform(0.1, 1.0, rows), jnp.float32)
+    dy = jnp.asarray(rng.standard_normal((tokens, width)), jnp.float32)
+    dispatch, combine = _row_movers(tokens, tile)
+
+    def both(dispatch, combine):
+        def fn(x, out, wgt, n_live):
+            return dispatch(x, token, n_live), combine(out, wgt, token,
+                                                       n_live)
+        if way == "checkpoint":
+            fn = jax.checkpoint(fn, policy=_keep_named())
+
+        def run(x, out, wgt, n_live):
+            got, vjp = jax.vjp(lambda *a: fn(*a, n_live), x, out, wgt)
+            return got, vjp((d_rows, dy))
+        return (run if way == "eager" else jax.jit(run))(
+            x, out, wgt, jnp.int32(live))
+    (got, got_grads), (want, want_grads) = both(dispatch, combine), both(
+        _plain_dispatch, lambda o, w, t, n: _plain_combine(o, w, t, n,
+                                                           tokens))
+    for g, w in zip(got[0], want[0]):
         assert np.array_equal(np.asarray(g), np.asarray(w))
+    for g, w in zip(got[1:] + got_grads, want[1:] + want_grads):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6,
+                                   atol=1e-6)
+
+
+def test_no_pass_outside_a_loop_touches_the_whole_buffer():
+    """The mechanism engages: in the traced layer, forward and gradient, no
+    gather, scatter-add or select outside a ``while`` body reads or writes
+    an array of ``top_k`` x T rows by D lanes, and a loop is there."""
+    import jax
+    import jax.numpy as jnp
+    from jax.extend import core as jex_core
+    from mxnet_tpu.parallel.moe import routed_experts
+    a = _routed_setup(seed=2)
+    args = tuple(jnp.asarray(a[k]) for k in (
+        "x", "router_w", "router_b", "w_gate", "w_up", "w_down"))
+    buffer = (3 * a["x"].shape[0], a["x"].shape[1])
+    seen = {"while": 0, "moved": []}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "while":
+                seen["while"] += 1
+                continue
+            if eqn.primitive.name in ("gather", "scatter-add", "scatter_add",
+                                      "select_n") and any(
+                    getattr(v.aval, "shape", None) == buffer
+                    for v in list(eqn.invars) + list(eqn.outvars)):
+                seen["moved"].append(str(eqn))
+            for sub in jax.tree.leaves(
+                    list(eqn.params.values()),
+                    is_leaf=lambda p: isinstance(
+                        p, (jex_core.Jaxpr, jex_core.ClosedJaxpr))):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    walk(sub.jaxpr)
+                elif isinstance(sub, jex_core.Jaxpr):
+                    walk(sub)
+
+    def layer(*xs):
+        return jnp.sum(routed_experts(*xs, top_k=3, first=2)[0] ** 2)
+    walk(jax.make_jaxpr(layer)(*args).jaxpr)
+    assert seen["while"] == 2 and not seen["moved"], seen
+    walk(jax.make_jaxpr(jax.grad(layer, argnums=(0, 1, 3, 4, 5)))(*args)
+         .jaxpr)
+    assert seen["while"] >= 6 and not seen["moved"], seen

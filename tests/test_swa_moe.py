@@ -329,3 +329,7 @@ def test_gauges_and_scopes_are_there(monkeypatch):
     tr.step((tokens,), tokens, batch_size=1)
     routing = publish_routing(tr)
     assert routing["expert_load_max"] >= routing["expert_load_mean"] > 0
+    gauges = registry().snapshot()
+    assert gauges["moe.buffer_rows"] == 2 * tokens.size
+    assert 0 < routing["live_rows"] <= routing["rows_moved"] <= \
+        routing["live_rows"] + gauges["moe.row_tile"] - 1
