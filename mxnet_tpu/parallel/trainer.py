@@ -323,6 +323,9 @@ class ShardedTrainer:
         import jax.numpy as jnp
 
         self._dispatch_metrics = _dispatch_metrics()
+        # {(jitted function, the batch's shapes and dtypes): Compiled};
+        # whatever rebuilds the jits comes through here and empties it
+        self._compiled = {}
         block, loss_blk, remat = self._block, self._loss, self._remat
         _metrics_registry().gauge(
             "trainer.remat_blocks", "blocks the last trainer built "
@@ -941,10 +944,27 @@ class ShardedTrainer:
         number, and its host work lies in three spans (histograms of the
         same names, in microseconds): ``trainer.to_vals_us`` (inputs to
         values, the checks, the step's RNG key), ``trainer.h2d_us`` (the
-        ``device_put``s and the three scalars) and ``trainer.jit_call_us``
-        (the jitted call until it returns, not waited for)."""
+        ``device_put``s: the batch, the key, the three scalars) and
+        ``trainer.jit_call_us`` (the compiled step's call until it returns,
+        not waited for).
+
+        The trainer owns the executable it calls (:meth:`_jit_call`): the
+        first step, and the first with another batch shape or dtype, traces,
+        lowers and compiles the program here, once, under the spans
+        ``trainer.step_trace`` / ``.step_lower`` / ``.step_compile``
+        (``trainer.compile_call_s`` is that whole call,
+        ``trainer.trace_lower_s`` its first two parts).  What the compiled
+        step holds in temporaries on a device is in the gauge
+        ``trainer.step_temp_bytes``, and ``mx.profiler.step_scopes()`` says
+        which scope of the program each of its device instructions belongs
+        to.
+
+        The RNG key and the three scalars are placed as the executable was
+        compiled to take them (replicated over the mesh), as the batch is:
+        a ``Compiled`` refuses an argument committed elsewhere (a key set
+        through ``mx.random.set_state`` from a checkpoint, say) where
+        ``jax.jit`` moved it."""
         import jax
-        import jax.numpy as jnp
         if not self._built:
             self._ensure_built(_to_vals(x), _to_val(y))
         with jax.profiler.StepTraceAnnotation("mx.train",
@@ -984,47 +1004,74 @@ class ShardedTrainer:
                                for v, s in zip(yv, self._y_sh))
                 else:
                     yv = jax.device_put(yv, self._y_sh)
-                t = jnp.asarray(self._t, dtype=jnp.int32)
-                lr = jnp.asarray(self._optimizer.learning_rate,
-                                 dtype=jnp.float32)
-                rescale = jnp.asarray(self._scale / batch_size,
-                                      dtype=jnp.float32)
+                key, t, lr, rescale = jax.device_put(
+                    (key, _np.int32(self._t),
+                     _np.float32(self._optimizer.learning_rate),
+                     _np.float32(self._scale / batch_size)), self._r_sh)
             if self._guard:
                 (self._pvals, self._avals, self._state, lval, self._gstate,
                  self._last_finite) = self._jit_call(
                     self._jit_step, self._pvals, self._avals, self._state,
-                    key, t, lr, rescale, self._gstate, xv, yv)
+                    key, t, lr, rescale, self._gstate, xv, yv, batch=2)
             else:
                 self._pvals, self._avals, self._state, lval = \
                     self._jit_call(
                         self._jit_step, self._pvals, self._avals,
-                        self._state, key, t, lr, rescale, xv, yv)
+                        self._state, key, t, lr, rescale, xv, yv, batch=2)
         if self._sparse_trace_info:
             self._record_sparse_metrics()
         return NDArray(lval, ctx=self._ctx)
 
-    def _jit_call(self, fn, *args):
-        """``fn(*args)`` under the span ``trainer.jit_call_us``, the call
-        un-waited.  A call during which jax traced, lowered or compiled
-        anything is kept out of that histogram: its seconds go to the
-        counters ``trainer.compile_call_s`` / ``trainer.compile_calls``,
-        the part that was tracing and lowering (which no cache saves) to
+    def _jit_call(self, fn, *args, batch: int):
+        """``fn(*args)`` through the executable the trainer owns, the call
+        un-waited.  ``fn`` is one of the trainer's jitted functions and the
+        last ``batch`` arguments are the batch; the table
+        ``{(fn, the batch's shapes and dtypes): jax.stages.Compiled}`` is
+        what ``jax.jit`` kept out of sight.
+
+        A hit is the ``Compiled`` called under the span
+        ``trainer.jit_call_us``.  A miss (the first call, a new batch shape
+        or dtype, the first call after the jits were rebuilt) builds the
+        program here: ``fn.trace(*args)``, ``.lower()``, ``.compile()``,
+        each under a span of its own (``trainer.step_trace``,
+        ``.step_lower``, ``.step_compile``: on the profiler's clock, so a
+        build inside a traced window owns its idle gap).  Such a call is
+        kept out of the ``trainer.jit_call_us`` histogram: its whole
+        seconds go to ``trainer.compile_call_s`` /
+        ``trainer.compile_calls``, the part no cache saves to
         ``trainer.trace_lower_s``, and the gauge ``trainer.compile_step``
-        keeps the number of the last step that did it."""
+        keeps the number of the last step that did it.  Where the train
+        step was built, :func:`_publish_step` says what it holds in memory
+        and hands the executable to ``mx.profiler.step_scopes()``."""
+        import jax
         m = self._dispatch_metrics
-        n0 = sum(c.n for c in m.jax_phases_n)
-        tl0 = sum(c.n for c in m.jax_trace_lower_s)
-        with _span("trainer.jit_call_us", histogram=False,
-                   args={"step_num": self._t}) as sp:
-            out = fn(*args)
-        if sum(c.n for c in m.jax_phases_n) == n0:
+        key = (fn, tuple((v.shape, v.dtype)
+                         for v in jax.tree.leaves(args[-batch:])))
+        compiled = self._compiled.get(key)
+        if compiled is not None:
+            with _span("trainer.jit_call_us", histogram=False,
+                       args={"step_num": self._t}) as sp:
+                out = compiled(*args)
             m.jit_call_us.observe(sp.duration_us)
-        else:
-            m.compile_calls.inc()
-            m.compile_call_s.inc(sp.duration_us / 1e6)
-            m.trace_lower_s.inc(
-                sum(c.n for c in m.jax_trace_lower_s) - tl0)
-            m.compile_step.set(self._t)
+            return out
+        which = {"step_num": self._t}
+        with _span("trainer.jit_call_us", histogram=False, args=which) as sp:
+            with _span("trainer.step_trace", histogram=False,
+                       args=which) as traced:
+                stage = fn.trace(*args)
+            with _span("trainer.step_lower", histogram=False,
+                       args=which) as lowered:
+                stage = stage.lower()
+            with _span("trainer.step_compile", histogram=False, args=which):
+                compiled = stage.compile()
+            self._compiled[key] = compiled
+            if fn is self._jit_step:
+                _publish_step(compiled, self)
+            out = compiled(*args)
+        m.compile_calls.inc()
+        m.compile_call_s.inc(sp.duration_us / 1e6)
+        m.trace_lower_s.inc((traced.duration_us + lowered.duration_us) / 1e6)
+        m.compile_step.set(self._t)
         return out
 
     def _record_sparse_metrics(self) -> None:
@@ -1133,8 +1180,9 @@ class ShardedTrainer:
         with _span("trainer.h2d_us"):
             xv = tuple(jax.device_put(v, s)
                        for v, s in zip(xv, self._x_sh))
+            key = jax.device_put(key, self._r_sh)
         out = self._jit_call(self._jit_fwd, self._pvals, self._avals, key,
-                             xv)
+                             xv, batch=1)
         if isinstance(out, tuple):
             return tuple(NDArray(o, ctx=self._ctx) for o in out)
         return NDArray(out, ctx=self._ctx)
@@ -1160,13 +1208,17 @@ class ShardedTrainer:
 
     def lower_step(self, x, y):
         """The train step for this batch, lowered (``jax.stages.Lowered``)
-        against the live state without running or donating it.
-        ``.compile()`` gives the program as the backend builds it: its
-        ``as_text()`` is where a check reads which kernels
-        (``tpu_custom_call``) and collectives (``reduce-scatter``,
-        ``all-gather``) the step really carries, its
-        ``memory_analysis()`` what it needs on each device.  With a
-        persistent compilation cache on, that compile is a cache read."""
+        against the live state without running or donating it: a second
+        lowering beside the one :meth:`step` made, for a check that needs
+        the text of a step the trainer has not run (``.compile().as_text()``
+        shows which kernels, ``tpu_custom_call``, and collectives,
+        ``reduce-scatter``, ``all-gather``, it carries), or the StableHLO.
+        A step that holds a Pallas kernel is keyed anew at every lowering,
+        so that compile misses the persistent cache.  What the step that
+        *runs* holds in temporaries is published where it was compiled
+        (the gauge ``trainer.step_temp_bytes``, from its
+        ``memory_analysis()``), and ``mx.profiler.step_scopes()`` reads its
+        text."""
         return self.trace_step(x, y).lower()
 
     def _checkpointer(self):
@@ -1532,23 +1584,22 @@ class ShardedTrainer:
 
 
 def _dispatch_metrics():
-    """What :meth:`ShardedTrainer._jit_call` reads and writes: jax's
-    compile counters (``tuning.compile_cache.watch_compiles``) and the
-    trainer's own."""
+    """What :meth:`ShardedTrainer._jit_call` writes.  The process-wide
+    ``compile.*`` counters (``tuning.compile_cache.watch_compiles``) are
+    installed beside them; they fire only where jax compiles and nothing
+    here reads them."""
     import types
     from ..tuning.compile_cache import watch_compiles
-    watched = watch_compiles()
+    watch_compiles()
     reg = _metrics_registry()
     return types.SimpleNamespace(
-        jax_phases_n=[watched[p][1] for p in ("trace", "lower", "backend")],
-        jax_trace_lower_s=[watched[p][0] for p in ("trace", "lower")],
         jit_call_us=reg.histogram(
             "trainer.jit_call_us",
-            help="the jitted call until it returns, un-waited; calls "
-                 "that traced or compiled are not in it"),
+            help="the compiled step's call until it returns, un-waited; "
+                 "calls that built a program are not in it"),
         compile_calls=reg.counter(
             "trainer.compile_calls",
-            "trainer calls during which jax traced, lowered or compiled"),
+            "trainer calls that traced, lowered and compiled a program"),
         compile_call_s=reg.counter(
             "trainer.compile_call_s", "seconds those calls took"),
         trace_lower_s=reg.counter(
@@ -1557,6 +1608,25 @@ def _dispatch_metrics():
         compile_step=reg.gauge(
             "trainer.compile_step",
             "number of the last step whose call compiled"))
+
+
+def _publish_step(compiled, trainer) -> None:
+    """The account of a train step just compiled: the temporaries it
+    holds on a device in the gauge ``trainer.step_temp_bytes``, as
+    ``memory_analysis()`` counts them (a cheap call; a loop's carry is in
+    that sum twice, so it reads above what the buffer assignment allocates:
+    8.64 GB for 7.61 in the SmallThinker cell, PERF.md PR 37), and the
+    executable itself to ``mx.profiler.step_scopes()``, which reads its
+    text when asked, or when ``trainer`` is gone."""
+    from .. import profiler
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        _metrics_registry().gauge(
+            "trainer.step_temp_bytes",
+            "temporaries a device holds for the train step last compiled, "
+            "as memory_analysis() counts them (loop carries twice)").set(
+            mem.temp_size_in_bytes)
+    profiler.publish_step(compiled, trainer)
 
 
 def _np_to_dev(val, ctx):

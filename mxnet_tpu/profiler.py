@@ -17,22 +17,30 @@ Two levels, mirroring the reference:
   (``trace.span`` = ``mx.<name>`` annotations) by name, and the device's
   idle gaps by the span that covers them.  Without a device trace
   ``dumps()`` is the table of engine-op dispatch times it always was.
+- **The compiled step's own table**, ``step_scopes()``: which scope every
+  device instruction of the train step belongs to, from the executable
+  that ``ShardedTrainer`` built and handed over, read when asked.  It gives
+  a scope to what a trace alone leaves without one (``dumps()`` marks that
+  time as inferred) and is what the benchmark's per-layer device times
+  read.
 """
 from __future__ import annotations
 
 import glob
+import io
 import json
 import os
 import re
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional
 
 from .base import MXNetError
 from .engine import engine
 
 __all__ = ["set_config", "set_state", "state", "pause", "resume", "dump",
-           "dumps", "Profiler"]
+           "dumps", "step_scopes", "Profiler"]
 
 
 class Profiler:
@@ -51,6 +59,11 @@ class Profiler:
         self._listener_installed = False
         self._tracing_device = False
         self._xplane: Optional[str] = None   # the last device trace written
+        # the train step a trainer compiled last: its executable while its
+        # trainer lives, its text after, the table once asked (step_scopes)
+        self._step_compiled = None
+        self._step_text: Optional[str] = None
+        self._step_table: Optional[Dict[str, tuple]] = None
         self._t0 = time.perf_counter()
         # ONE timeline for the whole fleet: pid = this process's host
         # index (resolved lazily — profiling may start before the
@@ -222,7 +235,7 @@ class Profiler:
         nothing about the device and are not shown beside a trace."""
         if self._xplane is not None:
             text = format_tables(reduce_trace(load_xplane(self._xplane),
-                                              depth))
+                                              depth, step_scopes()))
             if reset:
                 self._xplane = None
             return text
@@ -467,6 +480,207 @@ def scope_of(op_name: str, depth: int):
     return "/".join(own[:depth]), way
 
 
+# -- the compiled step's own table: instruction -> scope ---------------------
+#
+# A device trace names each operation by its HLO instruction (``fusion.884``)
+# and carries the instruction's ``op_name`` where XLA kept one.  The compiled
+# module has more: every instruction of every computation with its operands,
+# so an instruction that states no scope (a cloned constant, a prefetch's
+# ``copy-start`` / ``copy-done``, the grouped matmul's custom call, whose
+# ``op_name`` is XLA's own ``ragged-dot-none``) can be given the scope of the
+# instructions that read it.  The trainer hands over the executable it built
+# (``parallel/trainer.py:_publish_step``); its text is read when somebody asks.
+
+_WAYS = ("fwd", "recompute", "bwd")
+#: ``name = shape opcode(operands), attributes`` with the shape skipped by hand
+_HLO_NAME = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_REF = re.compile(r"%?([A-Za-z_][\w.\-]*)")
+_HLO_FUSED = re.compile(r"\bfusion\(.*?\bcalls=%?([\w.\-]+)")
+
+
+def publish_step(compiled, owner) -> None:
+    """Keep ``compiled`` (a ``jax.stages.Compiled``; nothing else of the
+    trainer ``owner``: not its jitted function, not an argument) as the
+    step that :func:`step_scopes` answers for.  Nothing is read here.
+
+    A loaded executable keeps its temporaries reserved on the device for
+    as long as it lives (5.2 GB for the BERT cell's step, 7.6 GB for the
+    SmallThinker cell's: PERF.md, PR 38), so it may not outlive its
+    trainer here: when ``owner`` is collected the text is read (0.1-0.3 s
+    for those steps) and the executable let go."""
+    p = Profiler.get()
+    p._step_compiled, p._step_text, p._step_table = compiled, None, None
+    weakref.finalize(owner, _owner_gone, compiled).atexit = False
+
+
+def _owner_gone(compiled) -> None:
+    p = Profiler.get()
+    if p._step_compiled is compiled:
+        p._step_text, p._step_compiled = compiled.as_text(), None
+
+
+def step_scopes() -> Optional[Dict[str, tuple]]:
+    """``{instruction name: (scope, "fwd" | "recompute" | "bwd", inferred)}``
+    for the train step most recently compiled by a ``ShardedTrainer``, or
+    None where none was.  The first call parses the executable's text
+    (``compiled.as_text()``, :func:`scopes_from_hlo`); the table is kept
+    and the executable, or its text, let go."""
+    p = Profiler.get()
+    if p._step_compiled is not None:
+        p._step_text, p._step_compiled = p._step_compiled.as_text(), None
+    if p._step_text is not None:
+        p._step_table, p._step_text = scopes_from_hlo(p._step_text), None
+    return p._step_table
+
+
+def scope_way(op_name: str, depth: int = 6):
+    """:func:`scope_of` with the forward that a rematerialised block runs
+    again as a way of its own: ``(scope, "fwd" | "recompute" | "bwd")``,
+    ``recompute`` taken out of the path before it is cut to ``depth``."""
+    scope, way = scope_of(op_name, depth + 1)
+    parts = scope.split("/") if scope else []
+    if "recompute" in parts:
+        parts.remove("recompute")
+        way = "recompute"
+    return "/".join(parts[:depth]), way
+
+
+def _hlo_instructions(text: str):
+    """[(name, opcode, operand names, op_name)] of every instruction of a
+    module's text outside its fused computations (the trace shows the
+    fusion, not its inside): the entry, ``while`` bodies and conditions,
+    called computations.  As the text lists them: operands before users."""
+    fused = set(_HLO_FUSED.findall(text))
+    rows, skip = [], False
+    for line in io.StringIO(text):      # line by line: the text is tens of MB
+        line = line.rstrip("\n")
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split(" ", 2)
+            skip = (head[1] if head[0] == "ENTRY" else head[0]) \
+                .lstrip("%") in fused
+            continue
+        m = None if skip else _HLO_NAME.match(line)
+        if m is None:
+            continue
+        i = m.end()
+        if line[i] == "(":                      # a tuple's shape
+            i = _closing(line, i) + 1
+        i = line.index(" ", i) + 1
+        j = line.index("(", i)
+        k = _closing(line, j)
+        at = line.find('metadata={op_name="', k)
+        op_name = ""
+        if at >= 0:
+            at += len('metadata={op_name="')
+            op_name = line[at:line.index('"', at)]
+        rows.append((m.group(1), line[i:j],
+                     _HLO_REF.findall(line[j + 1:k]), op_name))
+    names = {r[0] for r in rows}
+    return [(n, code, [o for o in ops if o in names], op)
+            for n, code, ops, op in rows]
+
+
+def _closing(line: str, i: int) -> int:
+    """Index of the ``)`` that closes the ``(`` at ``i``."""
+    depth = 0
+    for j in range(i, len(line)):
+        c = line[j]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if depth == 0:
+                return j
+    raise ValueError(f"unbalanced parentheses in {line[:120]!r}")
+
+
+def scopes_from_hlo(text: str, depth: int = 6) -> Dict[str, tuple]:
+    """The table of :func:`step_scopes` from a compiled module's text.
+
+    An instruction whose ``op_name`` names a scope of the program's states
+    it (:func:`scope_way`), and only stated scopes are evidence.  One that
+    states none is given the scope of the nearest instructions that state
+    one, reached through others that state none (so a ``copy-start``
+    reaches through its ``copy-done``, a cloned constant through the tuple
+    a ``while`` takes, a value through its ``bitcast``): their longest
+    common prefix where they disagree, ``inferred`` true.  Which side is
+    asked first follows from what the instruction is.  One XLA made by
+    itself (no ``op_name``: a prefetch, a layout ``copy``, a zero-fill)
+    serves the instructions that use it: users first, else operands.  One
+    that computes what the program asked for and lost its place (an
+    ``op_name`` that is XLA's own, as the grouped matmul's
+    ``ragged-dot-none``, or a primitive at the step's top level) belongs
+    where its inputs were made: operands first, else users, so the expert
+    weights' gradient is the expert layer's and not the optimizer's that
+    reads it.  The side asked first settles it: where its scopes share no
+    prefix (a weight's prefetch that a block and the optimizer both read)
+    the instruction stays ``("", "fwd", False)``, as do parameters, which
+    say nothing about who reads them; the other side is heard only where
+    its answer lies inside the first's (a grouped matmul whose operands
+    share no more than ``lm/layer*/remat``, one of them being the
+    checkpoint's copy of a weight, and whose user is in ``.../moe/experts``
+    is in ``.../moe/experts``).  The way follows from the order a
+    step runs in (forward, the forward run again, backward): no later than
+    the first of the users, no sooner than the last of the operands.
+    Nothing is inferred from a time or from an instruction's name."""
+    rows = _hlo_instructions(text)
+    table, users = {}, {}
+    for name, _, operands, op_name in rows:
+        table[name] = (*scope_way(op_name, depth), False)
+        for o in operands:
+            users.setdefault(o, []).append(name)
+
+    def nearest(order, neighbours):
+        """{name: the (scope, way)s stated nearest to it on one side}."""
+        seen, nothing = {}, frozenset()
+        for name, code, _, _ in order:
+            found = set()
+            for n in neighbours.get(name, ()):
+                scope, way, _ = table[n]
+                if scope:
+                    found.add((scope, way))
+                else:
+                    found |= seen.get(n, nothing)
+            seen[name] = nothing if code == "parameter" else frozenset(found)
+        return seen
+
+    above = nearest(rows, {name: operands for name, _, operands, _ in rows})
+    below = nearest(reversed(rows), users)
+    inferred = {}
+    for name, code, _, op_name in rows:
+        if table[name][0] or code == "parameter":
+            continue
+        asked = [(below[name], min), (above[name], max)]
+        if op_name:
+            asked.reverse()
+        if not asked[0][0]:
+            asked.reverse()
+        (found, pick), (other, _) = asked
+        got = _agreed(found, pick) if found else None
+        if got:
+            finer = _agreed(other, pick) if other else None
+            if finer and finer[0].startswith(got[0] + "/"):
+                got = finer[0], got[1]
+            inferred[name] = (*got, True)
+    table.update(inferred)
+    return table
+
+
+def _agreed(found, pick):
+    """(longest common prefix, way) of some (scope, way)s; None where the
+    scopes share no prefix."""
+    scopes = [scope.split("/") for scope, _ in found]
+    common = scopes[0]
+    for parts in scopes[1:]:
+        k = 0
+        while k < min(len(common), len(parts)) and common[k] == parts[k]:
+            k += 1
+        common = common[:k]
+    if not common:
+        return None
+    return "/".join(common), pick((w for _, w in found), key=_WAYS.index)
+
+
 def _self_times(events):
     """[[event, self ns]]: each event's duration less that of the events
     nested in it (a ``while`` holds the operations of its body; a span
@@ -483,7 +697,8 @@ def _self_times(events):
     return out
 
 
-def reduce_trace(trace: dict, depth: int = 4) -> dict:
+def reduce_trace(trace: dict, depth: int = 4,
+                 table: Optional[Dict[str, tuple]] = None) -> dict:
     """The three tables, as numbers (seconds, a device plane's average):
 
     ``busy_s``; ``scopes``: {scope: {"fwd": s, "bwd": s}} of device self
@@ -491,11 +706,16 @@ def reduce_trace(trace: dict, depth: int = 4) -> dict:
     ``unscoped``: that rest by operation; ``spans``: {mx.<name>: [count,
     total s, self s]}; ``gaps``: {owner: s} of the idle gaps of 20 us and
     more, each given to the innermost (shortest) ``mx.*`` span that covers
-    most of it."""
+    most of it.
+
+    ``table`` (:func:`step_scopes`) gives an operation whose own
+    ``op_name`` states no scope the one inferred for its instruction;
+    ``inferred``: {scope: s} is the part of ``scopes`` that came so (in
+    a row's sum already), at most six names deep."""
     n = max(len(trace["device"]), 1)
     spans_in = trace["host"]
     busy = 0.0
-    scopes, unscoped, gaps, spans = {}, {}, {}, {}
+    scopes, unscoped, gaps, spans, inferred = {}, {}, {}, {}, {}
     for events in trace["device"].values():
         merged = []
         for _, _, s, d in sorted(events, key=lambda e: e[2]):
@@ -506,10 +726,17 @@ def reduce_trace(trace: dict, depth: int = 4) -> dict:
         busy += sum(e - s for s, e in merged)
         for (hlo, op_name, _, _), self_ns in _self_times(events):
             scope, way = scope_of(op_name, depth)
+            op = hlo.split(" = ", 1)[0].lstrip("%")
+            entry = table.get(op) if table and not scope else None
+            if entry and entry[0]:
+                scope, way = _as_path(*entry[:2], depth)
+                if entry[2]:
+                    inferred[scope] = inferred.get(scope, 0.0) \
+                        + self_ns / n / 1e9
             row = scopes.setdefault(scope, {"fwd": 0.0, "bwd": 0.0})
             row[way] += self_ns / n / 1e9
             if not scope:
-                op = re.sub(r"\.\d+$", "", hlo.split(" = ", 1)[0].lstrip("%"))
+                op = re.sub(r"\.\d+$", "", op)
                 unscoped[op] = unscoped.get(op, 0.0) + self_ns / n / 1e9
         for (_, e0), (s1, _) in zip(merged, merged[1:]):
             if s1 - e0 < SHORT_GAP_NS:
@@ -528,20 +755,39 @@ def reduce_trace(trace: dict, depth: int = 4) -> dict:
             row[1] += dur / 1e9
             row[2] += self_ns / 1e9
     return {"busy_s": busy / n / 1e9, "scopes": scopes,
-            "unscoped": unscoped, "spans": spans, "gaps": gaps}
+            "unscoped": unscoped, "inferred": inferred, "spans": spans,
+            "gaps": gaps}
+
+
+def _as_path(scope: str, way: str, depth: int):
+    """A row of :func:`reduce_trace` for an entry of the step's table:
+    ``recompute`` back in the path where :func:`scope_of` has it (behind
+    the ``remat`` scope that ``ShardedTrainer(remat=...)`` opens; in front
+    where there is none) and, as there, counted with the backward."""
+    parts = scope.split("/")
+    if way == "recompute":
+        at = parts.index("remat") + 1 if "remat" in parts else 0
+        parts.insert(at, "recompute")
+        way = "bwd"
+    return "/".join(parts[:depth]), way
 
 
 def format_tables(red: dict, top: int = 12) -> str:
     busy = red["busy_s"] or float("nan")
+    inferred = red.get("inferred", {})
     out = [f"device self time by scope (busy {red['busy_s']:.6f} s a "
-           f"device)\n",
-           f"{'Scope':<56}{'fwd(s)':>11}{'bwd(s)':>11}{'% busy':>8}\n"]
+           f"device; ~inferred: the part of a row whose operations state "
+           f"no scope and were given their users' or operands')\n",
+           f"{'Scope':<56}{'fwd(s)':>11}{'bwd(s)':>11}{'% busy':>8}"
+           f"{'~inferred(s)':>14}\n"]
     rows = sorted(red["scopes"].items(),
                   key=lambda kv: -(kv[1]["fwd"] + kv[1]["bwd"]))
     for scope, t in rows:
         out.append(f"{scope or '(no scope of the program)':<56}"
                    f"{t['fwd']:>11.6f}{t['bwd']:>11.6f}"
-                   f"{100 * (t['fwd'] + t['bwd']) / busy:>8.2f}\n")
+                   f"{100 * (t['fwd'] + t['bwd']) / busy:>8.2f}"
+                   + (f"{'~':>5}{inferred[scope]:.6f}"
+                      if scope in inferred else "") + "\n")
     if not any(scope for scope, _ in rows):
         out.append("  (this trace's device events carry no op_name)\n")
     elif red["unscoped"]:

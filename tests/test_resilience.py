@@ -210,6 +210,14 @@ def test_transient_step_failure_exhausts_retries():
 
 # -- mid-step failure rollback (ROADMAP 'Known gap' from PR 1) --------------
 
+def _stand_in(st, call):
+    """``call`` in place of every executable the trainer owns (what
+    ``step()`` calls: ``ShardedTrainer._jit_call``); the real ones back."""
+    real = dict(st._compiled)
+    st._compiled.update(dict.fromkeys(real, call))
+    return real
+
+
 def test_midstep_failure_rolls_back_t_and_rng():
     """A failure raised from INSIDE ShardedTrainer.step leaves `_t` and
     the RNG stream advanced; the supervisor must roll both back per
@@ -222,9 +230,9 @@ def test_midstep_failure_rolls_back_t_and_rng():
 
     rt = ResilientTrainer(_build_trainer(), auto_resume=False,
                           retry_on=(ValueError,), retry_base_delay=0.001)
-    rt.step(*bs[0])                      # builds the jit
+    rt.step(*bs[0])                      # builds the step
     st = rt.trainer
-    orig, state = st._jit_step, {"fail": True}
+    (orig,), state = st._compiled.values(), {"fail": True}
 
     def flaky_jit(*a, **kw):
         # dies AFTER step() advanced _t and consumed the RNG key — the
@@ -234,7 +242,7 @@ def test_midstep_failure_rolls_back_t_and_rng():
             raise ValueError("injected mid-step failure")
         return orig(*a, **kw)
 
-    st._jit_step = flaky_jit
+    _stand_in(st, flaky_jit)
     got = [float(rt.step(x, y).asnumpy()) for x, y in bs[1:]]
     assert want == [want[0]] + got       # bit-identical trajectory
     c = rt.counters
@@ -254,7 +262,7 @@ def test_midstep_failure_without_retry_still_rolls_back():
     def dead_jit(*a, **kw):
         raise ValueError("boom")
 
-    st._jit_step = dead_jit
+    _stand_in(st, dead_jit)
     with pytest.raises(ValueError):
         rt.step(*bs[1])
     assert st.num_update == 1            # rolled back, not desynced
@@ -275,14 +283,14 @@ def test_midstep_nonretryable_failure_also_rolls_back():
     def dead_jit(*a, **kw):
         raise ValueError("not transient")
 
-    orig, st._jit_step = st._jit_step, dead_jit
+    orig = _stand_in(st, dead_jit)
     with pytest.raises(ValueError):
         rt.step(*bs[1])
     assert st.num_update == 1                # rolled back
     assert mx.random.get_state() is rng_before
     assert rt.counters["rollbacks"] == 1
     # the trainer is still usable after restoring the real step
-    st._jit_step = orig
+    st._compiled.update(orig)
     rt.step(*bs[1])
     assert st.num_update == 2
 
@@ -303,7 +311,7 @@ def test_refuse_retry_after_donation_consumed():
             v.delete()                   # what real donation leaves
         raise ValueError("dies after donation")
 
-    st._jit_step = donated_then_dead
+    _stand_in(st, donated_then_dead)
     with pytest.raises(MXNetError, match="donated"):
         rt.step(*bs[1])
     assert st.donation_consumed
