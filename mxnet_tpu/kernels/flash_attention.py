@@ -80,6 +80,28 @@ fetched.  Kernels built with a window carry the names
 layer's calls from a full layer's, and whoever reads ``flash_attention_fwd``
 / ``_bwd`` counts both); one built without keeps today's names and code.
 
+Masks only where a mask can change a tile.  A (query tile, key tile) pair
+whose every key is below the row's length, at or under every row's diagonal
+and inside every row's window (``_plain``: three scalar comparisons of what
+a kernel already holds) needs no iota, no compare, no ``where`` and no
+dead-row rule, and each of those is the identity there, so all three kernels
+run such a tile through a body without them and every other tile through the
+masked body, bit for bit the results of masking every tile.  The two bodies
+are one tile function (``masked``) under a ``lax.cond`` inside the sweep's
+one loop (``_sweep_tiles``), and a sweep with two bodies keeps its state (the
+running max, denominator and accumulators) in VMEM scratch that the bodies
+update in place: as values of a loop or a ``cond`` such accumulators, which
+fill the registers several times over, are copied wherever control flow
+joins, and that cost more than the masks (PERF.md section 6, PR 39).  The
+build engages this from the shape alone (``_sweeps``): a causal build of at
+least a lane group of queries whose longest sweep holds ``_SPLIT_TILES`` key
+tiles or more; every other build (no causal mask, one query against a cache,
+a row of two or three key tiles) is the one masked body with its state in
+the loop's carry, as it always was.  Gauges ``kernels.flash_attention.tiles_visited``
+and ``.tiles_plain`` (of one head's sweeps, all rows full: the key tiles
+visited and those that take the body without a mask; 0 where the build has
+one body), and the same two under ``kernels.flash_attention_bwd.``.
+
 The grouped index map.  With ``num_kv_heads`` < ``num_heads`` (tokens-major,
 heads of whole lane groups: another width is refused) the q, output and dq
 blocks are at head ``h`` and the k and v blocks at head ``first + h //
@@ -224,28 +246,89 @@ def _each(parts, width: int, axis: int = 1):
     return whole
 
 
-def _key_tiles(lq: int, lk: int, block_q: int, block_k: int,
-               diagonal: bool, window: int):
-    """(("key_tiles", n), ("key_tiles_causal", n)) of a build with a
-    window: the key tiles that the sweeps of one head's query tiles visit,
-    all rows full, and what the diagonal alone would leave them.  A build
-    without a window publishes neither, so the two gauges are the last
-    window build's whatever was built after it."""
-    if not window:
-        return ()
-    off, visited, causal_only = lk - lq, 0, 0
+def _plain(q0, k0, length, block_q: int, block_k: int, off: int,
+           window: int):
+    """Whether no mask can change the score tile of the ``block_q`` queries
+    from ``q0`` and the ``block_k`` keys from ``k0`` under the causal mask:
+    no key of it is beyond the row's ``length``, above any row's diagonal
+    (the first row's, at key ``q0 + off``, is the lowest) or behind any
+    row's ``window`` (the last row's reaches back least far).  Every weight
+    of such a tile is live, so no row of it is dead either.  Python ints
+    (the gauges) and traced scalars (the kernels) alike."""
+    plain = (k0 + block_k <= length) & (k0 + block_k - 1 <= q0 + off)
+    if window:
+        plain = plain & (k0 > q0 + block_q - 1 + off - window)
+    return plain
+
+
+# the shortest longest sweep, in key tiles, that is given a second loop
+# body: the shortest read to gain (4096 keys, K-major: forward, ``dq`` and
+# ``dkv`` 13, 11 and 3 % faster); at 8 and at 4 (2048 and 1024 keys, K and
+# V resident) the forward lost the 5-7 % that ``dq`` gained (PERF.md
+# section 6, PR 39), and BERT's two tiles a row read slower in PR 29
+_SPLIT_TILES = 16
+
+
+def _sweeps(lq: int, lk: int, block_q: int, block_k: int, causal: bool,
+            window: int, split=None):
+    """(whether the build masks only the tiles a mask can change, its
+    gauges) from the shape alone: of one head's sweeps, all rows full,
+    ``tiles_visited`` key tiles and ``tiles_plain`` that run the body
+    without a mask (0 where one masked body is kept) and, for a build with
+    a window, ``key_tiles`` and ``key_tiles_causal`` (what the window and
+    what the diagonal alone leave the sweeps; a build without a window
+    publishes neither, so the two are the last window build's).  The rule:
+    a causal build of a lane group of queries or more whose longest sweep
+    holds ``_SPLIT_TILES`` key tiles or more; ``split`` given is the
+    tests' own: False keeps the one masked body (their reference), True has
+    two at a causal shape small enough to interpret."""
+    off = lk - lq
+    diagonal = causal and off >= 0
+    visited = causal_only = plain = longest = 0
     for q0 in range(0, _round_up(lq, block_q), block_q):
         end = -(-min(lk, q0 + block_q + off if diagonal else lk) // block_k)
+        start = max(q0 + off - window + 1, 0) // block_k if window else 0
         causal_only += end
-        visited += end - max(q0 + off - window + 1, 0) // block_k
-    return ("key_tiles", visited), ("key_tiles_causal", causal_only)
+        visited += end - start
+        longest = max(longest, end - start)
+        plain += sum(bool(_plain(q0, t * block_k, lk, block_q, block_k, off,
+                                 window)) for t in range(start, end))
+    if split is None:
+        split = causal and lq >= 128 and longest >= _SPLIT_TILES
+    gauges = (("tiles_visited", visited),
+              ("tiles_plain", plain if split else 0))
+    if window:
+        gauges += (("key_tiles", visited), ("key_tiles_causal", causal_only))
+    return split, gauges
+
+
+def _sweep_tiles(lo, hi, tile, plain, held, keep):
+    """The loop of a sweep with two bodies: tile ``t`` of [lo, hi) runs
+    ``tile(t, state, masked)``, without its masks where ``plain(t)`` (a
+    scalar of ``_plain``).  The state goes from ``held()`` to ``keep()``
+    round every tile, that is, it stays in the VMEM scratch those read and
+    write, and the loop carries nothing: accumulators that fill the
+    registers several times over are copied wherever control flow joins
+    when they are a loop's or a ``cond``'s values (two bodies then read
+    slower than one; in place, faster: PERF.md section 6, PR 39)."""
+    from jax import lax
+
+    def body(masked):
+        def step(t):
+            keep(*tile(t, held(), masked))
+        return step
+
+    def either(t, _):
+        lax.cond(plain(t), body(False), body(True), t)
+        return 0
+    lax.fori_loop(lo, hi, either, 0)
 
 
 @functools.lru_cache(maxsize=None)
 def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                 scale: float, dtype_name: str, interpret: bool,
                 blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0),
-                window: int = 0, group: int = 1):
+                window: int = 0, group: int = 1, split=None):
     """The kernel for one call's (unpadded) shape; it takes the operands
     padded as ``_tiling`` says.  ``d`` is the width of a lane block: of
     every operand's last axis the grid's second axis owns ``blocks`` of
@@ -274,7 +357,9 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
     # beyond a query tile's last row weigh nothing for the whole tile, so
     # they bound the sweep as the valid length does; with Lk < Lq some rows
     # see no key and take the dead-row rule below, which reads every tile
-    diagonal = causal and lk >= lq
+    off = lk - lq
+    diagonal = causal and off >= 0
+    split, sweeps = _sweeps(lq, lk, block_q, block_k, causal, window, split)
 
     reg = registry()
     reg.counter("kernels.flash_attention.builds",
@@ -284,9 +369,7 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                         ("grid_steps", bh * blocks * nq * nkv),
                         ("lane_heads", hb),
                         ("tokens_major", int(blocks * hb > 1)),
-                        ("window", window), ("kv_group", group),
-                        *_key_tiles(lq, lk, block_q, block_k, diagonal,
-                                    window)):
+                        ("window", window), ("kv_group", group), *sweeps):
         reg.gauge(f"kernels.flash_attention.{name}",
                   "tiling of the last flash forward kernel built").set(value)
 
@@ -294,45 +377,52 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         """Keys that query tile ``qi`` of a row of length ``vl`` can weigh."""
         if not diagonal:
             return vl
-        return jnp.minimum(vl, (qi + 1) * block_q + (lk - lq))
+        return jnp.minimum(vl, (qi + 1) * block_q + off)
 
     def key_start(qi):
         """The first key that query tile ``qi`` can weigh under the window:
         its first row's, which reaches furthest back."""
-        return jnp.maximum(qi * block_q + (lk - lq) - window + 1, 0)
+        return jnp.maximum(qi * block_q + off - window + 1, 0)
 
-    def sweep(vl, q, k_ref, v_ref, qi, kj, carry):
+    def sweep(vl, q, k_ref, v_ref, qi, kj, held, keep):
         """Online softmax of one query tile over the key tiles of K-major
         block ``kj`` that hold a key below ``vl`` (the tile's key limit): a
         key at or beyond it has weight 0 whether the row is live or dead,
-        so the tiles beyond it are never visited.  Where the block holds
+        so the tiles beyond it are never visited.  The running max,
+        denominator and accumulator come from ``held()`` and go to
+        ``keep()``.  Where the block holds
         two heads, each is swept with the other's lanes of q set to
         nought (its scores are then a contraction over all the lanes, the
         other head's adding exact zeros), and of ``p v``, which comes out
         all lanes wide, each head keeps its own."""
+        # one body: the state is the loop's carry, read before the bounds
+        # are made (the kernel as it was before some builds had two bodies)
+        carry = None if split else held()
         k0 = kj * kv_block
         qs = [_own(q, h, hb) for h in range(hb)]
 
-        def tile(t, carry):
+        def tile(t, carry, masked=True):
             ms, ls, acc = carry
             start = pl.multiple_of(t * block_k, block_k)
             k = k_ref[0, pl.ds(start, block_k), :]
             v = v_ref[0, pl.ds(start, block_k), :]
-            # mask K padding (and the causal upper triangle)
-            k_idx = k0 + start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            kmask = k_idx < vl
-            mask = kmask
-            if causal:
+            # mask K padding (and the causal upper triangle); a tile that
+            # ``_plain`` vouches for has no weight to mask and no dead row
+            if masked:
+                k_idx = k0 + start + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                kmask = k_idx < vl
+                mask = kmask
+            if masked and causal:
                 # bottom-right alignment (the flash/decode convention and
                 # this repo's reference): query i sits at absolute key
                 # position (lk - lq + i), so Lq=1 against a length-N
                 # cache attends ALL N keys
                 q_idx = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
-                mask = mask & (k_idx <= q_idx + (lk - lq))
+                mask = mask & (k_idx <= q_idx + off)
                 if window:
-                    mask = mask & (k_idx > q_idx + (lk - lq) - window)
+                    mask = mask & (k_idx > q_idx + off - window)
             ms_new, ls_new, corrs, pvs = [], [], [], []
             for q, m, l in zip(qs, ms, ls):
                 # operands stay in the input dtype, the scale is applied to
@@ -343,10 +433,11 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                 s = lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())), precision=precision,
                     preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-                s = jnp.where(mask, s, _NEG_INF)
+                if masked:
+                    s = jnp.where(mask, s, _NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
                 p = jnp.exp(s - m_new)
-                if causal:
+                if masked and causal:
                     # rows whose every key so far is masked (causal bound
                     # < 0): the reference softmaxes a uniform -NEG_INF row,
                     # i.e. uniform attention over the valid keys — exp(0)=1
@@ -376,7 +467,12 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         n = jnp.clip(pl.cdiv(vl - k0, block_k), 0, tiles)
         lo = jnp.clip((key_start(qi) - k0) // block_k, 0, tiles) \
             if window else 0
-        return lax.fori_loop(lo, n, tile, carry)
+        if not split:
+            keep(*lax.fori_loop(lo, n, tile, carry))
+            return
+        _sweep_tiles(lo, n, tile, lambda t: _plain(
+            qi * block_q, k0 + t * block_k, vl, block_q, block_k, off,
+            window), held, keep)
 
     def start():
         return ((jnp.full((block_q, 1), _NEG_INF, jnp.float32),) * hb,
@@ -388,21 +484,26 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
         # rows with no valid keys (padded queries) divide by 1 instead
         o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(dtype)
 
-    def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, *carries):
+    def kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, *scratch):
         b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
         # per-sequence valid key length (padding mask support): the tile
         # padding bound ``lk`` is static; vl tightens it per row
         vl = key_limit(jnp.minimum(vl_ref[b], lk), qi)
-        if nkv == 1:
-            _, ls, acc = sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start())
-            finish(o_ref, ls, acc)
+        if not scratch:
+            # K and V resident and one body: the state is the loop's carry
+            sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, start,
+                  lambda ms, ls, acc: finish(o_ref, ls, acc))
             return
-        m_refs, l_refs, acc_ref = carries[:hb], carries[hb:-1], carries[-1]
+        m_refs, l_refs, acc_ref = scratch[:hb], scratch[hb:-1], scratch[-1]
 
         def keep(ms, ls, acc):
             for r, x in zip(m_refs + l_refs, ms + ls):
                 r[...] = x
             acc_ref[...] = acc
+
+        def held():
+            return (tuple(r[...] for r in m_refs),
+                    tuple(r[...] for r in l_refs), acc_ref[...])
 
         @pl.when(kj == 0)
         def _():
@@ -412,9 +513,7 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
                  & ((kj + 1) * kv_block > key_start(qi)) if window
                  else kj * kv_block < vl)
         def _():
-            keep(*sweep(vl, q_ref[0], k_ref, v_ref, qi, kj,
-                        (tuple(r[...] for r in m_refs),
-                         tuple(r[...] for r in l_refs), acc_ref[...])))
+            sweep(vl, q_ref[0], k_ref, v_ref, qi, kj, held, keep)
 
         @pl.when(kj == nkv - 1)
         def _():
@@ -449,7 +548,9 @@ def _build_call(bh: int, lq: int, lk: int, d: int, causal: bool,
             (1, kv_block, dp),
             lambda b, h, i, j, vl: (b, kv_block_of(b, i, j, vl),
                                     at + (h // group if group > 1 else h)))
-    scratch = [] if nkv == 1 else (
+    # the sweep's state between K-major blocks, and between the tiles of a
+    # sweep that has two bodies
+    scratch = [] if nkv == 1 and not split else (
         [pltpu.VMEM((block_q, 1), jnp.float32)] * (2 * hb)
         + [pltpu.VMEM((block_q, dp), jnp.float32)])
     return pl.pallas_call(
@@ -493,7 +594,7 @@ def _bwd_tiling(lq: int, lk: int, d: int, itemsize: int):
 def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                     scale: float, dtype_name: str, interpret: bool,
                     blocks: int = 1, lane_heads: int = 1, first=(0, 0, 0),
-                    window: int = 0, group: int = 1):
+                    window: int = 0, group: int = 1, split=None):
     """The backward's two kernels for one call's (unpadded) shape; they
     take the operands padded as ``_bwd_tiling`` says, and lane blocks as
     the forward does (``_build_call``: ``d`` lanes a block, ``blocks`` of
@@ -565,6 +666,7 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     off = lk - lq
     diagonal = causal and off >= 0          # as the forward's
     hb = lane_heads
+    split, sweeps = _sweeps(lq, lk, block_q, block_k, causal, window, split)
 
     reg = registry()
     reg.counter("kernels.flash_attention_bwd.builds",
@@ -573,9 +675,7 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                         ("grid_steps",
                          bh * blocks * (nq * nkv + nkb * nqb)),
                         ("lane_heads", hb),
-                        ("window", window), ("kv_group", group),
-                        *_key_tiles(lq, lk, block_q, block_k, diagonal,
-                                    window)):
+                        ("window", window), ("kv_group", group), *sweeps):
         reg.gauge(f"kernels.flash_attention_bwd.{name}",
                   "tiling of the last flash backward kernels built"
                   ).set(value)
@@ -623,14 +723,15 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
         return jnp.where(keep, s, _NEG_INF), kmask
 
     def weights(s, kmask, lse, linv, dp_, delta):
-        """(p, ds) of a block from its rows' statistics."""
+        """(p, ds) of a block from its rows' statistics; ``kmask`` None: a
+        tile that ``_plain`` vouches for, which holds no dead row."""
         p = jnp.exp(s - lse)
-        if dead_rows:
+        if dead_rows and kmask is not None:
             dead = lse <= _NEG_INF * 0.5
             p = jnp.where(dead, 0.0 if window
                           else kmask.astype(jnp.float32) * linv, p)
         ds = p * (dp_ - delta)
-        if dead_rows:
+        if dead_rows and kmask is not None:
             ds = jnp.where(dead, 0.0, ds)
         return p, ds
 
@@ -678,18 +779,20 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
 
         cs = rows(c_ref)
 
-        def tile(t, carry):
+        def tile(t, carry, masked=True):
             ms, ls, ns, a, b_ = carry
             start = pl.multiple_of(t * block_k, block_k)
             k = k_ref[0, pl.ds(start, block_k), :].astype(mxu)
             v = v_ref[0, pl.ds(start, block_k), :].astype(mxu)
             ms_new, ls_new, ns_new, corrs, kas, kbs = [], [], [], [], [], []
             for q, g, c, m, l, n in zip(qs, gs, cs, ms, ls, ns):
-                s, kmask = mask(nt(k, q), key, rel, length, qi * block_q,
-                                k0 + start)
+                s = nt(k, q)
+                if masked:
+                    s, kmask = mask(s, key, rel, length, qi * block_q,
+                                    k0 + start)
                 m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                 p = jnp.exp(s - m_new)
-                if dead_rows:
+                if masked and dead_rows:
                     p = jnp.where(m_new <= _NEG_INF * 0.5, 0.0 if window
                                   else kmask.astype(jnp.float32), p)
                 corr = jnp.exp(m - m_new)
@@ -705,13 +808,22 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                     a * corr + _each(kas, dp, 0),
                     b_ * corr + _each(kbs, dp, 0))
 
-        ms, ls, ns, a, b_ = lax.fori_loop(
-            lo, tiles, tile, (rows(m_ref), rows(l_ref), rows(n_ref),
-                             a_ref[...], b_ref[...]))
-        for ref, xs in ((m_ref, ms), (l_ref, ls), (n_ref, ns)):
-            for h, x in enumerate(xs):
-                ref[h:h + 1, :] = x
-        a_ref[...], b_ref[...] = a, b_
+        def held():
+            return (rows(m_ref), rows(l_ref), rows(n_ref), a_ref[...],
+                    b_ref[...])
+
+        def keep(ms, ls, ns, a, b_):
+            for ref, xs in ((m_ref, ms), (l_ref, ls), (n_ref, ns)):
+                for h, x in enumerate(xs):
+                    ref[h:h + 1, :] = x
+            a_ref[...], b_ref[...] = a, b_
+
+        if split:
+            _sweep_tiles(lo, tiles, tile, lambda t: _plain(
+                qi * block_q, k0 + t * block_k, length, block_q, block_k,
+                off, window), held, keep)
+        else:
+            keep(*lax.fori_loop(lo, tiles, tile, held()))
 
         @pl.when(kj == nkv - 1)
         def _():
@@ -792,7 +904,9 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
     key_heads = blocks // group
 
     def dkv_kernel(vl_ref, k_ref, v_ref, q_ref, g_ref, st_ref, dk_ref,
-                   dv_ref, dk_acc, dv_acc):
+                   dv_ref, dk_acc, dv_acc, dk_tile=None, dv_tile=None):
+        # ``dk_tile``, ``dv_tile``: a key tile's sums over its query tiles
+        # where that sweep has two bodies (``_sweep_tiles``)
         b, kb = pl.program_id(0), pl.program_id(2)
         if group > 1:
             gi, qb = pl.program_id(3), pl.program_id(4)
@@ -823,15 +937,17 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                   for h in range(hb)]
             kt = k0 + start
 
-            def query_tile(u, carry):
+            def query_tile(u, carry, masked=True):
                 dk, dv = carry
                 at = pl.ds(pl.multiple_of(u * block_q, block_q), block_q)
                 q, g = scaled(q_ref[0, at, :]), g_ref[0, at, :].astype(mxu)
                 st = st_ref[0, 0, u]
                 dks, dvs = [], []
                 for h, (k, v) in enumerate(zip(ks, vs)):
-                    s, kmask = mask(nt(k, q), key, rel, length,
-                                    q0 + u * block_q, kt)
+                    s, kmask = nt(k, q), None
+                    if masked:
+                        s, kmask = mask(s, key, rel, length,
+                                        q0 + u * block_q, kt)
                     p, ds = weights(s, kmask, st[3 * h:3 * h + 1],
                                     st[3 * h + 2:3 * h + 3], nt(v, g),
                                     st[3 * h + 1:3 * h + 2])
@@ -848,9 +964,21 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
             last = jnp.clip(pl.cdiv(kt + block_k - 1 + window - off - q0,
                                     block_q), 0, q_tiles) \
                 if window else q_tiles
-            dk, dv = lax.fori_loop(
-                first, last, query_tile,
-                (jnp.zeros((block_k, dp), jnp.float32),) * 2)
+            nought = (jnp.zeros((block_k, dp), jnp.float32),) * 2
+            if split:
+                def keep(dk, dv):
+                    dk_tile[...], dv_tile[...] = dk, dv
+
+                def held():
+                    return dk_tile[...], dv_tile[...]
+
+                keep(*nought)
+                _sweep_tiles(first, last, query_tile, lambda u: _plain(
+                    q0 + u * block_q, kt, length, block_q, block_k, off,
+                    window), held, keep)
+                dk, dv = held()
+            else:
+                dk, dv = lax.fori_loop(first, last, query_tile, nought)
             dk_acc[rows, :] += dk
             dv_acc[rows, :] += dv
             return 0
@@ -909,7 +1037,9 @@ def _build_backward(bh: int, lq: int, lk: int, d: int, causal: bool,
                               lambda b, hk, hq, kb, qb, vl: (
                                   b, hq, q_index(b, kb, qb, vl), 0, 0)))],
             out_specs=[kb_spec(0), kb_spec(0)],
-            scratch_shapes=[pltpu.VMEM((key_block, dp), jnp.float32)] * 2),
+            scratch_shapes=[pltpu.VMEM((key_block, dp), jnp.float32)] * 2
+            + [pltpu.VMEM((block_k, dp), jnp.float32)]
+            * (2 if split else 0)),
         out_shape=[jax.ShapeDtypeStruct((bh, lkp, key_heads * dp),
                                         dtype)] * 2,
         compiler_params=pltpu.CompilerParams(

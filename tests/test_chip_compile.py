@@ -107,6 +107,15 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, lk, dtype,
         assert " transpose(" not in text and " copy(" not in text
 
 
+def _build_anew():
+    """The gauges are those of the kernels last BUILT: drop the builders'
+    caches, so that the compile below builds its own."""
+    import importlib
+    fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
+    fa._build_call.cache_clear()
+    fa._build_backward.cache_clear()
+
+
 def _kernel_calls(text, name):
     """Mosaic custom calls of the compiled ``text`` whose kernel is named
     ``name``: XLA names the instruction after the ``pallas_call``, with the
@@ -125,6 +134,7 @@ def _kernel_calls(text, name):
     ((192, 512, 64), None, "float32", True, False, None),
     ((192, 512, 64), None, "bfloat16", True, False, None),
     ((20, 8192, 256), None, "float32", False, True, None),
+    ((20, 8192, 256), None, "bfloat16", False, True, None),
     ((4, 200, 64), 640, "float32", True, True, None),
     ((4, 640, 128), 200, "float32", True, True, None),
     ((30, 2048, 128), None, "float32", False, True, None),
@@ -132,7 +142,8 @@ def _kernel_calls(text, name):
     ((16, 512, 768), None, "bfloat16", True, False, 12),
     ((1, 8192, 5120), None, "float32", False, True, 20),
     ((1, 2048, 3840), None, "float32", False, True, 30),
-], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k", "causal_lk_gt_lq",
+], ids=["bert_cell", "bert_cell_bfloat16", "mla_cell_8k",
+        "mla_cell_8k_bfloat16", "causal_lk_gt_lq",
         "causal_lk_lt_lq_dead_rows", "hybrid_cell_2k",
         "bert_cell_tokens_major", "bert_cell_tokens_major_bfloat16",
         "mla_cell_8k_tokens_major", "hybrid_cell_2k_tokens_major"])
@@ -144,11 +155,16 @@ def test_flash_attention_backward_compiles_for_v5e(
     stack 10.8 GB of carries there; a blocked one made eight block-major
     float32 copies, 1.3 GB).  ``heads``: tokens-major operands, as the
     cells' models hand them over (heads as blocks of the lanes, two of 64
-    to a block): no transposed copy beside the kernels."""
+    to a block): no transposed copy beside the kernels.  What compiles at
+    8192 keys is the build of two loop bodies (240 of a head's 272 key
+    tiles take the one without a mask); the BERT cell's is the one masked
+    body."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
 
+    _build_anew()
     q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((shape[0], lk or shape[1], shape[2]), dtype,
                               sharding=one_chip)
@@ -168,6 +184,10 @@ def test_flash_attention_backward_compiles_for_v5e(
     for name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
         assert _kernel_calls(text, name) == 1, name
     assert _kernel_calls(text, "flash_attention_fwd") <= 1
+    plain = [registry().get(f"kernels.flash_attention{kind}.tiles_plain")
+             .read() for kind in ("", "_bwd")]
+    assert plain == [{8192: 240}.get(shape[1], 0)
+                     if causal and not lk else 0] * 2
     if shape[1] == 8192:
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
     if heads:
@@ -177,23 +197,28 @@ def test_flash_attention_backward_compiles_for_v5e(
 # the window / full attention cell's kernels as its model hands them over:
 # one row of 16,384 tokens, 28 query heads of 128 lanes that read 4 key
 # heads, causal, a window of 4096 in three blocks of four and none in one
-@pytest.mark.parametrize("window,tail", [(4096, "_window"), (None, "")],
-                         ids=["window_block", "full_block"])
-def test_grouped_window_kernels_compile_for_v5e(one_chip, window, tail):
+@pytest.mark.parametrize("window,tail,dtype,plain", [
+    (4096, "_window", "float32", 392), (None, "", "float32", 992),
+    (4096, "_window", "bfloat16", 392)],
+    ids=["window_block", "full_block", "window_block_bfloat16"])
+def test_grouped_window_kernels_compile_for_v5e(one_chip, window, tail,
+                                                dtype, plain):
     """Forward, ``dq`` and ``dkv`` at (28 / 4 heads, 128, 16,384, window
     4096) compile for the chip, one Mosaic call each, the window build's
     under its own names; k and v are read, and dk and dv written, a key head
     wide: the compiled gradient holds no array of a key operand's rows that
     is a query head wide but q's own, the result's and their gradients', no
-    transpose and no copy."""
+    transpose and no copy.  All three are the build of two loop bodies:
+    ``plain`` of a head's key tiles take the one without a mask."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import flash_attention
+    from mxnet_tpu.observability.registry import registry
 
+    _build_anew()
     seq, heads, kv_heads, d = 16384, 28, 4, 128
-    q = jax.ShapeDtypeStruct((1, seq, heads * d), "float32",
-                             sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, seq, kv_heads * d), "float32",
+    q = jax.ShapeDtypeStruct((1, seq, heads * d), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads * d), dtype,
                               sharding=one_chip)
 
     def loss(q, k, v):
@@ -211,11 +236,16 @@ def test_grouped_window_kernels_compile_for_v5e(one_chip, window, tail):
         assert _kernel_calls(text, name) == 1, name
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert " transpose(" not in text and " copy(" not in text
+    for kind in ("", "_bwd"):
+        assert registry().get(
+            f"kernels.flash_attention{kind}.tiles_plain").read() == plain
     # dk and dv leave the kernel 4 heads wide
     dkv = [line for line in text.splitlines()
            if "flash_attention_bwd_dkv" in line.split(" = ")[0]
            and "tpu_custom_call" in line]
-    assert f"f32[1,{seq},{kv_heads * d}]" in dkv[0].split(" custom-call(")[0]
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    assert f"{short}[1,{seq},{kv_heads * d}]" in \
+        dkv[0].split(" custom-call(")[0]
     # q, the result, the cotangent and dq: the only arrays 28 heads wide
     assert compiled.memory_analysis().temp_size_in_bytes < \
         3 * seq * heads * d * 4

@@ -902,6 +902,138 @@ def test_flash_window_kernels_match_masked_softmax(lq, lk, d, window,
             assert not np.asarray(got[0])[2, lq - 1].any()
 
 
+# every kind of tile in one sweep: tiles wholly under the diagonal and inside
+# the window (which run the body without a mask), tiles the diagonal or the
+# window's edge crosses, and tiles a row's length ends in, which the diagonal
+# alone would call interior
+@pytest.mark.parametrize("lq,lk,lanes,blocks,hb,group,window,lens", [
+    (2560, 2560, 256, 1, 1, 1, 0, [2560, 1100, 0]),
+    (700, 1700, 128, 1, 1, 1, 0, [1700, 1300]),
+    (2560, 2560, 128, 1, 1, 1, 1100, [2560, 900]),
+    (1280, 1280, 128, 7, 1, 7, 0, [1280, 1000]),
+    (1536, 1536, 128, 1, 2, 1, 900, [1536, 500])],
+    ids=["causal_k_major_length_inside_and_nought", "lk_gt_lq",
+         "window_edges_and_padded_rows", "group_of_7",
+         "two_heads_of_64_window"])
+def test_flash_split_build_is_the_masked_build_bit_for_bit(
+        lq, lk, lanes, blocks, hb, group, window, lens):
+    """The build that masks only the tiles ``_plain`` does not vouch for
+    (at these sizes by the builders' own ``split=True``: the rule of the
+    shape wants sweeps of sixteen tiles) gives the output, dq, the rows'
+    statistics, dk and dv of the build that masks every tile
+    (``split=False``) exactly: the same
+    products, roundings and order of accumulation.  The scale is a power
+    of two: the CPU backend contracts ``s * scale - m`` into one fused
+    multiply-add where no select stands between the two, which rounds
+    once where the masked body rounds twice, unless the product is exact
+    (Mosaic does no such thing: on the chip the two builds read equal at
+    the cells' own scales, PERF.md section 6, PR 39)."""
+    import jax.numpy as jnp
+    from mxnet_tpu.observability.registry import registry
+    fa = _flash_module()
+
+    b, scale = len(lens), 0.125
+    rs = np.random.RandomState(39)
+    q, g = (jnp.asarray(rs.randn(b, lq, blocks * lanes).astype(np.float32))
+            for _ in range(2))
+    k, v = (jnp.asarray(rs.randn(b, lk, blocks // group * lanes)
+                        .astype(np.float32)) for _ in range(2))
+    vl = jnp.asarray(lens, jnp.int32)
+    _, lqp, _, kvb, lkp, _ = fa._tiling(lq, lk, lanes, 4)
+    _, qb, lqp_b, _, _, _, lkp_b, _ = fa._bwd_tiling(lq, lk, lanes, 4)
+    if lanes == 256:                    # K-major and Q-major blocks
+        assert lkp // kvb > 1 and lqp_b // qb > 1
+    reg = registry()
+
+    def plain_tiles(kind):
+        return tuple(reg.get(f"kernels.flash_attention{kind}.{n}").read()
+                     for n in ("tiles_plain", "tiles_visited"))
+
+    got = {}
+    for split in (False, True):
+        args = (b, lq, lk, lanes, True, scale, "float32", True, blocks, hb,
+                (0, 0, 0), window, group)
+        out = fa._build_call(*args, split=split)(
+            vl, *(fa._pad_to(x, n) for x, n in ((q, lqp), (k, lkp),
+                                                (v, lkp))))
+        fwd_tiles = plain_tiles("")
+        dq_call, dkv_call = fa._build_backward(*args, split=split)
+        qp, gp, kp, vp = (fa._pad_to(x, n) for x, n in (
+            (q, lqp_b), (g, lqp_b), (k, lkp_b), (v, lkp_b)))
+        dq, stats = dq_call(vl, qp, gp, kp, vp)
+        dk, dv = dkv_call(vl, kp, vp, qp, gp, stats)
+        for tiles in (fwd_tiles, plain_tiles("_bwd")):
+            # both bodies are in the split build's sweeps, one in the other's
+            assert (0 < tiles[0] < tiles[1]) if split \
+                else tiles[0] == 0 < tiles[1]
+        got[split] = [np.asarray(x) for x in (out, dq, stats, dk, dv)]
+    for name, a, c in zip(("out", "dq", "stats", "dk", "dv"), got[True],
+                          got[False]):
+        assert np.isfinite(a[..., :lq if name in ("out", "dq") else lk, :]
+                           if name != "stats" else a[a > -1e29]).all(), name
+        assert (a == c).all(), name
+    out, dq, _, dk, _ = got[True]
+    assert np.abs(out).max() > 0.1 and np.abs(dq).max() > 0.1
+    if 0 in lens:                       # no valid key: nothing weighs
+        row = lens.index(0)
+        assert not out[row].any() and not dq[row].any() \
+            and not dk[row].any()
+    if window:
+        # a padded query a window beyond its row's length sees no key, in a
+        # tile that is masked: the dead-row rule gives 0 and no gradient
+        row, n = 1, lens[1]
+        assert n + window < lq
+        assert not out[row, n + window:lq].any()
+        assert not dq[row, n + window:lq].any()
+        assert np.abs(out[row, :n]).min() > 0
+
+
+# the gauges' arithmetic at (512, 256) tiles, and the rule of the shape: a
+# window block and a full block of the window cell (one row of 16,384), the
+# MLA cell, 4096 keys; the hybrid cell (eight tiles a sweep at most), BERT's
+# cell (no causal mask, two key tiles a row) and one query against a long
+# cache keep the one masked body
+@pytest.mark.parametrize("lq,lk,d,causal,window,visited,plain", [
+    (16384, 16384, 128, True, 4096, 504, 392),
+    (16384, 16384, 128, True, 0, 1056, 992),
+    (8192, 8192, 256, True, 0, 272, 240),
+    (4096, 4096, 128, True, 0, 72, 56),
+    (2048, 2048, 128, True, 0, 20, 0),
+    (512, 512, 64, False, 0, 2, 0),
+    (1, 8192, 128, True, 0, 32, 0),
+    (600, 600, 128, True, 0, 5, 0)],
+    ids=["window_cell_window_block", "window_cell_full_block", "mla_cell",
+         "sixteen_tiles_a_sweep", "hybrid_cell", "bert_cell", "decode",
+         "three_tiles_a_sweep"])
+def test_flash_plain_tile_gauges_and_the_rule_of_the_shape(
+        lq, lk, d, causal, window, visited, plain):
+    """``tiles_visited`` / ``tiles_plain`` of a build: of one head's sweeps,
+    all rows full, the key tiles visited and those that run the body
+    without a mask; 0 where the shape keeps the one masked body (then the
+    build is the kernel it was before there were two).  Forward and
+    backward publish the same pair; built, not run."""
+    from mxnet_tpu.observability.registry import registry
+    fa = _flash_module()
+    reg = registry()
+    fa._build_call.cache_clear()
+    fa._build_backward.cache_clear()
+    args = (192 if d == 64 else 1, lq, lk, d, causal, d ** -0.5, "float32",
+            True)
+    fa._build_call(*args, window=window)
+    fa._build_backward(*args, window=window)
+    for kind in ("", "_bwd"):
+        read = tuple(reg.get(f"kernels.flash_attention{kind}.{n}").read()
+                     for n in ("tiles_visited", "tiles_plain"))
+        assert read == (visited, plain), kind
+    # the tests' reference build has no second body whatever the shape,
+    # and the one they force has two (at 2048 keys 12 of the 20 tiles)
+    fa._build_call(*args, window=window, split=False)
+    assert reg.get("kernels.flash_attention.tiles_plain").read() == 0
+    if lq == 2048:
+        fa._build_call(*args, split=True)
+        assert reg.get("kernels.flash_attention.tiles_plain").read() == 12
+
+
 # key heads shared by a group of query heads, read in place: groups of 1,
 # 2 and 7 query heads of a whole lane group (128 lanes) to a key head, with
 # a window and without, with rows' lengths
