@@ -28,16 +28,11 @@ channel, and ``K K^T * Gam`` and ``Q K^T * Gam`` become sums inside the
 channels, ``P_ij = sum_d x_i[d] k_j[d] exp(gamma_i[d] - gamma_j[d])`` (scope
 subchunk); ``W``, ``S'`` and ``O``'s first term take ``exp(gamma)`` channel by
 channel, and the state's decay ``exp(gamma_C)`` is a vector of dk.  ``P`` is
-made with no positive exponent and nothing of tokens x C x dk
-(``_decayed_products``): the chunk is halved again and again, and the pairs
-that a halving puts on two sides of it (``i`` in the second half, ``j`` in the
-first) are one product ``(x_i exp(gamma_i - r)) (k_j exp(r - gamma_j))^T``
-about the last token ``r`` of the first half, both factors at most 1 since
-``gamma`` falls; the diagonal is ``x_i . k_i``.  Each of the log2(C) halvings
-holds factors of tokens / 2 x dk, for a group of ``_GROUP_HEADS`` heads at a
-time, and is made again in the backward (``_channel_rule``); no bound on
-``g`` is relied on (a decay that underflows to 0 gives 0, as the
-recurrence's does).
+made by a pair of Pallas kernels, forward and backward, that hold a chunk in
+VMEM (``decayed_products``): a product about a token between the keys and
+the rows after them, the rest pair by pair, no positive exponent and no
+bound on ``g`` relied on; nothing of tokens x C x dk is made, and nothing but
+``q``, ``k`` and ``gamma`` is kept for the backward.
 
 Everything but the pass ``S -> S'`` is batched over heads and chunks; that
 pass is a ``lax.scan`` over the chunks with two matmuls in its body, and its
@@ -222,49 +217,6 @@ def _state_pass_fn():
     return state_pass
 
 
-def _decayed_products(q, k, gamma):
-    """``(P_k, P_q)`` of one chunk, (..., C, C) each: ``P_x[i, j] = sum_d
-    x_i[d] k_j[d] exp(gamma_i[d] - gamma_j[d])`` for ``i >= j``, nought
-    above; ``q``, ``k``, ``gamma`` (..., C, dk) with ``gamma`` falling along
-    C, C a power of two.  Built up from the diagonal: blocks of ``s`` rows
-    on the diagonal are merged in pairs, ``[[P_first, 0], [X, P_second]]``,
-    where the cross block ``X`` of the pair's two halves is one product about
-    the first half's last token ``r``: ``(x_i exp(gamma_i - gamma_r)) (k_j
-    exp(gamma_r - gamma_j))^T``, no exponent positive."""
-    import jax.numpy as jnp
-
-    c, dk = gamma.shape[-2:]
-    lead = gamma.shape[:-2]
-
-    def cross(s):
-        """The cross blocks of every pair of ``s``-row blocks, (..., C /
-        2s, s, s) for k and for q."""
-        def halves(x):
-            x = x.reshape(lead + (c // (2 * s), 2, s, dk))
-            return x[..., 0, :, :], x[..., 1, :, :]
-        g0, g1 = halves(gamma)
-        r = g0[..., s - 1:, :]
-        right = halves(k)[0] * jnp.exp(r - g0)
-        down = jnp.exp(g1 - r)
-        return tuple(jnp.einsum("...id,...jd->...ij", halves(x)[1] * down,
-                                right, precision=_PRECISION)
-                     for x in (k, q))
-
-    diag = [jnp.sum(x * k, axis=-1)[..., None, None] for x in (k, q)]
-    out, s = diag, 1
-    while s < c:
-        blocks = cross(s)
-        merged = []
-        for p, x in zip(out, blocks):
-            p = p.reshape(lead + (c // (2 * s), 2, s, s))
-            merged.append(jnp.concatenate(
-                [jnp.concatenate([p[..., 0, :, :],
-                                  jnp.zeros_like(p[..., 0, :, :])], -1),
-                 jnp.concatenate([x, p[..., 1, :, :]], -1)], -2))
-        out, s = merged, 2 * s
-    return tuple(p.reshape(lead + (c, c)) for p in out)
-
-
 def gated_delta_rule(q, k, v, g, beta):
     """``q``, ``k`` (B, L, H, dk), ``v`` (B, L, H, dv), ``beta`` (B, L, H),
     ``g`` (B, L, H), one decay a head, or (B, L, H, dk), one a key channel
@@ -353,6 +305,7 @@ def _channel_rule(q, k, v, g, beta, lower, mm, seq, out_dtype):
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from .decayed_products import decayed_products
 
     b, h, n, c, dv = v.shape
 
@@ -368,7 +321,7 @@ def _channel_rule(q, k, v, g, beta, lower, mm, seq, out_dtype):
             grown = jnp.exp(gamma)
             rest = jnp.exp(total[..., None, :] - gamma)
         with jax.named_scope("subchunk"):
-            pk, pq = _decayed_products(q, k, gamma)
+            pk, pq = decayed_products(q, k, gamma)
         with jax.named_scope("kkt"):
             a = jnp.where(jnp.tril(lower, -1), beta[..., None] * pk, 0.0)
         with jax.named_scope("inverse"):

@@ -324,6 +324,32 @@ def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+@pytest.mark.parametrize("lead,c,dk", [((2, 256), 64, 128), ((2, 5), 32, 96),
+                                       ((2, 3), 16, 8)],
+                         ids=["kimi_cell_group", "c32_padded", "c16_padded"])
+def test_decayed_products_kernels_compile_for_v5e(one_chip, monkeypatch,
+                                                  lead, c, dk):
+    """The channel gate's decayed-product kernels, forward and backward: a
+    group of the Kimi cell's rule (2 heads x 256 chunks of 64 x 128), and
+    shorter chunks at widths whose lanes are padded; the gradient holds the
+    two kernels, each once, by name."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import decayed_products as dp
+
+    # the kernels ask the default backend, which is the CPU here
+    monkeypatch.setattr(dp, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct(lead + (c, dk), "float32", sharding=one_chip)
+
+    def loss(q, k, g):
+        pk, pq = dp.decayed_products(q, k, g)
+        return jnp.sum(pk * pk) + jnp.sum(pq * pq)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert _kernel_calls(text, "gdn_decayed_products_fwd") == 1
+    assert _kernel_calls(text, "gdn_decayed_products_bwd") == 1
+
+
 @pytest.mark.parametrize("keeps,calls", [(True, 1), (False, 2)],
                          ids=["output_kept", "nothing_kept"])
 def test_checkpointed_attention_block_runs_the_kernel_once_for_v5e(

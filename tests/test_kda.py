@@ -1,8 +1,9 @@
 """Kimi Linear on the CPU at a tiny size against its plain reference
 (``benchmarks/reference/kda_mla_moe_lm.py``: the delta rule as its
 per-token recurrence): the channel-gated delta rule (``gated_delta_rule``
-with a (B, L, H, dk) gate), the flash kernels with a value width of its own,
-``MLAttention`` without rotary positions or a query bottleneck, the
+with a (B, L, H, dk) gate), its decayed products' kernels against the
+halvings that made them before (``halving_products``), the flash kernels
+with a value width of its own, ``MLAttention`` without rotary positions or a query bottleneck, the
 five-layer ``MLAMoELM`` with ``layer_types``, one chip's share of the
 experts, the expert layer in pieces, and the programs of the four older
 cells, which must trace as the parent's did."""
@@ -19,6 +20,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import autograd, nd
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.transformer import MLAMoELM, MLAttention
+from mxnet_tpu.kernels import decayed_products as dp_mod
 from mxnet_tpu.kernels import gated_delta_rule as gdr_mod
 from mxnet_tpu.kernels.flash_attention import flash_attention
 from mxnet_tpu.kernels.gated_delta_rule import gated_delta_rule
@@ -134,12 +136,14 @@ def _largest(jaxpr):
                 for v in e.outvars if hasattr(v.aval, "shape")), default=0)
 
 
-def test_no_value_of_tokens_by_chunk_by_key_lanes():
+def test_no_value_of_tokens_by_chunk_by_key_lanes(monkeypatch):
     """The rule of one layer, forward and backward, at 512 tokens, 4 heads
     of 32 / 32: no value of tokens x C x dk elements (a (C, C, dk) decay a
     chunk) is ever made; the largest are what the rule with one decay a
     head holds too, a (tokens x heads x dk) operand or the (C, C) pairs of
-    every chunk."""
+    every chunk; and none is larger than the largest the halvings made
+    (the kernels' lanes are padded to 128, a group's (2 x 8, 64, 128)
+    operands no more than the halvings' largest)."""
     seq, h, dk = 512, 4, 32
     args = rule_operands(seq, h=h, dk=dk, dv=32)
     c = gdr_mod.chunk_of(seq)
@@ -148,6 +152,8 @@ def test_no_value_of_tokens_by_chunk_by_key_lanes():
     largest = _largest(jax.make_jaxpr(f)(*args).jaxpr)
     assert largest < seq * c * dk
     assert largest <= seq * h * max(dk, c)
+    monkeypatch.setattr(dp_mod, "decayed_products", halving_products)
+    assert largest <= _largest(jax.make_jaxpr(f)(*args).jaxpr)
 
 
 def test_gauges_and_the_channel_gates_scope():
@@ -160,6 +166,146 @@ def test_gauges_and_the_channel_gates_scope():
         q, k, v, g[..., 0], beta).as_text(debug_info=True)
     assert registry().get("gdn.gate_lanes").value == 1
     assert "subchunk" not in text
+
+
+# -- the decayed products' kernels ------------------------------------------------
+
+def halving_products(q, k, gamma):
+    """The oracle: the decayed products as the rule made them before its
+    kernels, in ``jnp``.  Blocks of ``s`` rows on the diagonal are merged in
+    pairs, ``[[P_first, 0], [X, P_second]]``, where the cross block ``X``
+    of the pair's two halves is one product about the first half's last
+    token ``r``: ``(x_i exp(gamma_i - gamma_r)) (k_j exp(gamma_r -
+    gamma_j))^T``, no exponent positive."""
+    c, dk = gamma.shape[-2:]
+    lead = gamma.shape[:-2]
+
+    def cross(s):
+        def halves(x):
+            x = x.reshape(lead + (c // (2 * s), 2, s, dk))
+            return x[..., 0, :, :], x[..., 1, :, :]
+        g0, g1 = halves(gamma)
+        r = g0[..., s - 1:, :]
+        right = halves(k)[0] * jnp.exp(r - g0)
+        down = jnp.exp(g1 - r)
+        return tuple(jnp.einsum("...id,...jd->...ij", halves(x)[1] * down,
+                                right, precision="highest")
+                     for x in (k, q))
+
+    out, s = [jnp.sum(x * k, axis=-1)[..., None, None] for x in (k, q)], 1
+    while s < c:
+        merged = []
+        for p, x in zip(out, cross(s)):
+            p = p.reshape(lead + (c // (2 * s), 2, s, s))
+            merged.append(jnp.concatenate(
+                [jnp.concatenate([p[..., 0, :, :],
+                                  jnp.zeros_like(p[..., 0, :, :])], -1),
+                 jnp.concatenate([x, p[..., 1, :, :]], -1)], -2))
+        out, s = merged, 2 * s
+    return tuple(p.reshape(lead + (c, c)) for p in out)
+
+
+def product_operands(c, dk, strength=1.0, lead=(2, 3), seed=0):
+    """q, k (unit rows) and gamma, the running sum of a gate of up to
+    e^-strength a token, (lead..., C, dk); and a cotangent for each
+    product, lower triangle and all."""
+    r = np.random.default_rng(seed)
+    q, k = (r.normal(size=lead + (c, dk)).astype(np.float32)
+            for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -strength * r.uniform(0.0, 1.0, lead + (c, dk)).astype(np.float32)
+    cts = tuple(jnp.asarray(r.normal(size=lead + (c, c)).astype(np.float32))
+                for _ in range(2))
+    return [jnp.asarray(x) for x in (q, k, np.cumsum(g, axis=-2))], cts
+
+
+def _near(got, want, rel):
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=0, atol=rel * float(
+            jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("c,dk,strength", [
+    (c, dk, 1.0) for c in (16, 32, 64) for dk in (8, 32, 128)] + [
+    (64, 128, 60.0)])
+def test_products_kernels_are_the_halvings(c, dk, strength):
+    """The kernels (interpret mode; lanes padded to 128 below it) against
+    the halvings, with decays of up to e^-strength a token (at 60 the
+    pairs whose decay underflows are nought in both): P_k and P_q within
+    1e-6 of their size, and dq, dk and dgamma against ``jax.vjp`` of the
+    halvings, within 2e-6 of theirs, from cotangents that are not nought
+    above the diagonal (``Q K^T``'s is not); all finite."""
+    args, cts = product_operands(c, dk, strength)
+    got, vjp = jax.vjp(dp_mod.decayed_products, *args)
+    want, vjp_want = jax.vjp(halving_products, *args)
+    grads = vjp(cts)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got + grads)
+    _near(got, want, 1e-6)
+    for a in got:
+        assert not np.any(np.triu(np.asarray(a), 1))
+    _near(grads, vjp_want(cts), 2e-6)
+
+
+def test_products_kernels_leave_the_diagonal_out_of_dgamma():
+    """A cotangent on the diagonals alone: ``x_i . k_i`` does not depend on
+    gamma, so dgamma is nought to the last bit (the pair's two terms would
+    cancel only to rounding, which no decay shrinks), and dq, dk are the
+    diagonal's own ``D_ii k_i`` and ``2 D_ii k_i + D_ii q_i``."""
+    args, cts = product_operands(64, 128, strength=60.0)
+    eye = jnp.eye(64, dtype=bool)
+    cts = tuple(jnp.where(eye, x, 0.0) for x in cts)
+    dq, dk, dgamma = jax.vjp(dp_mod.decayed_products, *args)[1](cts)
+    assert not np.any(np.asarray(dgamma))
+    q, k, _ = args
+    dkk, dqq = (jnp.diagonal(x, axis1=-2, axis2=-1)[..., None] for x in cts)
+    _near((dq, dk), (dqq * k, 2.0 * dkk * k + dqq * q), 1e-6)
+
+
+@pytest.mark.parametrize("strength", [1.0, 60.0])
+def test_products_kernels_dgamma_sums_to_nought_over_a_chunk(strength):
+    """P reads gamma through differences inside a chunk alone, so dgamma
+    sums to nought over each chunk, channel by channel: the kernel's within
+    1.5e-7 of its size, its chunk mean taken off (the two sums' rounding
+    leaves up to 3.3e-7 under the strong gate, the halvings 7e-8)."""
+    args, cts = product_operands(64, 128, strength)
+    dgamma = np.asarray(jax.vjp(dp_mod.decayed_products, *args)[1](cts)[2],
+                        np.float64)
+    size = np.sum(np.abs(dgamma), axis=-2)
+    assert np.all(np.abs(np.sum(dgamma, axis=-2)) <= 1.5e-7 * size)
+
+
+def test_products_kernels_sit_under_subchunk_and_count(monkeypatch):
+    """The channel rule's gradient lowered for the chip (``jax.export``,
+    no chip needed): its Mosaic calls are the forward kernel, made again
+    by the map body's checkpoint, and the backward kernel, each located
+    under the scope ``subchunk`` (which ``gdn_channel_gate_device_ms.train``
+    reads); ``gdn.products_kernel_calls`` counts each call traced."""
+    from jax import export
+    monkeypatch.setattr(dp_mod, "_interpret", lambda: False)
+    S = jax.ShapeDtypeStruct
+    args = [S((1, 80, 2, 8), jnp.float32)] * 4 + [S((1, 80, 2), jnp.float32)]
+    calls = registry().counter("gdn.products_kernel_calls")
+    before = calls.value
+    text = export.export(jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)[0]), argnums=range(5))),
+        platforms=("tpu",))(*args).mlir_module()
+    assert calls.value - before == 3
+    assert text.count("@tpu_custom_call") == 3
+    located = re.findall(r'loc\("([^"]*/gdn_decayed_products_(?:fwd|bwd))/',
+                         text)
+    assert sorted(x.rsplit("_", 1)[1] for x in located) == \
+        ["bwd", "fwd", "fwd"]
+    assert all(x.split("/")[-2] == "subchunk" for x in located)
+
+
+@pytest.mark.parametrize("chunks,block", [(4, 4), (512, 8), (12, 6), (7, 7),
+                                          (11, 1)])
+def test_products_grid_step_divides_the_chunks(chunks, block):
+    """A grid step holds the most chunks, up to 8, that divide the call's:
+    two heads of the Kimi cell's 256 chunks take 8 a step."""
+    assert dp_mod._block_of(chunks) == block
 
 
 # -- flash attention with a value width of its own --------------------------------
