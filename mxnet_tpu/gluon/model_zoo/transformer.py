@@ -32,7 +32,8 @@ __all__ = ["SlidingWindowSelfAttention", "LongformerEncoderCell",
            "TransformerDecoder", "TransformerNMT", "BERTEncoder",
            "BERTModel", "bert_base", "bert_small", "transformer_nmt_base",
            "CausalLMCell", "CausalLM", "causal_lm_small",
-           "MLAttention", "MLADecoderCell", "MTPModule", "MLAMoELM",
+           "MLAttention", "HyperConnection", "MLADecoderCell", "MTPModule",
+           "MLAMoELM",
            "GatedDeltaNet", "QKNormAttention", "HybridDecoderCell",
            "OlmoHybridLM", "GroupedQueryAttention", "WindowMoEDecoderCell",
            "WindowMoELM", "TP_RULES"]
@@ -976,17 +977,26 @@ class MLAttention(HybridBlock):
     head, rotated by position where ``rope`` (with ``rope`` False those
     lanes carry no position: Kimi Linear's ``mla_use_nope``).  A head's
     query and key are ``[nope | rope]``, its value ``v_head_dim`` wide (the
-    flash kernels take it at its own width)."""
+    flash kernels take it at its own width).  ``rope_scaling``: None, or
+    the configuration's YaRN ``rope_scaling``, which sets the rotary
+    frequencies (the ``rope`` op's ``scaling``) and multiplies the softmax
+    scale by ``yarn_softmax_factor``."""
 
     def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-                 rope_theta=10000.0, epsilon=1e-5, rope=True, prefix=None,
-                 params=None):
+                 rope_theta=10000.0, epsilon=1e-5, rope=True,
+                 rope_scaling=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        from ...ndarray.ops_nn import yarn_softmax_factor
         self._heads, self._nope, self._rope, self._vd = \
             num_heads, qk_nope_head_dim, qk_rope_head_dim, v_head_dim
         self._kvr, self._theta, self._rotate = kv_lora_rank, rope_theta, rope
         self._lora_q = q_lora_rank is not None
+        # the op's keyword as hashable pairs, so that its function is cached
+        self._rope_kw = {} if rope_scaling is None else {
+            "scaling": tuple(sorted(dict(rope_scaling).items()))}
+        self._softmax_factor = 1.0 if rope_scaling is None else \
+            yarn_softmax_factor(dict(rope_scaling))
         qk = qk_nope_head_dim + qk_rope_head_dim
 
         def lin(out, inp, name):
@@ -1029,10 +1039,11 @@ class MLAttention(HybridBlock):
                                  size=h), dim=-1)
         if self._rotate:
             with jax.named_scope("rope"):
-                q = F.rope(q, base=self._theta, rotary_dim=rd, seq_axis=1)
+                q = F.rope(q, base=self._theta, rotary_dim=rd, seq_axis=1,
+                           **self._rope_kw)
                 k = shared_key(F.rope(
                     F.slice_axis(ckv, axis=-1, begin=self._kvr, end=None),
-                    base=self._theta, seq_axis=1))
+                    base=self._theta, seq_axis=1, **self._rope_kw))
         else:
             k = shared_key(F.slice_axis(ckv, axis=-1, begin=self._kvr,
                                         end=None))
@@ -1050,11 +1061,44 @@ class MLAttention(HybridBlock):
                              shape=(b * h, s, width))
         q, k, v = heads_first(q, nope + rd), heads_first(k, nope + rd), \
             heads_first(v, vd)
-        out = _causal_attention(F, q, k, v, 1.0 / math.sqrt(nope + rd),
+        out = _causal_attention(F, q, k, v,
+                                self._softmax_factor / math.sqrt(nope + rd),
                                 kernel_serves=vd <= nope + rd)
         out = F.transpose(F.reshape(out, shape=(b, h, s, vd)),
                           axes=(0, 2, 1, 3))
         return self.proj(F.reshape(out, shape=(b, s, h * vd)))
+
+
+class HyperConnection(HybridBlock):
+    """One sublayer's manifold-constrained hyper-connection
+    (``kernels/hyper_connection.py`` has the equations) over ``streams``
+    residual streams of ``units``: ``gamma`` the coefficient norm's gain,
+    ``phi`` (streams^2 + 2 streams, streams x units), ``bias``, ``alpha``
+    (pre, post, res).  Called on the state X (B, S, streams, units) it
+    returns ``(x_in, h_post, h_res)`` in float32, the sublayer's input
+    (B, S, units) and the coefficients, under the scopes
+    ``hc/{coeff,sinkhorn,pre}``; ``F.hyper_connection_mix(X, y, h_post,
+    h_res)`` with the sublayer's output ``y`` is the new state (scope
+    ``hc/mix``), and needs no parameter."""
+
+    def __init__(self, units, streams, sinkhorn_iters=20, eps=1e-6,
+                 clamp=(-30.0, 30.0), prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        from ...initializer import Constant, Normal
+        self._kw = dict(iters=sinkhorn_iters, eps=eps,
+                        clamp=tuple(float(c) for c in clamp))
+        width, outs = streams * units, streams * (streams + 2)
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(width,),
+                                         init="ones")
+            self.phi = self.params.get("phi", shape=(outs, width),
+                                       init=Normal(0.02))
+            self.bias = self.params.get("bias", shape=(outs,), init="zeros")
+            self.alpha = self.params.get("alpha", shape=(3,),
+                                         init=Constant(0.01))
+
+    def hybrid_forward(self, F, x, gamma, phi, bias, alpha):
+        return F.hyper_connection_pre(x, phi, bias, alpha, gamma, **self._kw)
 
 
 class MLADecoderCell(HybridBlock):
@@ -1062,12 +1106,17 @@ class MLADecoderCell(HybridBlock):
     FFN(RMSNorm(x))`` with ``ffn`` a dense SwiGLU or a sparse-expert layer;
     the mixer, ``attention()``, is the block's attribute of the name
     ``mixer`` (``mla``: latent attention; ``gdn``: the channel-gated delta
-    rule)."""
+    rule).  With ``hc_mult`` streams (mHC) the block's input and output are
+    (B, S, hc_mult, units) and each sublayer, norm and mixer or norm and
+    FFN, sits inside a ``HyperConnection`` (``attn_hc``, ``ffn_hc``) in
+    place of the plain residual add."""
 
     def __init__(self, units, attention, ffn, epsilon=1e-5, prefix=None,
-                 params=None, mixer="mla"):
+                 params=None, mixer="mla", hc_mult=None, hc_sinkhorn_iters=20,
+                 hc_eps=1e-6, hc_clamp=(-30.0, 30.0)):
         super().__init__(prefix=prefix, params=params)
         self._mixer = mixer
+        self._hc = hc_mult is not None
         with self.name_scope():
             self.attn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
                                      prefix="attn_norm_")
@@ -1075,10 +1124,23 @@ class MLADecoderCell(HybridBlock):
             self.ffn_norm = RMSNorm(epsilon=epsilon, in_channels=units,
                                     prefix="ffn_norm_")
             self.ffn = ffn()
+            if self._hc:
+                hc = dict(streams=hc_mult, sinkhorn_iters=hc_sinkhorn_iters,
+                          eps=hc_eps, clamp=hc_clamp)
+                self.attn_hc = HyperConnection(units, prefix="attn_hc_", **hc)
+                self.ffn_hc = HyperConnection(units, prefix="ffn_hc_", **hc)
 
     def hybrid_forward(self, F, x):
-        x = x + getattr(self, self._mixer)(self.attn_norm(x))
-        return x + self.ffn(self.ffn_norm(x))
+        if not self._hc:
+            x = x + getattr(self, self._mixer)(self.attn_norm(x))
+            return x + self.ffn(self.ffn_norm(x))
+        for hc, sublayer in (
+                (self.attn_hc, lambda h: getattr(self, self._mixer)(
+                    self.attn_norm(h))),
+                (self.ffn_hc, lambda h: self.ffn(self.ffn_norm(h)))):
+            x_in, h_post, h_res = hc(x)
+            x = F.hyper_connection_mix(x, sublayer(x_in), h_post, h_res)
+        return x
 
 
 class MTPModule(HybridBlock):
@@ -1086,10 +1148,15 @@ class MTPModule(HybridBlock):
     (before its final norm) and the embedding of the NEXT token, each
     normed, concatenated ``[embedding ; hidden]`` and projected back to the
     width; one decoder cell; a final norm of its own.  The embedding and
-    the head are the model's."""
+    the head are the model's.  With ``hc_mult`` streams the projection is
+    spread into streams of its own, the cell runs on them, and they are
+    summed before the final norm (the hidden state is the main stack's
+    streams summed)."""
 
-    def __init__(self, units, cell, epsilon=1e-5, prefix=None, params=None):
+    def __init__(self, units, cell, epsilon=1e-5, prefix=None, params=None,
+                 hc_mult=None):
         super().__init__(prefix=prefix, params=params)
+        self._streams = hc_mult
         with self.name_scope():
             self.enorm = RMSNorm(epsilon=epsilon, in_channels=units,
                                  prefix="enorm_")
@@ -1104,7 +1171,15 @@ class MTPModule(HybridBlock):
     def hybrid_forward(self, F, hidden, next_embed):
         h = self.eh_proj(F.concat(self.enorm(next_embed), self.hnorm(hidden),
                                   dim=-1))
-        return self.final_norm(self.cell(h))
+        if self._streams is None:
+            return self.final_norm(self.cell(h))
+        return self.final_norm(F.sum(self.cell(_spread(F, h, self._streams)),
+                                     axis=2))
+
+
+def _spread(F, x, streams):
+    """(B, S, C) -> (B, S, streams, C): the same values in every stream."""
+    return F.broadcast_axis(F.expand_dims(x, axis=2), axis=2, size=streams)
 
 
 class MLAMoELM(HybridBlock):
@@ -1120,7 +1195,14 @@ class MLAMoELM(HybridBlock):
     heads of ``linear_head_dim`` lanes with a convolution of
     ``linear_conv_size`` taps (Kimi Linear).  ``mla_rope`` False: the MLA
     blocks take no rotary position; ``q_lora_rank`` None: one query
-    projection.
+    projection; ``rope_scaling``: YaRN's, for the MLA blocks.
+
+    ``hc_mult`` (None: the plain residual path) streams of manifold-
+    constrained hyper-connections (``HyperConnection``, with
+    ``hc_sinkhorn_iters``, ``hc_eps`` and ``hc_clamp``) around every
+    sublayer: the embedding goes into every stream as it is, the streams
+    are summed before the final norm, and the MTP module takes that sum and
+    runs its cell on streams of its own.
 
     ``net(tokens)`` returns the logits (B, S, vocab), and with an MTP
     module ``(logits, mtp_logits)``: ``mtp_logits[:, i]`` scores token
@@ -1136,7 +1218,9 @@ class MLAMoELM(HybridBlock):
                  norm_topk=True, first_dense=1, num_mtp=1, rope_theta=10000.0,
                  epsilon=1e-5, layer_types=None, linear_num_heads=None,
                  linear_head_dim=None, linear_conv_size=4, mla_rope=True,
-                 prefix=None, params=None):
+                 rope_scaling=None, hc_mult=None, hc_sinkhorn_iters=20,
+                 hc_eps=1e-6, hc_clamp=(-30.0, 30.0), prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         import functools
         from ...parallel.moe import SparseMoE
@@ -1150,7 +1234,8 @@ class MLAMoELM(HybridBlock):
         attention = functools.partial(
             MLAttention, units, num_heads, q_lora_rank, kv_lora_rank,
             qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-            rope_theta=rope_theta, epsilon=epsilon, rope=mla_rope)
+            rope_theta=rope_theta, epsilon=epsilon, rope=mla_rope,
+            rope_scaling=rope_scaling)
         mixers = {"full_attention": (attention, "mla")}
         if "linear_attention" in kinds:
             mixers["linear_attention"] = (functools.partial(
@@ -1167,12 +1252,17 @@ class MLAMoELM(HybridBlock):
             shared_hidden=moe_hidden_size * num_shared_experts,
             routed_scale=routed_scale, norm_topk=norm_topk, prefix="moe_")
 
+        hc = {} if hc_mult is None else dict(
+            hc_mult=hc_mult, hc_sinkhorn_iters=hc_sinkhorn_iters,
+            hc_eps=hc_eps, hc_clamp=hc_clamp)
+        self._streams = hc_mult
+
         def cell(i, prefix, kind="full_attention"):
             mixer, name = mixers[kind]
             return MLADecoderCell(units, mixer,
                                   dense if i < first_dense else sparse,
                                   epsilon=epsilon, prefix=prefix,
-                                  mixer=name)
+                                  mixer=name, **hc)
         with self.name_scope():
             self.embed = Embedding(vocab_size, units, prefix="embed_")
             self.cells = HybridSequential(prefix="")
@@ -1184,7 +1274,18 @@ class MLAMoELM(HybridBlock):
                               in_units=units, prefix="head_")
             self.mtp = MTPModule(
                 units, functools.partial(cell, first_dense),
-                epsilon=epsilon, prefix="mtp_") if num_mtp else None
+                epsilon=epsilon, prefix="mtp_", hc_mult=hc_mult) \
+                if num_mtp else None
+        if hc_mult is not None:
+            from ...observability.registry import registry
+            for name, value, doc in (
+                    ("streams", hc_mult, "residual streams a token"),
+                    ("sinkhorn_iters", hc_sinkhorn_iters, "Sinkhorn-Knopp "
+                     "steps of a sublayer's H_res"),
+                    ("sublayers", 2 * (num_layers + num_mtp), "sublayers "
+                     "inside a hyper-connection")):
+                registry().gauge(f"hc.{name}", doc + ", model last built") \
+                    .set(value)
 
     @property
     def remat_blocks(self):
@@ -1192,8 +1293,18 @@ class MLAMoELM(HybridBlock):
 
     def hybrid_forward(self, F, tokens):
         x = self.embed(tokens)
+        if self._streams is not None:
+            x = _spread(F, x, self._streams)
+            from ...observability.registry import registry
+            registry().gauge(
+                "hc.saved_stream_bytes", "bytes of the streams that enter "
+                "the main stack's blocks, what they keep when "
+                "rematerialised, model last traced").set(
+                len(self.cells) * x.size * x.dtype.itemsize)
         for cell in self.cells:
             x = cell(x)
+        if self._streams is not None:
+            x = F.sum(x, axis=2)
         logits = self.head(self.final_norm(x))
         if self.mtp is None:
             return logits

@@ -1069,6 +1069,26 @@ def _register_round3b():
     register_op("_contrib_causal_conv1d", causal_conv1d_maker,
                 aliases=("causal_conv1d",), use_jit=False)
 
+    # ---- manifold-constrained hyper-connections
+    # (kernels/hyper_connection.py): the coefficients and the sublayer's
+    # input, then the streams' update -------------------------------------
+    def hc_pre_maker(iters=20, eps=1e-6, clamp=(-30.0, 30.0)):
+        from ..kernels import hyper_connection as _hc
+
+        def fn(x, phi, bias, alpha, gamma):
+            return _hc.pre(x, phi, bias, alpha, gamma, iters=iters, eps=eps,
+                           clamp=clamp)
+        return fn
+
+    def hc_mix_maker():
+        from ..kernels.hyper_connection import mix as _mix
+        return _mix
+    # use_jit=False, as routed_experts: the hc/... scopes stay the program's
+    register_op("_contrib_hyper_connection_pre", hc_pre_maker,
+                aliases=("hyper_connection_pre",), use_jit=False)
+    register_op("_contrib_hyper_connection_mix", hc_mix_maker,
+                aliases=("hyper_connection_mix",), use_jit=False)
+
     # ---- allclose --------------------------------------------------------
     def allclose_maker(rtol=1e-5, atol=1e-8, equal_nan=False):
         def fn(a, b):

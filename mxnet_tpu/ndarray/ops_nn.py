@@ -10,9 +10,48 @@ SoftmaxOutput's fused-gradient semantics.
 """
 from __future__ import annotations
 
+import math as _math
+
 import numpy as _np
 
 from .register import register_op
+
+
+def _yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * _math.log(factor) + 1.0
+
+
+def yarn_frequencies(rotary_dim, base, scaling):
+    """YaRN in DeepSeek-V3's form (the ``rope_scaling`` keys ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``): ``(inverse frequencies (rotary_dim //
+    2,) float64, the factor on cos and sin)``.  Frequency i is ``base^(-2i /
+    d)`` blended towards the same over ``factor`` by a ramp that is 0 up to
+    the correction dimension of ``beta_fast`` rotations and 1 from that of
+    ``beta_slow``, at ``original_max_position_embeddings`` positions."""
+    d, factor = int(rotary_dim), float(scaling["factor"])
+    orig = scaling["original_max_position_embeddings"]
+
+    def dim_of(rotations):
+        return d * _math.log(orig / (rotations * 2 * _math.pi)) \
+            / (2 * _math.log(base))
+    low = max(_math.floor(dim_of(scaling.get("beta_fast", 32))), 0)
+    high = min(_math.ceil(dim_of(scaling.get("beta_slow", 1))), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = _np.clip((_np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    extra = float(base) ** (-_np.arange(0, d, 2, dtype=_np.float64) / d)
+    inv = extra / factor * ramp + extra * (1.0 - ramp)
+    return inv, _yarn_mscale(factor, scaling.get("mscale", 1)) \
+        / _yarn_mscale(factor, scaling.get("mscale_all_dim", 0))
+
+
+def yarn_softmax_factor(scaling):
+    """What YaRN multiplies the attention's softmax scale by:
+    ``mscale(factor, mscale_all_dim)^2`` (1 where ``mscale_all_dim`` is 0 or
+    absent)."""
+    return _yarn_mscale(float(scaling["factor"]),
+                        scaling.get("mscale_all_dim", 0)) ** 2
 
 
 def _register():
@@ -371,21 +410,35 @@ def _register():
         return fn
     register_op("RMSNorm", rmsnorm_maker, aliases=("rms_norm",))
 
-    def rope_maker(base=10000.0, rotary_dim=None, seq_axis=1):
+    def rope_maker(base=10000.0, rotary_dim=None, seq_axis=1, scaling=None):
         """Rotary position embedding, rotate-half convention, on the last
         ``rotary_dim`` lanes of the last axis (all of them by default);
         the lanes before pass through.  Position i is index i of
-        ``seq_axis``."""
+        ``seq_axis``.  ``scaling``: None, or YaRN's ``rope_scaling`` (a
+        mapping or its (key, value) pairs; ``yarn_frequencies``) for the
+        frequencies and the factor on cos and sin."""
+        yarn = None if scaling is None else dict(scaling)
+        if yarn is not None and yarn.pop("type", "yarn") != "yarn":
+            raise ValueError(f"rope scaling {dict(scaling)['type']!r} is "
+                             "not 'yarn'")
+
         def fn(x):
             d = x.shape[-1]
             r = d if rotary_dim is None else int(rotary_dim)
             ax = seq_axis % x.ndim
-            inv = float(base) ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+            if yarn is None:
+                inv = float(base) ** (-jnp.arange(0, r, 2, dtype=jnp.float32)
+                                      / r)
+            else:
+                inv, mscale = yarn_frequencies(r, float(base), yarn)
+                inv = jnp.asarray(inv, jnp.float32)
             ang = jnp.arange(x.shape[ax], dtype=jnp.float32)[:, None] * inv
             shape = [1] * x.ndim
             shape[ax], shape[-1] = x.shape[ax], r
             cos = jnp.tile(jnp.cos(ang), (1, 2)).reshape(shape)
             sin = jnp.tile(jnp.sin(ang), (1, 2)).reshape(shape)
+            if yarn is not None and mscale != 1.0:
+                cos, sin = cos * mscale, sin * mscale
             keep, rot = x[..., :d - r], x[..., d - r:].astype(jnp.float32)
             half = jnp.concatenate([-rot[..., r // 2:], rot[..., :r // 2]],
                                    axis=-1)

@@ -324,6 +324,36 @@ def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def test_hyper_connection_compiles_for_v5e_and_holds_no_broadcast(one_chip):
+    """One sublayer's hyper-connection at the Xing cell's shape (one row of
+    4096 tokens, four streams of 3584) around a stand-in sublayer, forward
+    and backward: it compiles for the chip; the stream mixes are summed one
+    stream at a time, so no (4, 4, 3584) product a token is made; and its
+    temporaries stay under five copies of the streams (235 MB each: 1.11 GB
+    when written, the normed streams kept for the projection's backward
+    among them, and the mix's output and cotangent, which this loss
+    holds)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import hyper_connection as hc
+
+    def operand(*shape):
+        return jax.ShapeDtypeStruct(shape, "float32", sharding=one_chip)
+    x = operand(1, 4096, 4, 3584)
+    leaves = (operand(24, 4 * 3584), operand(24), operand(3),
+              operand(4 * 3584))
+
+    def loss(x, phi, bias, alpha, gamma):
+        x_in, h_post, h_res = hc.pre(x, phi, bias, alpha, gamma, iters=20,
+                                     eps=1e-6, clamp=(-30.0, 30.0))
+        return jnp.sum(jnp.square(hc.mix(x, jnp.tanh(x_in), h_post, h_res)))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, *leaves).compile()
+    assert "f32[1,4096,4,4,3584]" not in compiled.as_text()
+    streams = 4096 * 4 * 3584 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * streams
+
+
 @pytest.mark.parametrize("lead,c,dk", [((2, 256), 64, 128), ((2, 5), 32, 96),
                                        ((2, 3), 16, 8)],
                          ids=["kimi_cell_group", "c32_padded", "c16_padded"])
