@@ -1074,12 +1074,13 @@ class HyperConnection(HybridBlock):
     (``kernels/hyper_connection.py`` has the equations) over ``streams``
     residual streams of ``units``: ``gamma`` the coefficient norm's gain,
     ``phi`` (streams^2 + 2 streams, streams x units), ``bias``, ``alpha``
-    (pre, post, res).  Called on the state X (B, S, streams, units) it
-    returns ``(x_in, h_post, h_res)`` in float32, the sublayer's input
-    (B, S, units) and the coefficients, under the scopes
-    ``hc/{coeff,sinkhorn,pre}``; ``F.hyper_connection_mix(X, y, h_post,
-    h_res)`` with the sublayer's output ``y`` is the new state (scope
-    ``hc/mix``), and needs no parameter."""
+    (pre, post, res).  Called on the state X (B, S, streams x units), the
+    streams side by side, it returns ``(x_in, coef, X)`` under the scopes
+    ``hc/pre``: the sublayer's input (B, S, units), the coefficients in
+    float32 and X itself; ``F.hyper_connection_mix(X, y, coef)`` with that
+    X and the sublayer's output ``y`` is the new state (scope ``hc/mix``),
+    and needs no parameter.  The mix takes the X that ``pre`` hands back, so
+    that the backward adds both cotangents of X in one place."""
 
     def __init__(self, units, streams, sinkhorn_iters=20, eps=1e-6,
                  clamp=(-30.0, 30.0), prefix=None, params=None):
@@ -1107,9 +1108,9 @@ class MLADecoderCell(HybridBlock):
     the mixer, ``attention()``, is the block's attribute of the name
     ``mixer`` (``mla``: latent attention; ``gdn``: the channel-gated delta
     rule).  With ``hc_mult`` streams (mHC) the block's input and output are
-    (B, S, hc_mult, units) and each sublayer, norm and mixer or norm and
-    FFN, sits inside a ``HyperConnection`` (``attn_hc``, ``ffn_hc``) in
-    place of the plain residual add."""
+    (B, S, hc_mult x units), the streams side by side, and each sublayer,
+    norm and mixer or norm and FFN, sits inside a ``HyperConnection``
+    (``attn_hc``, ``ffn_hc``) in place of the plain residual add."""
 
     def __init__(self, units, attention, ffn, epsilon=1e-5, prefix=None,
                  params=None, mixer="mla", hc_mult=None, hc_sinkhorn_iters=20,
@@ -1138,8 +1139,8 @@ class MLADecoderCell(HybridBlock):
                 (self.attn_hc, lambda h: getattr(self, self._mixer)(
                     self.attn_norm(h))),
                 (self.ffn_hc, lambda h: self.ffn(self.ffn_norm(h)))):
-            x_in, h_post, h_res = hc(x)
-            x = F.hyper_connection_mix(x, sublayer(x_in), h_post, h_res)
+            x_in, coef, x = hc(x)
+            x = F.hyper_connection_mix(x, sublayer(x_in), coef)
         return x
 
 
@@ -1173,13 +1174,20 @@ class MTPModule(HybridBlock):
                                   dim=-1))
         if self._streams is None:
             return self.final_norm(self.cell(h))
-        return self.final_norm(F.sum(self.cell(_spread(F, h, self._streams)),
-                                     axis=2))
+        return self.final_norm(_merge(F, self.cell(_spread(F, h,
+                                                           self._streams)),
+                                      self._streams))
 
 
 def _spread(F, x, streams):
-    """(B, S, C) -> (B, S, streams, C): the same values in every stream."""
-    return F.broadcast_axis(F.expand_dims(x, axis=2), axis=2, size=streams)
+    """(B, S, C) -> (B, S, streams x C): the same values in every stream,
+    the streams side by side."""
+    return F.tile(x, reps=(1, 1, streams))
+
+
+def _merge(F, x, streams):
+    """(B, S, streams x C) -> (B, S, C): the streams summed."""
+    return F.sum(F.reshape(x, shape=(0, 0, streams, -1)), axis=2)
 
 
 class MLAMoELM(HybridBlock):
@@ -1304,7 +1312,7 @@ class MLAMoELM(HybridBlock):
         for cell in self.cells:
             x = cell(x)
         if self._streams is not None:
-            x = F.sum(x, axis=2)
+            x = _merge(F, x, self._streams)
         logits = self.head(self.final_norm(x))
         if self.mtp is None:
             return logits

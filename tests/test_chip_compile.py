@@ -324,34 +324,43 @@ def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
-def test_hyper_connection_compiles_for_v5e_and_holds_no_broadcast(one_chip):
+def test_hyper_connection_compiles_for_v5e_and_holds_no_broadcast(
+        one_chip, monkeypatch):
     """One sublayer's hyper-connection at the Xing cell's shape (one row of
-    4096 tokens, four streams of 3584) around a stand-in sublayer, forward
-    and backward: it compiles for the chip; the stream mixes are summed one
-    stream at a time, so no (4, 4, 3584) product a token is made; and its
-    temporaries stay under five copies of the streams (235 MB each: 1.11 GB
-    when written, the normed streams kept for the projection's backward
-    among them, and the mix's output and cotangent, which this loss
-    holds)."""
+    4096 tokens, four streams of 3584 side by side) around a stand-in
+    sublayer, forward and backward: the four kernels compile for the chip
+    and are there by name, each once; no (4, 4, 3584) product a token is
+    made; and the temporaries stay under three copies of the streams (235 MB
+    each; 0.65 GB when written: the mix's output and cotangent, which this
+    loss holds, and what the sublayer's input needs; 1.11 GB, the normed
+    streams among them, before the kernels)."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import hyper_connection as hc
 
+    # the kernels ask the default backend, which is the CPU here
+    monkeypatch.setattr(hc, "_interpret", lambda: False)
+
     def operand(*shape):
         return jax.ShapeDtypeStruct(shape, "float32", sharding=one_chip)
-    x = operand(1, 4096, 4, 3584)
+    x = operand(1, 4096, 4 * 3584)
     leaves = (operand(24, 4 * 3584), operand(24), operand(3),
               operand(4 * 3584))
 
     def loss(x, phi, bias, alpha, gamma):
-        x_in, h_post, h_res = hc.pre(x, phi, bias, alpha, gamma, iters=20,
-                                     eps=1e-6, clamp=(-30.0, 30.0))
-        return jnp.sum(jnp.square(hc.mix(x, jnp.tanh(x_in), h_post, h_res)))
+        x_in, coef, x = hc.pre(x, phi, bias, alpha, gamma, iters=20,
+                               eps=1e-6, clamp=(-30.0, 30.0))
+        return jnp.sum(jnp.square(hc.mix(x, jnp.tanh(x_in), coef)))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         x, *leaves).compile()
-    assert "f32[1,4096,4,4,3584]" not in compiled.as_text()
+    text = compiled.as_text()
+    for name in ("hc_pre_fwd", "hc_mix_fwd", "hc_mix_bwd", "hc_pre_bwd"):
+        assert _kernel_calls(text, name) == 1, name
+    broadcast = 4096 * 4 * 4 * 3584
+    assert not [s for s in re.findall(r"f32\[([\d,]+)\]", text)
+                if np.prod([int(d) for d in s.split(",")]) == broadcast]
     streams = 4096 * 4 * 3584 * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * streams
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * streams
 
 
 @pytest.mark.parametrize("lead,c,dk", [((2, 256), 64, 128), ((2, 5), 32, 96),
