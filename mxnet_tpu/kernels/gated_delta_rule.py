@@ -35,10 +35,12 @@ bound on ``g`` relied on; nothing of tokens x C x dk is made, and nothing but
 ``q``, ``k`` and ``gamma`` is kept for the backward.
 
 Everything but the pass ``S -> S'`` is batched over heads and chunks; that
-pass is a ``lax.scan`` over the chunks with two matmuls in its body, and its
-backward is the same scan run the other way (written out below: the rest is
-left to jax's own differentiation).  Sums of ``g``, exponentials, the
-inverse and the carried state are float32 whatever the operands came in.
+pass is a pair of Pallas kernels, forward and backward, that hold each
+head's state, and then its cotangent, in VMEM across the chunks and read
+``W``, ``U`` and ``K * exp(gamma_C - gamma)`` where this module makes them
+(``state_pass``; the rest is left to jax's own differentiation).  Sums of
+``g``, exponentials, the inverse and the carried state are float32 whatever
+the operands came in.
 
 ``(I + A)^-1``: ``A`` is nilpotent, so on a diagonal block of ``_BASE`` rows
 the finite series ``(I - A)(I + A^2)(I + A^4)...`` is exact; blocks are then
@@ -50,11 +52,11 @@ Gauges (set while a program that holds the op is traced, as the flash
 kernel sets its tiling): ``gdn.heads``, ``gdn.key_dim``, ``gdn.value_dim``,
 ``gdn.chunk``, ``gdn.gate_lanes`` (1, or dk for the channel gate),
 ``gdn.state_bytes`` (the chunk states one call's backward holds) and
-``gdn.scan_steps``, to which every state pass traced adds its
-iterations: ``ShardedTrainer`` sets it to 0 where it begins to trace a step
-(beside ``trainer.remat_kept_bytes``), so that after the trace it reads the
-dependent iterations of one step, forward, recomputed and backward, all
-layers.  Counter ``gdn.builds``.
+``gdn.scan_steps``, to which every state pass traced adds the chunks it
+walks in sequence (``state_pass``): ``ShardedTrainer`` sets it to 0 where it
+begins to trace a step (beside ``trainer.remat_kept_bytes``), so that after
+the trace it reads the dependent iterations of one step, forward,
+recomputed and backward, all layers.  Counter ``gdn.builds``.
 """
 from __future__ import annotations
 
@@ -83,14 +85,6 @@ def chunk_of(seq: int) -> int:
     while c < min(seq, _MAX_CHUNK):
         c *= 2
     return c
-
-
-def _count_scan(steps: int) -> None:
-    from ..observability.registry import registry
-    gauge = registry().gauge(
-        "gdn.scan_steps", "dependent iterations of the delta rule's state "
-        "passes traced since a trainer last began to trace its step")
-    gauge.set(gauge.value + steps)
 
 
 @functools.lru_cache(maxsize=1)
@@ -146,77 +140,6 @@ def _unit_lower_inverse_fn():
     return inverse
 
 
-@functools.lru_cache(maxsize=1)
-def _state_pass_fn():
-    """The sequential pass over the chunks with its own backward (built
-    lazily so that importing this module never imports jax).
-
-    ``w`` (B, H, N, C, dk), ``u`` (B, H, N, C, dv), ``kd`` = ``K *
-    exp(gamma_C - gamma)`` (B, H, N, C, dk), ``decay`` = ``exp(gamma_C)``
-    (B, H, N), or (B, H, N, dk) under the channel gate.  Returns the state
-    each chunk starts from (B, H, N, dk, dv),
-    ``U'`` (B, H, N, C, dv) and the state after the last chunk."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def chunk_major(*xs):
-        return tuple(jnp.moveaxis(x, 2, 0) for x in xs)
-
-    def scaled(d_n, s):
-        """``s`` (B, H, dk, dv) times the chunk's decay: one a head, or
-        one a key channel (the rows of ``s``)."""
-        return d_n[..., None] * s if d_n.ndim == 3 else \
-            d_n[..., None, None] * s
-
-    def forward(w, u, kd, decay):
-        b, h, n, _, dk = w.shape
-        _count_scan(n)
-
-        def body(s, x):
-            w_n, u_n, kd_n, d_n = x
-            un = u_n - jnp.matmul(w_n, s, precision=_PRECISION)
-            nxt = scaled(d_n, s) + jnp.einsum(
-                "bhck,bhcv->bhkv", kd_n, un, precision=_PRECISION)
-            return nxt, (s, un)
-        final, (states, un) = lax.scan(
-            body, jnp.zeros((b, h, dk, u.shape[-1]), jnp.float32),
-            chunk_major(w, u, kd, decay))
-        return jnp.moveaxis(states, 0, 2), jnp.moveaxis(un, 0, 2), final
-
-    @jax.custom_vjp
-    def state_pass(w, u, kd, decay):
-        return forward(w, u, kd, decay)
-
-    def fwd(w, u, kd, decay):
-        states, un, final = forward(w, u, kd, decay)
-        return (states, un, final), (w, kd, decay, states, un)
-
-    def bwd(res, cts):
-        w, kd, decay, states, un = res
-        d_states, d_un, d_final = cts
-        _count_scan(w.shape[2])
-
-        def body(ds, x):
-            # ds: the cotangent of the state this chunk hands on
-            w_n, kd_n, d_n, dh_n, dun_n = x
-            dut = dun_n + jnp.matmul(kd_n, ds, precision=_PRECISION)
-            before = dh_n + scaled(d_n, ds) - jnp.einsum(
-                "bhck,bhcv->bhkv", w_n, dut, precision=_PRECISION)
-            return before, (ds, dut)
-        _, (ds, dut) = lax.scan(
-            body, d_final, chunk_major(w, kd, decay, d_states, d_un),
-            reverse=True)
-        ds, dut = jnp.moveaxis(ds, 0, 2), jnp.moveaxis(dut, 0, 2)
-        d_w = -jnp.einsum("bhncv,bhnkv->bhnck", dut, states,
-                          precision=_PRECISION)
-        d_kd = jnp.einsum("bhncv,bhnkv->bhnck", un, ds, precision=_PRECISION)
-        return d_w, dut, d_kd, jnp.sum(
-            ds * states, axis=-1 if decay.ndim == 4 else (-2, -1))
-    state_pass.defvjp(fwd, bwd)
-    return state_pass
-
-
 def gated_delta_rule(q, k, v, g, beta):
     """``q``, ``k`` (B, L, H, dk), ``v`` (B, L, H, dv), ``beta`` (B, L, H),
     ``g`` (B, L, H), one decay a head, or (B, L, H, dk), one a key channel
@@ -226,6 +149,7 @@ def gated_delta_rule(q, k, v, g, beta):
     import jax
     import jax.numpy as jnp
     from ..observability.registry import registry
+    from .state_pass import state_pass
 
     b, seq, h, dk = q.shape
     dv = v.shape[-1]
@@ -271,7 +195,7 @@ def gated_delta_rule(q, k, v, g, beta):
         w = mm("bhncs,bhnsd->bhncd", t, k * grown)
         u = mm("bhncs,bhnsd->bhncd", t, v)
     with jax.named_scope("state"):
-        states, un, final = _state_pass_fn()(w, u, k * rest, jnp.exp(total))
+        states, un, final = state_pass(w, u, k * rest, jnp.exp(total))
     with jax.named_scope("output"):
         o = mm("bhncd,bhndv->bhncv", q * grown, states) + mm(
             "bhncs,bhnsv->bhncv", mm("bhncd,bhnsd->bhncs", q, k) * gam, un)
@@ -306,6 +230,7 @@ def _channel_rule(q, k, v, g, beta, lower, mm, seq, out_dtype):
     import jax.numpy as jnp
     from jax import lax
     from .decayed_products import decayed_products
+    from .state_pass import state_pass
 
     b, h, n, c, dv = v.shape
 
@@ -343,7 +268,7 @@ def _channel_rule(q, k, v, g, beta, lower, mm, seq, out_dtype):
     w, u, kd, decay, pq = (merge(x) for x in lax.map(
         jax.checkpoint(before), groups))
     with jax.named_scope("state"):
-        states, un, final = _state_pass_fn()(w, u, kd, decay)
+        states, un, final = state_pass(w, u, kd, decay)
     groups, merge = _by_rows((q, g, states, un, pq), b * h)
     o = merge(lax.map(jax.checkpoint(after), groups))
     with jax.named_scope("output"):

@@ -337,7 +337,7 @@ class ShardedTrainer:
             "rematerialised blocks of the last step traced keep for the "
             "backward")
         # and so do the delta rule's state passes, which add their
-        # iterations (kernels/gated_delta_rule.py:_count_scan)
+        # iterations (kernels/state_pass.py:_count)
         scan_steps = _metrics_registry().gauge("gdn.scan_steps")
         tparams, aparams = self._train_params, self._aux_params
         fopt, ctx = self._fopt, self._ctx

@@ -301,16 +301,21 @@ def test_fused_projection_is_read_in_place_for_v5e(one_chip):
                           for line in joined)
 
 
-def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
+def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     """The chunked delta rule at the hybrid cell's shape (one row of 2048
     tokens, 30 heads of 96 / 192), forward and backward, compiles for the
-    chip: two ``while`` loops (the state pass and its backward) and
-    temporaries under 1 GiB (0.90 GB when written: some twenty arrays of
-    (30, 32, 64, 64..192) float32 that the batched parts keep for their
-    backward, among them 71 MB of chunk states)."""
+    chip: no ``while`` loop, the state pass one ``gdn_state_pass_fwd`` and
+    one ``gdn_state_pass_bwd`` kernel, and temporaries under 1 GiB (0.80 GB
+    when written, 0.90 with the scan and its chunk-major copies: some
+    twenty arrays of (30, 32, 64, 64..192) float32 that the batched parts
+    keep for their backward, among them 71 MB of chunk states)."""
     import jax
     import jax.numpy as jnp
+    from mxnet_tpu.kernels import state_pass as sp
     from mxnet_tpu.kernels.gated_delta_rule import gated_delta_rule
+
+    # the kernels ask the default backend, which is the CPU here
+    monkeypatch.setattr(sp, "_interpret", lambda: False)
 
     def operand(*shape):
         return jax.ShapeDtypeStruct(shape, "float32", sharding=one_chip)
@@ -320,8 +325,40 @@ def test_gated_delta_rule_compiles_for_v5e_and_fits(one_chip):
     compiled = jax.jit(jax.grad(
         lambda *a: jnp.sum(gated_delta_rule(*a)[0]),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
-    assert compiled.as_text().count(" while(") == 2
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert _kernel_calls(text, "gdn_state_pass_fwd") == 1
+    assert _kernel_calls(text, "gdn_state_pass_bwd") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("lead,n,dk", [((1, 32), 256, 128), ((2, 3), 11, 96)],
+                         ids=["kimi_layer", "ragged_narrow"])
+def test_state_pass_kernels_compile_for_v5e(one_chip, monkeypatch, lead, n,
+                                            dk):
+    """The state pass alone, forward and backward: a Kimi layer's (32 heads
+    of 128 / 128, 256 chunks of 64, the channel gate), and a last block of
+    three chunks at lanes of 96 / 192 under the scalar gate; the gradient
+    holds the two kernels, each once, by name."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import state_pass as sp
+
+    monkeypatch.setattr(sp, "_interpret", lambda: False)
+    dv = 2 * dk if dk == 96 else dk
+
+    def operand(*tail):
+        return jax.ShapeDtypeStruct(lead + tail, "float32",
+                                    sharding=one_chip)
+    args = (operand(n, 64, dk), operand(n, 64, dv), operand(n, 64, dk),
+            operand(n, dk) if dk == 128 else operand(n))
+
+    def loss(*a):
+        states, un, final = sp.state_pass(*a)
+        return jnp.sum(states * states) + jnp.sum(un * un) + jnp.sum(final)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
+    assert _kernel_calls(text, "gdn_state_pass_fwd") == 1
+    assert _kernel_calls(text, "gdn_state_pass_bwd") == 1
 
 
 def test_hyper_connection_compiles_for_v5e_and_holds_no_broadcast(

@@ -2,8 +2,9 @@
 (``benchmarks/reference/kda_mla_moe_lm.py``: the delta rule as its
 per-token recurrence): the channel-gated delta rule (``gated_delta_rule``
 with a (B, L, H, dk) gate), its decayed products' kernels against the
-halvings that made them before (``halving_products``), the flash kernels
-with a value width of its own, ``MLAttention`` without rotary positions or a query bottleneck, the
+halvings that made them before (``halving_products``), the state pass's
+kernels against the scan that made it before (``scan_pass``), the flash
+kernels with a value width of its own, ``MLAttention`` without rotary positions or a query bottleneck, the
 five-layer ``MLAMoELM`` with ``layer_types``, one chip's share of the
 experts, the expert layer in pieces, and the programs of the four older
 cells, which must trace as the parent's did."""
@@ -22,10 +23,12 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.transformer import MLAMoELM, MLAttention
 from mxnet_tpu.kernels import decayed_products as dp_mod
 from mxnet_tpu.kernels import gated_delta_rule as gdr_mod
+from mxnet_tpu.kernels import state_pass as sp_mod
 from mxnet_tpu.kernels.flash_attention import flash_attention
 from mxnet_tpu.kernels.gated_delta_rule import gated_delta_rule
 from mxnet_tpu.observability.registry import registry
 from mxnet_tpu.parallel import moe
+from mxnet_tpu.profiler import scope_way
 
 from benchmarks.harness import loader
 from benchmarks.models import kda_mla_moe_lm as KM
@@ -308,6 +311,110 @@ def test_products_grid_step_divides_the_chunks(chunks, block):
     assert dp_mod._block_of(chunks) == block
 
 
+
+# -- the state pass's kernels ------------------------------------------------------
+
+def scan_pass(w, u, kd, decay):
+    """The oracle: the pass over the chunks as the rule made it before its
+    kernels, a ``lax.scan`` with the chunks moved to the front, its
+    gradients jax's own."""
+    from jax import lax
+
+    def scaled(d_n, s):
+        return d_n[..., None] * s if d_n.ndim == 3 else \
+            d_n[..., None, None] * s
+
+    def body(s, x):
+        w_n, u_n, kd_n, d_n = x
+        un = u_n - jnp.matmul(w_n, s, precision="highest")
+        nxt = scaled(d_n, s) + jnp.einsum(
+            "bhck,bhcv->bhkv", kd_n, un, precision="highest")
+        return nxt, (s, un)
+    b, h, _, _, dk = w.shape
+    final, (states, un) = lax.scan(
+        body, jnp.zeros((b, h, dk, u.shape[-1]), jnp.float32),
+        tuple(jnp.moveaxis(x, 2, 0) for x in (w, u, kd, decay)))
+    return jnp.moveaxis(states, 0, 2), jnp.moveaxis(un, 0, 2), final
+
+
+def pass_operands(n, dk, dv, channel, strength=1.0, lead=(1, 4), c=16,
+                  seed=0):
+    """``w``, ``u``, ``kd`` and a chunk's decay ``exp(gamma_C)`` from a gate
+    of up to e^-strength a token (at 60 it underflows to nought), and a
+    cotangent for each of the pass's three results."""
+    r = np.random.default_rng(seed)
+    w, kd = (0.1 * r.normal(size=lead + (n, c, dk)) for _ in range(2))
+    u = r.normal(size=lead + (n, c, dv))
+    gate = lead + (n, dk) if channel else lead + (n,)
+    decay = np.exp(-strength * np.sum(r.uniform(0.0, 0.1, gate + (c,)),
+                                      axis=-1))
+    cts = [r.normal(size=lead + tail) for tail in
+           ((n, dk, dv), (n, c, dv), (dk, dv))]
+    return ([jnp.asarray(x, jnp.float32) for x in (w, u, kd, decay)],
+            tuple(jnp.asarray(x, jnp.float32) for x in cts))
+
+
+@pytest.mark.parametrize("n,dk,dv,channel,strength,lead", [
+    (11, 128, 128, True, 1.0, (1, 4)),
+    (16, 128, 128, False, 1.0, (1, 2)),
+    (5, 96, 192, False, 1.0, (2, 3)),
+    (19, 96, 192, True, 1.0, (1, 2)),
+    (11, 128, 128, True, 60.0, (1, 4))],
+    ids=["kimi_widths_ragged", "scalar_two_blocks", "olmo_widths_short",
+         "olmo_widths_ragged_channel", "strong_gate"])
+def test_state_pass_kernels_are_the_scan(n, dk, dv, channel, strength, lead):
+    """The kernels (interpret mode) against the scan: the states, ``U'``
+    and the final state within 1e-6 of their size, and the cotangents of
+    ``w``, ``u``, ``kd`` and the decay within 1e-5 of theirs (the scalar
+    gate's is summed over (dk, dv) in another order), at the Kimi and Olmo
+    cells' widths, with both gates, chunk counts that a grid step's eight
+    do not divide and fewer than eight, and a decay that underflows."""
+    args, cts = pass_operands(n, dk, dv, channel, strength, lead)
+    got, vjp = jax.vjp(sp_mod.state_pass, *args)
+    want, vjp_want = jax.vjp(scan_pass, *args)
+    grads = vjp(cts)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got + grads)
+    _near(got, want, 1e-6)
+    _near(grads, vjp_want(cts), 1e-5)
+
+
+@pytest.mark.parametrize("channel", [False, True], ids=["scalar", "channel"])
+def test_state_pass_kernels_sit_under_state_and_count(monkeypatch, channel):
+    """The rule's gradient lowered for the chip (``jax.export``, no chip
+    needed; the decayed products left in interpret mode): one call of
+    ``gdn_state_pass_fwd`` and one of ``gdn_state_pass_bwd``, each located
+    under the scope ``state`` (which ``gdn_rule_device_ms.train`` reads)
+    on its own side of the step, and no ``while`` under the scalar gate
+    (the channel gate's groups are ``lax.map``s); ``gdn.state_kernel_calls`` counts each call traced and
+    ``gdn.scan_steps`` the chunks each walks in sequence."""
+    from jax import export
+    monkeypatch.setattr(sp_mod, "_interpret", lambda: False)
+    S = jax.ShapeDtypeStruct
+    gate = (1, 80, 2, 8) if channel else (1, 80, 2)
+    args = [S((1, 80, 2, 8), jnp.float32)] * 3 + [S(gate, jnp.float32),
+                                                  S((1, 80, 2), jnp.float32)]
+    calls = registry().counter("gdn.state_kernel_calls")
+    steps = registry().gauge("gdn.scan_steps")
+    before = calls.value, steps.value
+    text = export.export(jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)[0]), argnums=range(5))),
+        platforms=("tpu",))(*args).mlir_module()
+    assert (calls.value - before[0], steps.value - before[1]) == (2, 4)
+    assert text.count("@tpu_custom_call") == 2
+    assert channel or "stablehlo.while" not in text
+    located = re.findall(r'loc\("([^"]*/gdn_state_pass_(?:fwd|bwd))/', text)
+    assert sorted((x.rsplit("_", 1)[1],) + scope_way(x) for x in located) \
+        == [("bwd", "state", "bwd"), ("fwd", "state", "fwd")]
+
+
+@pytest.mark.parametrize("rows,chunks,blocks", [
+    (32, 256, (4, 8)), (30, 32, (3, 8)), (6, 5, (3, 5)), (7, 11, (1, 8))])
+def test_state_pass_grid_step(rows, chunks, blocks):
+    """A grid step takes the most heads, up to four, that divide the rows
+    (the Kimi cell's 32 take 4, Olmo's 30 take 3), and eight chunks, or all
+    where fewer: a last block may be partial."""
+    assert sp_mod._blocks(rows, chunks) == blocks
+
 # -- flash attention with a value width of its own --------------------------------
 
 def _softmax_attention(q, k, v, scale):
@@ -517,10 +624,11 @@ def _fingerprint(text):
 
 # the jaxprs of PR 39's tree (the parent of PR 40) at these sizes, read with
 # the same code there and here; a PR that changes one of these programs on
-# purpose writes its new fingerprint
+# purpose writes its new fingerprint (the Olmo step and the scalar rule's:
+# the state pass's kernels)
 PARENT_STEPS = {"bert_base.pretrain_s512": "6ea0398824758236",
                 "glm47_flash_ep8.pretrain_s8k": "3fe348c3135d971d",
-                "olmo_hybrid_7b_l4.pretrain_s2k": "dccab563adb1daae",
+                "olmo_hybrid_7b_l4.pretrain_s2k": "69961ebcc5e5bdab",
                 "smallthinker_21b_ep8.pretrain_s16k": "bb679c5b52ba8199"}
 TINY_FILES = {"bert_base.pretrain_s512": "tiny_bert.json",
               "glm47_flash_ep8.pretrain_s8k": "tiny_mla_moe_lm.json",
@@ -538,7 +646,8 @@ def test_older_cells_trace_the_parents_step(cell, one_device):
     """The whole training step of each older cell's family (its model, the
     rematerialised blocks, the flash kernels in interpret mode, the expert
     layer, the scalar delta rule, Adam) at the rehearsal's tiny sizes
-    traces to the jaxpr that PR 39's tree traced."""
+    traces to the jaxpr of its fingerprint, the Olmo cell's with the state
+    pass's kernels in place of its scan."""
     _, c, cfg = loader.cell_and_config(
         loader.benchmark(), cell,
         os.path.join(ROOT, "benchmarks", "tests", "data", TINY_FILES[cell]))
@@ -550,8 +659,8 @@ def test_older_cells_trace_the_parents_step(cell, one_device):
     assert _fingerprint(str(jaxpr)) == PARENT_STEPS[cell]
 
 
-PARENT_KERNELS = {"rule_fwd": "a414259ace1050e6",
-                  "rule_grad": "777d4f0a58b6b91b",
+PARENT_KERNELS = {"rule_fwd": "6fa49aa2920db991",
+                  "rule_grad": "aed20f7f4d82a307",
                   "flash_heads_first_grad": "03e117ebbf37e5a1",
                   "flash_fused_grad": "c72b5bc4133ce1d0",
                   "flash_tokens_major_grad": "25a777cab5ae9fd9",
@@ -595,6 +704,7 @@ def test_older_kernel_calls_trace_the_parents(name):
     """The scalar delta rule (Olmo's call) and the flash calls as the BERT
     (fused projection), GLM (heads-first), Olmo (tokens-major) and
     SmallThinker (grouped heads under a window) cells make them: forward
-    and gradient jaxprs as PR 39's tree traced them; the value width and the
+    and gradient jaxprs as their fingerprints record them (the rule's with
+    the state pass's kernels in place of its scan); the value width and the
     channel gate add nothing where they are not used."""
     assert _fingerprint(str(_kernel_jaxpr(name))) == PARENT_KERNELS[name]

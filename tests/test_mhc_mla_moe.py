@@ -525,9 +525,10 @@ def test_the_shares_add_up():
 
 # -- the older cells' programs are the parent's -------------------------------------
 
-# the jaxprs of the parent tree at the rehearsal's sizes, read with this code
+# the jaxprs of the parent tree at the rehearsal's sizes, read with this code;
+# Kimi's with the state pass's kernels in place of the delta rule's scan
 PARENT_STEPS = {"glm47_flash_ep8.pretrain_s8k": "3fe348c3135d971d",
-                "kimi_linear_48b_ep32.pretrain_s16k": "891544e87a53a85f"}
+                "kimi_linear_48b_ep32.pretrain_s16k": "3e81d886df6b35c4"}
 TINY_FILES = {"glm47_flash_ep8.pretrain_s8k": "tiny_mla_moe_lm.json",
               "kimi_linear_48b_ep32.pretrain_s16k": "tiny_kda_mla_moe_lm.json"}
 
